@@ -1,4 +1,5 @@
-"""Kernel-level benchmark: structural report (VMEM footprint,
+"""CPU rehearsal of the kernels and the serving paths: structural
+report (VMEM footprint,
 arithmetic intensity, MXU alignment), fused-kernel correctness vs the
 ``ref.py`` oracles, and a *wall-clock* comparison of the seed evaluate
 path against the program-once execution engine.
@@ -20,15 +21,23 @@ numbers are recorded to keep the attribution honest:
     stack jit into one XLA computation (impossible for the seed path,
     whose per-call re-programming would be retraced into every step).
 
-(CPU here; on TPU the fused Pallas kernel widens all of these.)
+This is a rehearsal on the CPU, not a device benchmark. Kernels run in
+Pallas interpret mode, the fleet, deploy, obs, autotune and LM phases
+run in child processes pinned to simulated CPU devices, and every time
+and ratio it writes is host time on XLA:CPU. None of its figures is a
+TPU metric; ``chip_smoke.py`` at the repo root is what runs on the
+chip. The benchmark refuses to start on any other backend, so that a
+TPU host cannot produce CPU numbers under its name.
 
-Standalone:  PYTHONPATH=src python -m benchmarks.kernel_bench
-writes BENCH_kernels.json at the repo root (benchmarks/run.py does the
-same as part of the full suite).
+Standalone:  PYTHONPATH=src JAX_PLATFORMS=cpu python -m
+benchmarks.kernel_bench — writes BENCH_kernels.json at the repo root
+(benchmarks/run.py does the same as part of the full suite) and exits
+non-zero when a child phase failed or a gate did not pass.
 """
 import json
 import os
 import subprocess
+import sys
 import textwrap
 import time
 
@@ -1046,7 +1055,18 @@ def _lm_serve() -> dict:
     return res
 
 
+def _require_cpu() -> None:
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"kernel_bench is a CPU rehearsal (its child phases run on "
+            f"simulated CPU devices), but the default backend is "
+            f"{backend!r}; run it with JAX_PLATFORMS=cpu, and use "
+            f"chip_smoke.py on the chip")
+
+
 def run() -> dict:
+    _require_cpu()
     tiles = _structural_report()
     errs = _correctness()
     wc = _wallclock()
@@ -1075,7 +1095,13 @@ def run() -> dict:
         lm.get("mapped_vs_oracle", 0.0) >= 0.5 and \
         bool(lm.get("token_parity", False)) and \
         bool(lm.get("stats_exact", False))
+    children = {"fleet_serve": fleet, "fleet_degraded": degraded,
+                "deploy_serve": deploy, "obs_overhead": obs_oh,
+                "autotune": autotune, "lm_serve": lm}
+    failed = sorted(name for name, r in children.items() if "error" in r)
+    ok = ok and not failed
     return {"tiles": tiles, "kernel_err": max_err, "kernel_errs": errs,
+            "failed_children": failed,
             "wallclock": wc, "fleet_serve": fleet,
             "fleet_degraded": degraded,
             "deploy_serve": deploy, "variability_recal": vr,
@@ -1097,6 +1123,12 @@ def write_bench_json(result: dict,
 
 
 if __name__ == "__main__":
-    res = run()
+    try:
+        res = run()
+    except RuntimeError as e:
+        sys.exit(f"kernel_bench: {e}")
     p = write_bench_json(res)
-    print(f"\nwrote {p}; pass={res['pass']}")
+    print(f"\nwrote {p}; pass={res['pass']}"
+          + (f"; failed child phases: {res['failed_children']}"
+             if res["failed_children"] else ""))
+    sys.exit(0 if res["pass"] else 1)

@@ -55,7 +55,7 @@ from repro.core import routing as routing_lib
 from repro.core.crossbar_layer import (CrossbarParams, DigitalParams,
                                        MLPSpec, ProgrammedMLP,
                                        digital_apply, program_layer,
-                                       program_mlp)
+                                       program_mlp, stream_precision)
 from repro.core.device import DEFAULT_DEVICE, DeviceModel
 from repro.core.mapping import (Mapping, Net, map_networks)
 from repro.core.neural_core import CoreGeometry
@@ -211,7 +211,8 @@ def _crossbar_partials(p: CrossbarParams, x: jax.Array,
     else:
         w_eff = ((gp - gn) * p.scale[:, :, None, :]).astype(cdtype)
         parts = jnp.einsum("brk,rckn->brcn", xt, w_eff,
-                           preferred_element_type=jnp.float32)
+                           preferred_element_type=jnp.float32,
+                           precision=stream_precision(cdtype))
         parts = parts.reshape(xt.shape[0], R, C * cols)
     return parts[:, :, :p.d_out]
 
@@ -236,7 +237,8 @@ def _apply_stream_layer(layer: StreamLayer, x: jax.Array,
         parts = jnp.einsum("bgkd,k->bgd",
                            parts.reshape(B, groups, fan_in, d),
                            w.astype(parts.dtype),
-                           preferred_element_type=jnp.float32)
+                           preferred_element_type=jnp.float32,
+                           precision=stream_precision(parts.dtype))
     out = parts[:, 0, :] if parts.ndim == 3 else parts
     out = out + layer.bias[None, :]
     return q.make_activation(layer.activation)(out)

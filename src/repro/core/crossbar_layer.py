@@ -171,6 +171,13 @@ def program_layer(w: jax.Array, *, geom: CoreGeometry = MEMRISTOR_GEOM,
                           geom.rows, geom.cols)
 
 
+def stream_precision(dtype) -> Optional[jax.lax.Precision]:
+    """f32 stream contractions run at full precision: a TPU otherwise
+    takes one bf16 pass, and threshold activations then flip on
+    pre-activations near zero. bf16 streams keep the default pass."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+
 def crossbar_apply(params: CrossbarParams, x: jax.Array, *,
                    bias: Optional[jax.Array] = None,
                    activation: str = "linear",
@@ -203,7 +210,8 @@ def crossbar_apply(params: CrossbarParams, x: jax.Array, *,
         w_eff = ((params.gp - params.gn) *
                  params.scale[:, :, None, :]).astype(cdtype)
         out = jnp.einsum("brk,rckn->bcn", xt, w_eff,
-                         preferred_element_type=jnp.float32)
+                         preferred_element_type=jnp.float32,
+                         precision=stream_precision(cdtype))
         out = out.reshape(xt.shape[0], C * cols)[:, :params.d_out]
         if bias is not None:
             out = out + bias.astype(jnp.float32)[None, :]

@@ -189,13 +189,11 @@ def distributed_worker(verbose: bool = True) -> int:
                           "crashed": "injected"}), flush=True)
         return 3
 
-    from repro.compat import enable_cpu_collectives
-    if not enable_cpu_collectives():
-        print(json.dumps({"rank": rank, "ok": False,
-                          "error": "no CPU collectives on this jax"}),
-              flush=True)
-        return 1
     import jax
+
+    # the CPU client refuses multi-process computations unless it is
+    # built with a collectives implementation: set before initialize
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     jax.distributed.initialize(coordinator_address=f"127.0.0.1:{port}",
                                num_processes=nprocs, process_id=rank)

@@ -45,7 +45,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.chip.compile import (CompiledChip, reprogram_chip,
                                 stream_pipeline, validate_stream_rate,
                                 warn_once_deprecated)
-from repro.compat import make_array_from_process_local_data, shard_map
 from repro.launch.mesh import make_fleet_mesh, mesh_spans_processes
 from repro.obs.core import current as _obs_current
 
@@ -203,9 +202,13 @@ class ShardedChip:
                                            replication=rep)
 
                 in_specs = (P(), P(self.axis))
-            fn = jax.jit(shard_map(per_chip, mesh=self.mesh,
-                                   in_specs=in_specs,
-                                   out_specs=P(self.axis)))
+            # each chip runs its replica alone, with no collective, so
+            # there is no cross-chip typing to check; Pallas kernels in
+            # the body could not state it anyway
+            fn = jax.jit(jax.shard_map(per_chip, mesh=self.mesh,
+                                       in_specs=in_specs,
+                                       out_specs=P(self.axis),
+                                       check_vma=False))
             self._fns[(use_kernel, drift)] = fn
         return fn
 
@@ -286,7 +289,7 @@ class ShardedChip:
         if pad:
             xf = np.pad(xf, ((0, pad), (0, 0)))
         sharding = NamedSharding(self.mesh, P(self.axis))
-        xs = make_array_from_process_local_data(sharding, xf)
+        xs = jax.make_array_from_process_local_data(sharding, xf)
         age = self._age()
         if age is None:
             out = self._fn(use_kernel)(self._plan, xs)

@@ -57,21 +57,22 @@ def _kernel(x_ref, gp_ref, gn_ref, scale_ref, bias_ref, o_ref, *,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[:, 0, :]    # (Bt, rows)
-    gp = gp_ref[0, 0]     # (rows, cols)
-    gn = gn_ref[0, 0]
-    scale = scale_ref[0, 0]  # (cols,) — folded descale/Σ(σ⁺+σ⁻)
-
-    w = gp - gn
+    x = x_ref[...]            # (Bt, rows)
+    w = gp_ref[...] - gn_ref[...]   # (rows, cols)
     if x.dtype == jnp.bfloat16:
         w = w.astype(jnp.bfloat16)
-    num = jnp.dot(x, w, preferred_element_type=jnp.float32)
-    o_ref[:, 0, :] += num * scale[None, :]
+        precision = None
+    else:
+        # f32 operands: full-precision MXU passes, not one bf16 pass
+        precision = jax.lax.Precision.HIGHEST
+    num = jnp.dot(x, w, preferred_element_type=jnp.float32,
+                  precision=precision)
+    # scale: (1, cols) — folded descale/Σ(σ⁺+σ⁻)
+    o_ref[...] += num * scale_ref[...]
 
     @pl.when(pl.program_id(2) == n_rowchunks - 1)
     def _epilogue():
-        acc = o_ref[:, 0, :] + bias_ref[0][None, :]
-        o_ref[:, 0, :] = _ACTIVATIONS[activation](acc)
+        o_ref[...] = _ACTIVATIONS[activation](o_ref[...] + bias_ref[...])
 
 
 @functools.partial(jax.jit,
@@ -107,25 +108,32 @@ def crossbar_mvm(x: jax.Array, gp: jax.Array, gn: jax.Array,
     transcendentals = (x.shape[0] * C * cols
                        if activation in ("sigmoid", "tanh") else 0)
 
+    # Mosaic tiles the last two dims of every block by (8, 128) unless
+    # the block spans the whole dim, so the row-chunk and column-tile
+    # axes are kept out of the last two dims and squeezed: x is laid
+    # out (R, B, rows), the per-column vectors (…, 1, cols), and the
+    # output (C, B, cols).
+    sq = pl.squeezed
     out = pl.pallas_call(
         functools.partial(_kernel, n_rowchunks=R, activation=activation),
         grid=(nb, C, R),
         in_specs=[
-            pl.BlockSpec((bt, 1, rows), lambda b, c, r: (b, r, 0)),
-            pl.BlockSpec((1, 1, rows, cols), lambda b, c, r: (r, c, 0, 0)),
-            pl.BlockSpec((1, 1, rows, cols), lambda b, c, r: (r, c, 0, 0)),
-            pl.BlockSpec((1, 1, cols), lambda b, c, r: (r, c, 0)),
-            pl.BlockSpec((1, cols), lambda b, c, r: (c, 0)),
+            pl.BlockSpec((sq, bt, rows), lambda b, c, r: (r, b, 0)),
+            pl.BlockSpec((sq, sq, rows, cols), lambda b, c, r: (r, c, 0, 0)),
+            pl.BlockSpec((sq, sq, rows, cols), lambda b, c, r: (r, c, 0, 0)),
+            pl.BlockSpec((sq, sq, 1, cols), lambda b, c, r: (r, c, 0, 0)),
+            pl.BlockSpec((sq, 1, cols), lambda b, c, r: (c, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((bt, 1, cols), lambda b, c, r: (b, c, 0)),
-        out_shape=jax.ShapeDtypeStruct((x.shape[0], C, cols), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
+        out_specs=pl.BlockSpec((sq, bt, cols), lambda b, c, r: (c, b, 0)),
+        out_shape=jax.ShapeDtypeStruct((C, x.shape[0], cols), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(flops=flops,
                                       bytes_accessed=bytes_accessed,
                                       transcendentals=transcendentals),
         interpret=interpret,
-    )(x, gp.astype(jnp.float32), gn.astype(jnp.float32),
-      scale.astype(jnp.float32),
-      bias.astype(jnp.float32).reshape(C, cols))
-    return out[:B].reshape(B, C * cols)
+    )(x.transpose(1, 0, 2), gp.astype(jnp.float32),
+      gn.astype(jnp.float32),
+      scale.astype(jnp.float32).reshape(R, C, 1, cols),
+      bias.astype(jnp.float32).reshape(C, 1, cols))
+    return out[:, :B].transpose(1, 0, 2).reshape(B, C * cols)

@@ -26,7 +26,6 @@ from pathlib import Path
 
 import jax
 
-from repro import compat
 from repro.configs import (ARCH_IDS, SHAPES_BY_NAME, applicable, get_config,
                            get_reduced)
 from repro.launch import mesh as mesh_lib
@@ -112,7 +111,7 @@ def lower_cell(cfg, shape_cfg, mesh, *, verbose: bool = True,
         t_compile = time.time() - t0
 
     ma = compiled.memory_analysis()
-    cost = compat.cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     coll = roofline.parse_collectives(hlo, n_dev)
     flops_dev = float(cost.get("flops", 0.0))

@@ -17,7 +17,14 @@ from typing import Dict, Optional
 
 import jax
 
-from repro.compat import make_auto_mesh
+
+def make_auto_mesh(shape, axis_names):
+    """``jax.make_mesh`` with Auto axis types: the fleet and training
+    code shards through ``shard_map`` and ``NamedSharding`` and does
+    not type its values with explicit-axis shardings."""
+    return jax.make_mesh(
+        shape, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

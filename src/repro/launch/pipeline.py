@@ -30,8 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 def stage_index(axis: str = "pod"):
     return jax.lax.axis_index(axis)
@@ -101,9 +99,12 @@ def pipeline_apply(stage_fn: Callable, stage_params, x: jax.Array, *,
         return outs.reshape(B, *xs.shape[1:])
 
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
-    return shard_map(per_stage, mesh=mesh,
-                         in_specs=(pspec, P()), out_specs=P())(
-        stage_params, x)
+    # explicit-axis meshes (jax.make_mesh's default) type every traced
+    # value with its sharding, which needs the mesh in context
+    with jax.set_mesh(mesh):
+        return jax.shard_map(per_stage, mesh=mesh,
+                             in_specs=(pspec, P()), out_specs=P())(
+            stage_params, x)
 
 
 def bubble_fraction(n_stages: int, microbatches: int) -> float:

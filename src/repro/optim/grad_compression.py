@@ -24,7 +24,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -93,5 +92,5 @@ def compressed_psum(mesh, dp_axes: Tuple[str, ...], grads, error,
 
     spec = jax.tree.map(lambda _: P(), grads)
     espec = jax.tree.map(lambda _: P(), error)
-    return shard_map(body, mesh=mesh, in_specs=(spec, espec),
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, espec),
                          out_specs=(spec, espec))(grads, error)

@@ -20,7 +20,7 @@ from typing import Any, Optional, Tuple
 
 import jax
 
-from repro.compat import make_auto_mesh
+from repro.launch.mesh import make_auto_mesh
 from repro.launch.rules import make_rules
 from repro.sharding import axis_rules
 from repro.train import checkpoint as ckpt_lib

@@ -7,6 +7,7 @@ the independent costmodel assembly the Tables II–VI benchmark validates
 against the paper; and that the TDM slot schedule every compile carries
 is conflict-free per link.
 """
+import dataclasses
 import math
 
 import jax
@@ -15,7 +16,7 @@ import numpy as np
 import pytest
 
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import assume, given, settings, strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:         # property tests skip; parametrized cases run
     HAVE_HYPOTHESIS = False
@@ -39,11 +40,40 @@ def _oracle(params, spec, geom, mode, x):
     return programmed_mlp_apply(prog, x)
 
 
+# The mapped stream sums per-row-chunk partials through the combiner
+# neurons; the dense oracle contracts every row at once. Same products,
+# another float32 summation order: over a few hundred terms the two
+# differ by ~1e-6 relative. Hidden threshold activations turn such a
+# difference into a ±1 flip when a pre-activation lies that close to
+# zero, and one flip moves the output by a whole weight row. So rows
+# with an oracle pre-activation within THRESHOLD_MARGIN·max|z| of the
+# switch point (ten times the summation-order error) are left out of
+# the float comparison; the rest must agree to STREAM_RTOL.
+THRESHOLD_MARGIN = 1e-5
+STREAM_RTOL = 1e-5
+
+
+def _near_threshold(prog, x):
+    """Rows of x whose oracle pre-activation at any threshold layer
+    lies within THRESHOLD_MARGIN·max|z| of zero."""
+    near = np.zeros(x.shape[0], bool)
+    for i, act in enumerate(prog.activations):
+        if act != "threshold":
+            continue
+        head = dataclasses.replace(
+            prog, layers=prog.layers[:i + 1], biases=prog.biases[:i + 1],
+            activations=prog.activations[:i] + ("linear",))
+        z = np.abs(np.asarray(programmed_mlp_apply(head, x)))
+        near |= np.any(z <= THRESHOLD_MARGIN * z.max(), axis=1)
+    return near
+
+
 # -------------------- stream == dense oracle -------------------------- #
 def _check_stream_vs_oracle(dims, geom, batch):
     """chip.stream evaluates per-row-chunk partials through programmed
     combiner neurons (the mapped Fig. 11 dataflow), yet must agree with
-    the dense programmed oracle to float tolerance for any geometry."""
+    the dense programmed oracle to float tolerance for any geometry.
+    Returns how many rows were compared (see THRESHOLD_MARGIN)."""
     geom = CoreGeometry(*geom)
     spec = MLPSpec(tuple(dims), activation="threshold",
                    out_activation="linear")
@@ -53,7 +83,13 @@ def _check_stream_vs_oracle(dims, geom, batch):
                            minval=-1, maxval=1)
     y = chip.stream(x)
     assert y.shape == (batch, dims[-1])
-    assert _rel(y, _oracle(params, spec, geom, "crossbar", x)) <= 1e-5
+    assert bool(jnp.all(jnp.isfinite(y)))
+    prog = program_mlp(params, spec, mode="crossbar", geom=geom)
+    keep = ~_near_threshold(prog, x)
+    if keep.any():
+        assert _rel(y[keep], programmed_mlp_apply(prog, x)[keep]) \
+            <= STREAM_RTOL
+    return int(keep.sum())
 
 
 @pytest.mark.parametrize("dims,geom,batch", [
@@ -65,7 +101,7 @@ def _check_stream_vs_oracle(dims, geom, batch):
     ((65, 3), (32, 16), 1),                  # single layer, 1-col tile
 ])
 def test_stream_matches_oracle(dims, geom, batch):
-    _check_stream_vs_oracle(dims, geom, batch)
+    assert _check_stream_vs_oracle(dims, geom, batch) == batch
 
 
 if HAVE_HYPOTHESIS:
@@ -74,7 +110,7 @@ if HAVE_HYPOTHESIS:
            st.sampled_from([(16, 8), (32, 16), (128, 64)]),
            st.integers(1, 17))
     def test_stream_matches_oracle_across_geometries(dims, geom, batch):
-        _check_stream_vs_oracle(dims, geom, batch)
+        assume(_check_stream_vs_oracle(dims, geom, batch) > 0)
 
 
 def test_stream_fig11_multi_level_combiner():
@@ -109,15 +145,22 @@ def test_stream_digital_system():
 
 
 def test_stream_from_programmed_mlp_is_exact():
-    """Compiling from an already-programmed MLP reuses its tile state,
-    so the mapped stream is bit-identical to the dense oracle."""
+    """Compiling from an already-programmed MLP reuses its tile state
+    (the very same arrays), so the mapped stream differs from the dense
+    oracle only by float32 summation order (see STREAM_RTOL); no row of
+    this seeded batch sits near a threshold."""
     spec = MLPSpec((784, 200, 100, 10), activation="threshold",
                    out_activation="linear")
     params = mlp_init(jax.random.PRNGKey(0), spec)
     prog = program_mlp(params, spec, mode="crossbar")
     chip = compile_chip(prog)
+    assert all(a is b for a, b in zip(
+        jax.tree.leaves([l.tiles for l in chip.plan]),
+        jax.tree.leaves(prog.layers)))
     x = jax.random.uniform(jax.random.PRNGKey(1), (32, 784))
-    assert _rel(chip.stream(x), programmed_mlp_apply(prog, x)) == 0.0
+    assert not _near_threshold(prog, x).any()
+    assert _rel(chip.stream(x), programmed_mlp_apply(prog, x)) \
+        <= STREAM_RTOL
 
 
 def test_stream_replica_fanout_matches_single_replica():

@@ -834,9 +834,8 @@ def test_heterogeneous_two_device_subprocess(sim_subprocess):
 _DIST_WORKER = """
 import json, os
 import numpy as np
-from repro.compat import enable_cpu_collectives
-assert enable_cpu_collectives()
 import jax
+jax.config.update("jax_cpu_collectives_implementation", "gloo")
 jax.distributed.initialize(
     coordinator_address="127.0.0.1:" + os.environ["REPRO_DIST_PORT"],
     num_processes=int(os.environ["REPRO_DIST_NPROCS"]),

@@ -609,12 +609,8 @@ _LOCKSTEP_HA_WORKER = textwrap.dedent("""
     port = int(os.environ["REPRO_DIST_PORT"])
     ha_dir = os.environ["REPRO_FLEET_HA_DIR"]
 
-    from repro.compat import enable_cpu_collectives
-    if not enable_cpu_collectives():
-        print(json.dumps({"rank": rank, "ok": False,
-                          "skip": "no CPU collectives"}))
-        sys.exit(0)
     import jax
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=f"127.0.0.1:{port}",
         num_processes=nprocs, process_id=rank)
@@ -671,8 +667,6 @@ def test_lockstep_router_degrades_in_place_on_peer_death(tmp_path):
     assert victim.injected and not victim.crashed
     assert survivor.returncode == 0, survivor.stderr_tail
     out = simdev.last_json_line(survivor.stdout)
-    if out.get("skip"):
-        pytest.skip(out["skip"])
     assert out["ok"] and out["absorbed"] == [1] and out["degraded"]
     expected = set(range(6)) | {1_000_000 + k for k in range(6)}
     victim_completed = set(
