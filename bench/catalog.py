@@ -1,0 +1,91 @@
+"""Find a cell's pieces by name.
+
+Everything that belongs to one configuration, one traffic mix, one
+cell or one metric is a file of its own under the benchmark directory,
+and nothing here lists them:
+
+  configs/<config>.json   the deployment as it is run
+  traffic/<mix>.json      parameters of the general traffic generator
+  cells/<cell>.json       optional per-cell overrides of the mix (a
+                          fixed offered rate, for instance)
+  metrics/<metric>.py     a reader with ``read(ctx) -> float | None``
+
+A new configuration, mix or metric is therefore a new file plus a new
+entry in ``BENCHMARK.json``, with no edit to an existing file.
+"""
+from __future__ import annotations
+
+import importlib.util
+import json
+import os
+from typing import Any, Dict, List, Optional
+
+BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(BENCH_DIR)
+
+
+def _load_json(path: str) -> Dict[str, Any]:
+    with open(path) as f:
+        return json.load(f)
+
+
+def benchmark(root: str = ROOT) -> Dict[str, Any]:
+    """The ``BENCHMARK.json`` at the root of the checkout."""
+    return _load_json(os.path.join(root, "BENCHMARK.json"))
+
+
+def cell(bench: Dict[str, Any], name: str) -> Dict[str, Any]:
+    for entry in bench["workloads"]:
+        if entry["name"] == name:
+            return entry
+    known = sorted(e["name"] for e in bench["workloads"])
+    raise KeyError(f"no workload {name!r} in BENCHMARK.json "
+                   f"(known: {known})")
+
+
+def config(name: str, bench_dir: str = BENCH_DIR) -> Dict[str, Any]:
+    return _load_json(os.path.join(bench_dir, "configs", f"{name}.json"))
+
+
+def mix(name: str, cell_name: Optional[str] = None,
+        bench_dir: str = BENCH_DIR) -> Dict[str, Any]:
+    """The traffic mix's parameters, with the cell's own overrides
+    (``cells/<cell>.json``, when there is one) laid over them."""
+    params = _load_json(os.path.join(bench_dir, "traffic", f"{name}.json"))
+    if cell_name is not None:
+        path = os.path.join(bench_dir, "cells", f"{cell_name}.json")
+        if os.path.exists(path):
+            params.update(_load_json(path))
+    return params
+
+
+def reader(metric: str, bench_dir: str = BENCH_DIR):
+    """The ``read(ctx)`` function of ``metrics/<metric>.py``. Metric
+    names carry dots, so the file is loaded by path, not imported."""
+    path = os.path.join(bench_dir, "metrics", f"{metric}.py")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_metric_{metric.replace('.', '_').replace('-', '_')}", path)
+    if spec is None or spec.loader is None:
+        raise FileNotFoundError(path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.read
+
+
+def metrics_for(bench: Dict[str, Any], cell_name: str,
+                kind: str) -> List[Dict[str, Any]]:
+    """The ``end_to_end`` or ``per_layer`` entries this cell reports:
+    those without a ``workloads`` list, and those whose list names
+    it."""
+    return [m for m in bench[kind]
+            if "workloads" not in m or cell_name in m["workloads"]]
+
+
+def peaks(device_kind: str, bench_dir: str = BENCH_DIR) -> Dict[str, float]:
+    """Published peaks of one chip of ``device_kind``; a kind that is
+    not in the table is an error, never a default."""
+    table = _load_json(os.path.join(bench_dir, "peaks.json"))["devices"]
+    if device_kind not in table:
+        raise KeyError(f"no peaks for device kind {device_kind!r} in "
+                       f"peaks.json (known: {sorted(table)})")
+    return table[device_kind]
