@@ -49,7 +49,7 @@ from typing import Any, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 
-from repro.obs.core import current as _obs_current
+from repro.obs.core import current as _obs_current, span as _obs_span
 
 from repro.core import routing as routing_lib
 from repro.core.crossbar_layer import (CrossbarParams, DigitalParams,
@@ -395,28 +395,19 @@ class CompiledChip:
         age = None
         if self.has_drift:
             age = jnp.asarray(float(self.items_streamed), jnp.float32)
+        # program-vs-stream economics, measured: the traced stream
+        # span carries the chip's compile_count delta (pinned 0 — a
+        # stream must never re-run the program pass) next to the
+        # per-batch wall time the compile span prices against
         tel = _obs_current()
-        if not tel.active:
+        c0 = _COMPILE_COUNT
+        with _obs_span("chip.stream", cat="chip", rows=int(xf.shape[0]),
+                       system=self.system) as sp:
             out = _stream(self.plan, xf, use_kernel=use_kernel,
                           replication=rep, age=age)
-        else:
-            # program-vs-stream economics, measured: the stream span
-            # carries the chip's compile_count delta (pinned 0 — a
-            # stream must never re-run the program pass) next to the
-            # per-batch wall time the compile span prices against
-            t0 = time.perf_counter()
-            c0 = _COMPILE_COUNT
-            out = _stream(self.plan, xf, use_kernel=use_kernel,
-                          replication=rep, age=age)
-            jax.block_until_ready(out)
-            dur = time.perf_counter() - t0
-            tel.tracer.complete(
-                "chip.stream", t0, dur, tid=0, cat="chip",
-                args={"rows": int(xf.shape[0]), "system": self.system,
-                      "compile_delta": _COMPILE_COUNT - c0})
-            tel.metrics.counter("chip.items_streamed").inc(
-                int(xf.shape[0]))
-            tel.metrics.histogram("chip.stream_s").record(dur)
+            if tel.active:
+                jax.block_until_ready(out)
+                sp.args["compile_delta"] = _COMPILE_COUNT - c0
         if age is not None and advance_age:
             self.advance_age(xf.shape[0])
         return out.reshape(*lead, out.shape[-1]).astype(x.dtype)
@@ -675,7 +666,6 @@ def compile_chip(networks: NetworksLike, *,
                                   "dims": list(dims) if dims else None,
                                   "streamable": plan is not None})
         tel.metrics.counter("chip.compiles").inc()
-        tel.metrics.gauge("chip.compile_count").set(_COMPILE_COUNT)
         tel.metrics.histogram("chip.compile_s").record(dur)
     return chip
 

@@ -34,7 +34,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 from typing import Dict, Optional
 
 import jax
@@ -46,7 +45,7 @@ from repro.chip.compile import (CompiledChip, reprogram_chip,
                                 stream_pipeline, validate_stream_rate,
                                 warn_once_deprecated)
 from repro.launch.mesh import make_fleet_mesh, mesh_spans_processes
-from repro.obs.core import current as _obs_current
+from repro.obs.core import current as _obs_current, span as _obs_span
 
 
 def replicate_to_mesh(tree, mesh: jax.sharding.Mesh):
@@ -74,6 +73,27 @@ def replicate_to_mesh(tree, mesh: jax.sharding.Mesh):
             x.shape, sharding, shards)
 
     return jax.tree_util.tree_map(leaf, tree)
+
+
+def _stage(x, parts: int):
+    """The host half of a scatter: x (..., d_in) as float32 rows,
+    zero-padded to a multiple of ``parts``. Returns (padded rows, lead
+    shape, item count, pad row count)."""
+    xf = np.asarray(x, np.float32)
+    lead = xf.shape[:-1]
+    xf = xf.reshape(-1, xf.shape[-1])
+    n = xf.shape[0]
+    pad = math.ceil(max(n, 1) / parts) * parts - n
+    if pad:
+        xf = np.pad(xf, ((0, pad), (0, 0)))
+    return xf, lead, n, pad
+
+
+def _read_local_shards(out: jax.Array) -> np.ndarray:
+    """This process's output shards, read back in row order."""
+    shards = sorted(out.addressable_shards,
+                    key=lambda s: s.index[0].start or 0)
+    return np.concatenate([np.asarray(s.data) for s in shards])
 
 
 @dataclasses.dataclass
@@ -212,6 +232,54 @@ class ShardedChip:
             self._fns[(use_kernel, drift)] = fn
         return fn
 
+    def _member_call(self, name: str, x, parts: int, place, read_back,
+                     use_kernel: bool, **args) -> np.ndarray:
+        """One host-to-host member call: stage ``x`` into float32 rows
+        padded to ``parts``, ``place`` them on the mesh, run the stream
+        program, ``read_back`` its outputs to the host and drop the pad
+        rows. Under drift the program evaluates at the fleet's age and
+        the clock then advances by this call's rows (on a multi-process
+        mesh each process advances its own copy by its OWN rows; SPMD
+        symmetry, equal local rows per call, keeps the copies in
+        agreement).
+
+        With telemetry on, the call is the ``name`` span tiled by four:
+        ``fleet.stage`` (cast, reshape, host pad), ``fleet.h2d`` (the
+        placement), ``fleet.program`` (dispatch to done) and
+        ``fleet.d2h`` (read-back, pad rows dropped). The placement and
+        the program are waited on inside their own spans: both are
+        asynchronous, and their time would otherwise land in the span
+        that waits next. The counters ``fleet.h2d_bytes`` and
+        ``fleet.d2h_bytes`` (pad rows included) and ``fleet.pad_rows``
+        count what moved. Off, nothing waits and nothing is counted."""
+        tel = _obs_current()
+        with _obs_span(name, cat="fleet", **args) as sp:
+            with _obs_span("fleet.stage", cat="fleet"):
+                xf, lead, rows, pad = _stage(x, parts)
+            with _obs_span("fleet.h2d", cat="fleet"):
+                xs = place(xf)
+                if tel.active:
+                    xs.block_until_ready()
+            with _obs_span("fleet.program", cat="fleet"):
+                age = self._age()
+                if age is None:
+                    out = self._fn(use_kernel)(self._plan, xs)
+                else:
+                    out = self._fn(use_kernel, True)(self._plan, xs, age)
+                    self.chip.advance_age(rows)
+                if tel.active:
+                    out.block_until_ready()
+            with _obs_span("fleet.d2h", cat="fleet"):
+                host = read_back(out)
+                y = host[:rows]
+            if tel.active:
+                sp.args["rows"] = int(rows)
+                m = tel.metrics
+                m.counter("fleet.h2d_bytes").inc(int(xf.nbytes))
+                m.counter("fleet.d2h_bytes").inc(int(host.nbytes))
+                m.counter("fleet.pad_rows").inc(int(pad))
+        return y.reshape(*lead, y.shape[-1])
+
     def stream_host(self, x, *, use_kernel: bool = False) -> np.ndarray:
         """Host-to-host fleet stream: x (..., d_in) → (..., d_out) as a
         float32 numpy array — the serving hot path.
@@ -224,7 +292,8 @@ class ShardedChip:
         output would dispatch a second cross-chip computation — each
         measured in the ms/step range on the CPU client vs ~0.1 ms for
         the host scatter/gather, i.e. the difference between the fleet
-        scaling and not.
+        scaling and not. Traced, the call is the ``fleet.stream_host``
+        span and its four children (:meth:`_member_call`).
         """
         if self.is_distributed:
             raise ValueError(
@@ -233,31 +302,11 @@ class ShardedChip:
                 f"{len({d.process_index for d in self.mesh.devices.flat})} "
                 "processes. Use stream_local(x_local): every process "
                 "passes its own rows and reads back its own outputs.")
-        tel = _obs_current()
-        t0 = time.perf_counter() if tel.active else 0.0
-        xf = np.asarray(x, np.float32)
-        lead = xf.shape[:-1]
-        xf = xf.reshape(-1, xf.shape[-1])
-        B = xf.shape[0]
-        per = math.ceil(max(B, 1) / self.n_chips)
-        pad = per * self.n_chips - B
-        if pad:
-            xf = np.pad(xf, ((0, pad), (0, 0)))
-        xs = jax.device_put(
-            xf, NamedSharding(self.mesh, P(self.axis)))
-        age = self._age()
-        if age is None:
-            out = np.asarray(self._fn(use_kernel)(self._plan, xs))[:B]
-        else:
-            out = np.asarray(
-                self._fn(use_kernel, True)(self._plan, xs, age))[:B]
-            self.chip.advance_age(B)
-        if tel.active:
-            tel.tracer.complete(
-                "fleet.stream_host", t0, time.perf_counter() - t0,
-                tid=0, cat="fleet",
-                args={"rows": int(B), "chips": self.n_chips})
-        return out.reshape(*lead, out.shape[-1])
+        sharding = NamedSharding(self.mesh, P(self.axis))
+        return self._member_call(
+            "fleet.stream_host", x, self.n_chips,
+            lambda xf: jax.device_put(xf, sharding), np.asarray,
+            use_kernel, chips=self.n_chips)
 
     def stream_local(self, x, *, use_kernel: bool = False) -> np.ndarray:
         """Process-local scatter/gather: x (..., d_in) is THIS
@@ -275,39 +324,15 @@ class ShardedChip:
 
         Single-process it is equivalent to :meth:`stream_host` (one
         process owns all rows), which keeps the tier-1 suite able to
-        pin its semantics without spawning a cluster.
+        pin its semantics without spawning a cluster. Traced, the call
+        is the ``fleet.stream_local`` span and the same four children.
         """
-        tel = _obs_current()
-        t0 = time.perf_counter() if tel.active else 0.0
-        xf = np.asarray(x, np.float32)
-        lead = xf.shape[:-1]
-        xf = xf.reshape(-1, xf.shape[-1])
-        B = xf.shape[0]
-        n_local = self.n_local_chips
-        per = math.ceil(max(B, 1) / n_local)
-        pad = per * n_local - B
-        if pad:
-            xf = np.pad(xf, ((0, pad), (0, 0)))
         sharding = NamedSharding(self.mesh, P(self.axis))
-        xs = jax.make_array_from_process_local_data(sharding, xf)
-        age = self._age()
-        if age is None:
-            out = self._fn(use_kernel)(self._plan, xs)
-        else:
-            # each process advances its own copy of the clock by its
-            # OWN rows; SPMD symmetry (equal local rows per call)
-            # keeps the replicas' ages in agreement
-            out = self._fn(use_kernel, True)(self._plan, xs, age)
-            self.chip.advance_age(B)
-        shards = sorted(out.addressable_shards,
-                        key=lambda s: s.index[0].start or 0)
-        y = np.concatenate([np.asarray(s.data) for s in shards])[:B]
-        if tel.active:
-            tel.tracer.complete(
-                "fleet.stream_local", t0, time.perf_counter() - t0,
-                tid=0, cat="fleet",
-                args={"rows": int(B), "local_chips": n_local})
-        return y.reshape(*lead, y.shape[-1])
+        n_local = self.n_local_chips
+        return self._member_call(
+            "fleet.stream_local", x, n_local,
+            lambda xf: jax.make_array_from_process_local_data(sharding, xf),
+            _read_local_shards, use_kernel, local_chips=n_local)
 
     def stream(self, x: jax.Array, *,
                use_kernel: bool = False) -> jax.Array:
@@ -315,10 +340,16 @@ class ShardedChip:
         rows dealt across chips, each chip running the mapped dataflow
         on its shard (see :meth:`stream_host`, which this wraps —
         host-side consumers like the router use it directly to skip
-        the device round-trip of this jax-array return)."""
+        the device round-trip of this jax-array return). Traced, the
+        upload of the result is the ``fleet.result_to_device`` span."""
         dtype = x.dtype if hasattr(x, "dtype") else jnp.float32
-        return jnp.asarray(self.stream_host(x, use_kernel=use_kernel),
-                           dtype)
+        y = self.stream_host(x, use_kernel=use_kernel)
+        tel = _obs_current()
+        with _obs_span("fleet.result_to_device", cat="fleet"):
+            out = jnp.asarray(y, dtype)
+            if tel.active:
+                out.block_until_ready()
+        return out
 
     def __call__(self, x: jax.Array, **kw) -> jax.Array:
         return self.stream(x, **kw)

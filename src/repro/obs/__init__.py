@@ -26,7 +26,7 @@ This package never imports jax at module scope, so ``python -m
 repro.obs --selftest`` can pin simulated-device XLA flags first.
 """
 from repro.obs.core import (NULL_RECORDER, NullRecorder, StepRecorder,
-                            Telemetry, configure, current, disable)
+                            Telemetry, configure, current, disable, span)
 from repro.obs.metrics import (DEFAULT_RESERVOIR, Counter, Gauge,
                                Histogram, MetricsRegistry, Reservoir,
                                merge_snapshots)
@@ -37,7 +37,7 @@ __all__ = [
     "LANE_TID_BASE", "MetricsRegistry", "NULL_RECORDER",
     "NullRecorder", "Reservoir", "StepRecorder", "Telemetry",
     "Tracer", "allgather_snapshots", "configure", "current",
-    "disable", "merge_snapshots",
+    "disable", "merge_snapshots", "span",
 ]
 
 

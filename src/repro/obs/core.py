@@ -15,6 +15,12 @@ device_step, gather, finish) with ``phase(...)``, and ``close()``
 emits the step span plus per-phase histograms — the measured
 scatter/compute/gather split ROADMAP item 4 is gated on.
 ``NULL_RECORDER`` is its inert twin for the un-traced path.
+
+:func:`span` is the one span primitive: while telemetry is active it
+records a Chrome ``"X"`` event AND opens a
+``jax.profiler.TraceAnnotation`` of the same name around the region,
+so the span also lands on the profiler's host plane, on the same clock
+as the device's ``XLA Ops``. Step phases go through it too.
 """
 from __future__ import annotations
 
@@ -82,30 +88,39 @@ def disable() -> None:
 
 
 # ------------------------------------------------------------------- #
-# per-engine-step phase recording
+# spans: the tracer's event and the profiler's annotation, one region
 # ------------------------------------------------------------------- #
-class _Phase:
-    __slots__ = ("rec", "name", "args", "t0")
+def _profiler():
+    """``jax.profiler``, imported on first traced use only (this
+    package stays free of jax at import)."""
+    import jax.profiler
+    return jax.profiler
 
-    def __init__(self, rec: "StepRecorder", name: str, args):
-        self.rec = rec
+
+class _Span:
+    __slots__ = ("tracer", "name", "cat", "args", "t0", "dur", "_ann")
+
+    def __init__(self, tracer: Tracer, name: str, cat: str, args: dict):
+        self.tracer = tracer
         self.name = name
+        self.cat = cat
         self.args = args
 
     def __enter__(self):
+        self._ann = _profiler().TraceAnnotation(self.name)
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        dur = time.perf_counter() - self.t0
-        rec = self.rec
-        rec.phases[self.name] = rec.phases.get(self.name, 0.0) + dur
-        rec.tel.tracer.complete(self.name, self.t0, dur, tid=0,
-                                cat="phase", args=self.args)
+        self.dur = time.perf_counter() - self.t0
+        self._ann.__exit__(*exc)
+        self.tracer.complete(self.name, self.t0, self.dur, tid=0,
+                             cat=self.cat, args=self.args)
         return False
 
 
-class _NullPhase:
+class _NullSpan:
     __slots__ = ()
 
     def __enter__(self):
@@ -115,7 +130,39 @@ class _NullPhase:
         return False
 
 
-_NULL_PHASE = _NullPhase()
+_NULL_SPAN = _NullSpan()
+
+
+def span(name: str, *, cat: str, **args):
+    """Context manager timing one region of the host's work.
+
+    Inactive telemetry: the shared null context, nothing recorded and
+    ``jax.profiler`` never touched. Active: one complete ``"X"`` event
+    on the tracer (``args`` as its args; the body may add to
+    ``.args``) inside a ``jax.profiler.TraceAnnotation`` of the same
+    name, so a profiler trace places the region beside the device's
+    operations."""
+    tel = _CURRENT
+    if not tel.active:
+        return _NULL_SPAN
+    return _Span(tel.tracer, name, cat, args)
+
+
+# ------------------------------------------------------------------- #
+# per-engine-step phase recording
+# ------------------------------------------------------------------- #
+class _Phase(_Span):
+    __slots__ = ("rec",)
+
+    def __init__(self, rec: "StepRecorder", name: str, args):
+        super().__init__(rec.tel.tracer, name, "phase", args)
+        self.rec = rec
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        phases = self.rec.phases
+        phases[self.name] = phases.get(self.name, 0.0) + self.dur
+        return False
 
 
 class NullRecorder:
@@ -124,7 +171,7 @@ class NullRecorder:
     phases: dict = {}
 
     def phase(self, name: str, **args):
-        return _NULL_PHASE
+        return _NULL_SPAN
 
 
 NULL_RECORDER = NullRecorder()
@@ -141,7 +188,7 @@ class StepRecorder:
     def phase(self, name: str, **args):
         """Bracket one named phase of the step; re-entering a name
         accumulates (``device_step`` runs once per payload key)."""
-        return _Phase(self, name, args or None)
+        return _Phase(self, name, args)
 
     def close(self, t0: float, *, emitted: int, step: int,
               idle: bool = False) -> None:

@@ -344,3 +344,182 @@ def test_subprocess_serve_counters_deterministic(sim_subprocess):
     assert first["counters"]["engine.items"] == \
         sum(2 + i % 3 for i in range(5))
     assert first["counters"]["engine.requests_finished|key=app"] == 5
+
+
+# ------------------------------------------------------------------- #
+# the span primitive: tracer event + profiler annotation
+# ------------------------------------------------------------------- #
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``, logging every
+    enter and exit by name."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        log = self.log
+
+        class _Ann:
+            def __enter__(self):
+                log.append(("enter", name))
+                return self
+
+            def __exit__(self, *exc):
+                log.append(("exit", name))
+                return False
+
+        return _Ann()
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    import jax.profiler
+
+    fake = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", fake)
+    return fake
+
+
+def test_span_disabled_is_shared_null_context(monkeypatch):
+    import jax.profiler
+
+    def refuse(name):
+        raise AssertionError(f"TraceAnnotation({name!r}) while disabled")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    obs.disable()
+    a = obs.span("fleet.h2d", cat="fleet", rows=3)
+    b = obs.span("fleet.d2h", cat="fleet")
+    assert a is b
+    with a as inner:
+        with b:
+            pass
+    assert inner is a
+    assert obs.current().tracer.trace_events() == []
+    assert obs.current().metrics.snapshot() == \
+        MetricsRegistry(enabled=False).snapshot()
+
+
+def test_span_enabled_records_one_event_and_one_annotation(tel,
+                                                          annotations):
+    with obs.span("fleet.h2d", cat="fleet", rows=3) as sp:
+        sp.args["extra"] = 1
+    ev, = tel.tracer.trace_events()
+    assert ev["name"] == "fleet.h2d" and ev["ph"] == "X"
+    assert ev["cat"] == "fleet" and ev["tid"] == 0 and ev["dur"] >= 0
+    assert ev["args"] == {"rows": 3, "extra": 1}
+    assert annotations.log == [("enter", "fleet.h2d"),
+                               ("exit", "fleet.h2d")]
+
+
+def test_step_phases_open_profiler_annotations(tel, annotations):
+    eng = _echo()
+    _drive(eng)
+    opened = {name for kind, name in annotations.log if kind == "enter"}
+    assert {"admit", "dispatch", "device_step", "gather",
+            "finish"} <= opened
+    n_phases = sum(1 for e in tel.tracer.trace_events()
+                   if e.get("cat") == "phase")
+    assert sum(1 for kind, _ in annotations.log
+               if kind == "enter") == n_phases
+
+
+# ------------------------------------------------------------------- #
+# the fleet member call, split into child spans
+# ------------------------------------------------------------------- #
+MEMBER_CHILDREN = ("fleet.stage", "fleet.h2d", "fleet.program",
+                   "fleet.d2h")
+
+
+@pytest.fixture(scope="module")
+def member():
+    import jax
+
+    from repro.chip import compile_chip
+    from repro.core.crossbar_layer import MLPSpec, mlp_init
+    from repro.fleet import shard_chip
+
+    spec = MLPSpec((64, 32, 10), activation="threshold",
+                   out_activation="linear")
+    chip = compile_chip(spec, params=mlp_init(jax.random.PRNGKey(0), spec))
+    return shard_chip(chip, 1)
+
+
+def _items(rows, seed=3):
+    return np.random.default_rng(seed).uniform(
+        -1, 1, (rows, 64)).astype(np.float32)
+
+
+@pytest.mark.parametrize("verb, parent", [
+    ("stream_host", "fleet.stream_host"),
+    ("stream_local", "fleet.stream_local")])
+def test_member_child_spans_tile_the_call(member, tel, verb, parent):
+    call = getattr(member, verb)
+    x = _items(8192)
+    call(x)                                   # compiled before timing
+    n0 = len(tel.tracer.trace_events())
+    for _ in range(4):
+        call(x)
+    evs = [e for e in tel.tracer.trace_events()[n0:] if e["ph"] == "X"]
+    parents = [e for e in evs if e["name"] == parent]
+    assert len(parents) == 4
+    assert all(e["cat"] == "fleet" and e["args"]["rows"] == 8192
+               for e in parents)
+    for name in MEMBER_CHILDREN:
+        kids = [e for e in evs if e["name"] == name]
+        assert len(kids) == 4 and all(e["cat"] == "fleet" for e in kids)
+        # each child nests inside one call of the parent
+        for k in kids:
+            assert any(p["ts"] <= k["ts"] and
+                       k["ts"] + k["dur"] <= p["ts"] + p["dur"] + 1e-3
+                       for p in parents)
+    total = sum(e["dur"] for e in parents)
+    kids = sum(e["dur"] for e in evs if e["name"] in MEMBER_CHILDREN)
+    assert 0.90 <= kids / total <= 1.0 + 1e-9, kids / total
+    assert not any(e["cat"] == "phase" for e in evs)
+
+
+def test_member_byte_counters_are_exact(member, tel):
+    rows = 37
+    pad = -rows % member.n_chips
+    member.stream_host(_items(rows))
+    member.stream_host(_items(rows, seed=4))
+    c = tel.metrics.snapshot()["counters"]
+    padded = rows + pad
+    assert c["fleet.h2d_bytes"] == 2 * padded * member.d_in * 4
+    assert c["fleet.d2h_bytes"] == 2 * padded * member.d_out * 4
+    assert c["fleet.pad_rows"] == 2 * pad
+
+
+def test_member_traced_and_untraced_outputs_bit_identical(member):
+    x = _items(300, seed=5)
+    obs.disable()
+    plain_host = member.stream_host(x)
+    plain_dev = np.asarray(member.stream(x))
+    tel = obs.configure()
+    try:
+        traced_host = member.stream_host(x)
+        traced_dev = np.asarray(member.stream(x))
+        names = [e["name"] for e in tel.tracer.trace_events()]
+    finally:
+        obs.disable()
+    assert np.array_equal(plain_host, traced_host)
+    assert np.array_equal(plain_dev, traced_dev)
+    assert np.array_equal(plain_host, plain_dev)
+    assert names.count("fleet.result_to_device") == 1
+    assert names.count("fleet.stream_host") == 2
+
+
+def test_chip_stream_span_through_the_primitive(member, tel, annotations):
+    import jax.numpy as jnp
+
+    member.chip.stream(jnp.asarray(_items(16)))
+    ev, = [e for e in tel.tracer.trace_events()
+           if e["name"] == "chip.stream"]
+    assert ev["cat"] == "chip" and ev["args"]["rows"] == 16
+    assert ev["args"]["compile_delta"] == 0
+    assert ("enter", "chip.stream") in annotations.log
+    snap = tel.metrics.snapshot()
+    assert "chip.stream_s" not in snap["histograms"]
+    assert "chip.items_streamed" not in snap["counters"]
+    assert "chip.compile_count" not in snap["gauges"]
