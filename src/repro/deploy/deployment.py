@@ -310,7 +310,9 @@ class Deployment:
     def stream(self, app: str, x, *, use_kernel: Optional[bool] = None):
         """One-shot batch through ``app``'s fleet placement — identical
         arithmetic to the legacy ``shard_chip(...).stream`` path (the
-        member IS a ShardedChip), hence rel 0.0 against it."""
+        member IS a ShardedChip), hence rel 0.0 against it. Returns
+        :meth:`ShardedChip.stream`'s device array, or, multi-process,
+        this rank's rows as numpy (``stream_local``)."""
         m = self._streaming_member(app)
         if getattr(m.sharded, "is_lm", False):
             raise TypeError(

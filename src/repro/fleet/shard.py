@@ -33,6 +33,7 @@ multi-host, with two changes this module owns:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Dict, Optional
 
@@ -89,6 +90,14 @@ def _stage(x, parts: int):
     return xf, lead, n, pad
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _first_rows(y: jax.Array, n: int) -> jax.Array:
+    """The first ``n`` rows of a device array, cut on its devices: one
+    compiled program per row count, several times cheaper to dispatch
+    than the eager slice of a sharded array."""
+    return y[:n]
+
+
 def _read_local_shards(out: jax.Array) -> np.ndarray:
     """This process's output shards, read back in row order."""
     shards = sorted(out.addressable_shards,
@@ -100,13 +109,13 @@ def _read_local_shards(out: jax.Array) -> np.ndarray:
 class ShardedChip:
     """One compiled chip served as ``n_chips`` identical fleet members.
 
-    ``stream`` pads the batch to a multiple of the fleet size, deals it
-    across the mesh's ``"chip"`` axis, runs the mapped dataflow on every
-    device, and concatenates — semantically identical to the single
-    chip, ``n_chips``× the lanes. ``serve``/``report`` mirror the
-    CompiledChip verbs at fleet scale. On a multi-process mesh use
-    ``stream_local`` (and ``serve(distributed=True)``); see the module
-    docstring.
+    ``stream`` deals the batch across the mesh's ``"chip"`` axis (padded
+    to a multiple of the fleet size where it must be), runs the mapped
+    dataflow on every device, and returns the outputs as one array —
+    semantically identical to the single chip, ``n_chips``× the lanes.
+    ``serve``/``report`` mirror the CompiledChip verbs at fleet scale.
+    On a multi-process mesh use ``stream_local`` (and
+    ``serve(distributed=True)``); see the module docstring.
 
     ``items_per_second`` is an optional FLEET-level target rate: the
     compile already validated the chip's own target against its routed
@@ -232,26 +241,46 @@ class ShardedChip:
             self._fns[(use_kernel, drift)] = fn
         return fn
 
-    def _member_call(self, name: str, x, parts: int, place, read_back,
-                     use_kernel: bool, **args) -> np.ndarray:
-        """One host-to-host member call: stage ``x`` into float32 rows
-        padded to ``parts``, ``place`` them on the mesh, run the stream
-        program, ``read_back`` its outputs to the host and drop the pad
-        rows. Under drift the program evaluates at the fleet's age and
-        the clock then advances by this call's rows (on a multi-process
-        mesh each process advances its own copy by its OWN rows; SPMD
-        symmetry, equal local rows per call, keeps the copies in
-        agreement).
+    def _refuse_distributed(self) -> None:
+        if self.is_distributed:
+            raise ValueError(
+                "stream/stream_host need every fleet device to be "
+                "addressable from this process, but the mesh spans "
+                f"{len({d.process_index for d in self.mesh.devices.flat})} "
+                "processes. Use stream_local(x_local): every process "
+                "passes its own rows and reads back its own outputs.")
 
-        With telemetry on, the call is the ``name`` span tiled by four:
+    def _put(self, xf: np.ndarray) -> jax.Array:
+        """Staged host rows straight into the fleet layout, one slice
+        per chip."""
+        return jax.device_put(xf, NamedSharding(self.mesh, P(self.axis)))
+
+    def _member_call(self, name: str, x, parts: int, place, read_back,
+                     use_kernel: bool, **args):
+        """One member call: stage ``x`` into float32 rows padded to
+        ``parts``, ``place`` them on the mesh, run the stream program,
+        then ``read_back`` its outputs to the host and drop the pad
+        rows, or, with ``read_back=None``, drop them on the device and
+        return the program's output there once it is ready: the
+        placement may alias the caller's host buffer (the CPU client
+        does, for aligned float32 rows), so the program must have read
+        it before the call returns. Under drift the program evaluates
+        at the fleet's age and the clock then advances by this call's
+        rows (on a multi-process mesh each process advances its own
+        copy by its OWN rows; SPMD symmetry, equal local rows per call,
+        keeps the copies in agreement).
+
+        With telemetry on, the call is the ``name`` span tiled by
         ``fleet.stage`` (cast, reshape, host pad), ``fleet.h2d`` (the
-        placement), ``fleet.program`` (dispatch to done) and
+        placement), ``fleet.program`` (dispatch to done; without a
+        read-back, pad rows dropped) and, for a read-back,
         ``fleet.d2h`` (read-back, pad rows dropped). The placement and
         the program are waited on inside their own spans: both are
         asynchronous, and their time would otherwise land in the span
         that waits next. The counters ``fleet.h2d_bytes`` and
         ``fleet.d2h_bytes`` (pad rows included) and ``fleet.pad_rows``
-        count what moved. Off, nothing waits and nothing is counted."""
+        count what moved. Off, nothing is counted and only a call
+        without a read-back waits, on its program."""
         tel = _obs_current()
         with _obs_span(name, cat="fleet", **args) as sp:
             with _obs_span("fleet.stage", cat="fleet"):
@@ -267,18 +296,25 @@ class ShardedChip:
                 else:
                     out = self._fn(use_kernel, True)(self._plan, xs, age)
                     self.chip.advance_age(rows)
-                if tel.active:
+                if read_back is None and pad:
+                    out = _first_rows(out, rows)
+                if tel.active or read_back is None:
                     out.block_until_ready()
-            with _obs_span("fleet.d2h", cat="fleet"):
-                host = read_back(out)
-                y = host[:rows]
+            y = out
+            if read_back is not None:
+                with _obs_span("fleet.d2h", cat="fleet"):
+                    host = read_back(out)
+                    y = host[:rows]
             if tel.active:
                 sp.args["rows"] = int(rows)
                 m = tel.metrics
                 m.counter("fleet.h2d_bytes").inc(int(xf.nbytes))
-                m.counter("fleet.d2h_bytes").inc(int(host.nbytes))
                 m.counter("fleet.pad_rows").inc(int(pad))
-        return y.reshape(*lead, y.shape[-1])
+                if read_back is not None:
+                    m.counter("fleet.d2h_bytes").inc(int(host.nbytes))
+        if len(lead) != 1:
+            y = y.reshape(*lead, y.shape[-1])
+        return y
 
     def stream_host(self, x, *, use_kernel: bool = False) -> np.ndarray:
         """Host-to-host fleet stream: x (..., d_in) → (..., d_out) as a
@@ -295,18 +331,10 @@ class ShardedChip:
         scaling and not. Traced, the call is the ``fleet.stream_host``
         span and its four children (:meth:`_member_call`).
         """
-        if self.is_distributed:
-            raise ValueError(
-                "stream/stream_host need every fleet device to be "
-                "addressable from this process, but the mesh spans "
-                f"{len({d.process_index for d in self.mesh.devices.flat})} "
-                "processes. Use stream_local(x_local): every process "
-                "passes its own rows and reads back its own outputs.")
-        sharding = NamedSharding(self.mesh, P(self.axis))
-        return self._member_call(
-            "fleet.stream_host", x, self.n_chips,
-            lambda xf: jax.device_put(xf, sharding), np.asarray,
-            use_kernel, chips=self.n_chips)
+        self._refuse_distributed()
+        return self._member_call("fleet.stream_host", x, self.n_chips,
+                                 self._put, np.asarray, use_kernel,
+                                 chips=self.n_chips)
 
     def stream_local(self, x, *, use_kernel: bool = False) -> np.ndarray:
         """Process-local scatter/gather: x (..., d_in) is THIS
@@ -336,20 +364,29 @@ class ShardedChip:
 
     def stream(self, x: jax.Array, *,
                use_kernel: bool = False) -> jax.Array:
-        """Stream a batch through the fleet: x (..., d_in) → (..., d_out),
-        rows dealt across chips, each chip running the mapped dataflow
-        on its shard (see :meth:`stream_host`, which this wraps —
-        host-side consumers like the router use it directly to skip
-        the device round-trip of this jax-array return). Traced, the
-        upload of the result is the ``fleet.result_to_device`` span."""
-        dtype = x.dtype if hasattr(x, "dtype") else jnp.float32
-        y = self.stream_host(x, use_kernel=use_kernel)
-        tel = _obs_current()
-        with _obs_span("fleet.result_to_device", cat="fleet"):
-            out = jnp.asarray(y, dtype)
-            if tel.active:
-                out.block_until_ready()
-        return out
+        """Stream a batch through the fleet: x (..., d_in) → (..., d_out)
+        as a ``jax.Array``, rows dealt across chips, each chip running
+        the mapped dataflow on its shard.
+
+        The program's output is returned where it is, on the member's
+        own devices, with no read-back and no re-upload. The call
+        returns when the program is done, so ``x`` may be overwritten
+        as soon as it returns. When the rows divide across the chips
+        (always, on one chip) the result stays sharded over the mesh;
+        ragged rows are cut to size on the same chips, in a layout the
+        compiler picks (replicated for an odd row count). The result is
+        committed to those devices, so combining it with the output of a member
+        on other devices needs a ``jax.device_put`` first. Traced, the
+        call is the ``fleet.stream`` span tiled by ``fleet.stage``,
+        ``fleet.h2d`` and ``fleet.program``. Host-side consumers like
+        the router call :meth:`stream_host` directly."""
+        self._refuse_distributed()
+        dtype = jax.dtypes.canonicalize_dtype(
+            x.dtype if hasattr(x, "dtype") else jnp.float32)
+        out = self._member_call("fleet.stream", x, self.n_chips,
+                                self._put, None, use_kernel,
+                                chips=self.n_chips)
+        return out if out.dtype == dtype else out.astype(dtype)
 
     def __call__(self, x: jax.Array, **kw) -> jax.Array:
         return self.stream(x, **kw)

@@ -154,6 +154,43 @@ def test_single_app_deploy_matches_legacy_path(system, spec_a,
     d.close()
 
 
+@pytest.mark.parametrize("system", ["memristor", "digital"])
+def test_stream_returns_device_array_identical_to_stream_host(
+        system, spec_a, params_a):
+    chip = compile_chip(spec_a, params=params_a, system=system)
+    fleet = shard_chip(chip, 1)
+    d = deploy(AppSpec("app", spec_a, params=params_a, system=system))
+    x = np.asarray(jax.random.uniform(jax.random.PRNGKey(4),
+                                      (64, DIMS_A[0])), np.float32)
+    host = fleet.stream_host(x)
+    for y in (d.stream("app", x), fleet.stream(x), fleet(x)):
+        assert isinstance(y, jax.Array) and y.dtype == np.float32
+        assert y.shape == host.shape
+        assert np.array_equal(np.asarray(y), host)
+    d.close()
+
+
+def test_stream_input_may_be_overwritten_on_return(spec_a, params_a):
+    # a frame buffer reused at once: 64-byte aligned float32 rows, which
+    # the CPU client places without a copy, so the call has to be done
+    # with them before it returns
+    d = deploy(AppSpec("app", spec_a, params=params_a))
+    rows, width = 4096, DIMS_A[0]
+    buf = np.empty(rows * width * 4 + 64, np.uint8)
+    off = -buf.ctypes.data % 64
+    x = buf[off:off + rows * width * 4].view(np.float32).reshape(
+        rows, width)
+    x[:] = np.random.default_rng(8).uniform(-1, 1, (rows, width))
+    want = d.stream("app", x.copy())
+    for _ in range(3):
+        y = d.stream("app", x)
+        x_was = x.copy()
+        x[:] = 0.0
+        assert np.array_equal(np.asarray(y), np.asarray(want))
+        x[:] = x_was
+    d.close()
+
+
 def test_serve_shims_warn_exactly_once(spec_a, params_a):
     chip = compile_chip(spec_a, params=params_a)
     fleet = shard_chip(chip)
@@ -748,6 +785,59 @@ def test_two_device_deployment_subprocess(sim_subprocess):
     assert res["devices"] == 2 and res["n_chips"] == 2
     assert res["rel"] == 0.0
     assert res["exact"]
+
+
+_RAGGED_STREAM_SCRIPT = """
+import json
+import jax
+import numpy as np
+from repro import obs
+from repro.chip import compile_chip
+from repro.core.crossbar_layer import MLPSpec, mlp_init
+from repro.fleet import shard_chip
+
+spec = MLPSpec((64, 48, 10), activation="threshold",
+               out_activation="linear")
+fleet = shard_chip(compile_chip(spec, params=mlp_init(
+    jax.random.PRNGKey(0), spec)))
+rng = np.random.default_rng(2)
+odd, even = (rng.uniform(-1, 1, (n, 64)).astype(np.float32)
+             for n in (11, 12))
+tel = obs.configure()
+res = {"n_chips": fleet.n_chips}
+for name, x in (("odd", odd), ("even", even)):
+    n0 = len(tel.tracer.trace_events())
+    y = fleet.stream(x)
+    names = [e["name"] for e in tel.tracer.trace_events()[n0:]]
+    res[name] = {
+        "match": bool(np.array_equal(np.asarray(y),
+                                     fleet.stream_host(x))),
+        "shape": list(y.shape),
+        "devices": len(y.sharding.device_set),
+        "replicated": bool(y.sharding.is_fully_replicated),
+        "spans": {k: names.count(k) for k in (
+            "fleet.stream", "fleet.stream_host", "fleet.d2h",
+            "fleet.program")},
+    }
+print(json.dumps(res))
+"""
+
+
+def test_ragged_stream_cuts_its_pad_rows_on_the_device_subprocess(
+        sim_subprocess):
+    res = sim_subprocess(_RAGGED_STREAM_SCRIPT, n_devices=2)
+    assert res["n_chips"] == 2
+    odd, even = res["odd"], res["even"]
+    assert odd["match"] and even["match"]
+    assert odd["shape"] == [11, 10] and even["shape"] == [12, 10]
+    # both stay on the member's two chips with no read-back: 11 rows
+    # leave a pad row, cut on the device (replicated: the count is odd);
+    # 12 rows divide, and the output stays sharded
+    for r in (odd, even):
+        assert r["spans"] == {"fleet.stream": 1, "fleet.stream_host": 0,
+                              "fleet.d2h": 0, "fleet.program": 1}
+        assert r["devices"] == 2
+    assert odd["replicated"] and not even["replicated"]
 
 
 # ------------------------------------------------------------------- #

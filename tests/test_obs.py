@@ -450,10 +450,12 @@ def _items(rows, seed=3):
         -1, 1, (rows, 64)).astype(np.float32)
 
 
-@pytest.mark.parametrize("verb, parent", [
-    ("stream_host", "fleet.stream_host"),
-    ("stream_local", "fleet.stream_local")])
-def test_member_child_spans_tile_the_call(member, tel, verb, parent):
+@pytest.mark.parametrize("verb, parent, children", [
+    ("stream_host", "fleet.stream_host", MEMBER_CHILDREN),
+    ("stream_local", "fleet.stream_local", MEMBER_CHILDREN),
+    ("stream", "fleet.stream", MEMBER_CHILDREN[:3])])
+def test_member_child_spans_tile_the_call(member, tel, verb, parent,
+                                          children):
     call = getattr(member, verb)
     x = _items(8192)
     call(x)                                   # compiled before timing
@@ -465,7 +467,9 @@ def test_member_child_spans_tile_the_call(member, tel, verb, parent):
     assert len(parents) == 4
     assert all(e["cat"] == "fleet" and e["args"]["rows"] == 8192
                for e in parents)
-    for name in MEMBER_CHILDREN:
+    assert not any(e["name"] in MEMBER_CHILDREN and e["name"] not in
+                   children for e in evs)
+    for name in children:
         kids = [e for e in evs if e["name"] == name]
         assert len(kids) == 4 and all(e["cat"] == "fleet" for e in kids)
         # each child nests inside one call of the parent
@@ -474,7 +478,7 @@ def test_member_child_spans_tile_the_call(member, tel, verb, parent):
                        k["ts"] + k["dur"] <= p["ts"] + p["dur"] + 1e-3
                        for p in parents)
     total = sum(e["dur"] for e in parents)
-    kids = sum(e["dur"] for e in evs if e["name"] in MEMBER_CHILDREN)
+    kids = sum(e["dur"] for e in evs if e["name"] in children)
     assert 0.90 <= kids / total <= 1.0 + 1e-9, kids / total
     assert not any(e["cat"] == "phase" for e in evs)
 
@@ -506,8 +510,46 @@ def test_member_traced_and_untraced_outputs_bit_identical(member):
     assert np.array_equal(plain_host, traced_host)
     assert np.array_equal(plain_dev, traced_dev)
     assert np.array_equal(plain_host, plain_dev)
-    assert names.count("fleet.result_to_device") == 1
-    assert names.count("fleet.stream_host") == 2
+    # stream keeps its output on the device, so the traced pair opens
+    # one fleet.stream_host, one fleet.stream, one read-back, no upload
+    assert names.count("fleet.stream_host") == 1
+    assert names.count("fleet.stream") == 1
+    assert names.count("fleet.d2h") == 1
+    assert names.count("fleet.result_to_device") == 0
+
+
+def test_member_stream_result_stays_on_the_device(member, tel):
+    import jax
+
+    x = _items(37, seed=6)
+    host = member.stream_host(x)
+    c0 = dict(tel.metrics.snapshot()["counters"])
+    outs = [member.stream(x), member(x)]
+    c = tel.metrics.snapshot()["counters"]
+    for y in outs:
+        assert isinstance(y, jax.Array) and y.dtype == np.float32
+        assert np.array_equal(np.asarray(y), host)
+    assert c["fleet.d2h_bytes"] == c0["fleet.d2h_bytes"]
+    assert c["fleet.h2d_bytes"] - c0["fleet.h2d_bytes"] == \
+        2 * 37 * member.d_in * 4
+    names = [e["name"] for e in tel.tracer.trace_events()]
+    assert names.count("fleet.stream") == 2
+    assert names.count("fleet.d2h") == 1
+    assert "fleet.result_to_device" not in names
+
+
+@pytest.mark.parametrize("shape", [(64,), (3, 5, 64)])
+def test_member_stream_keeps_the_lead_shape_and_dtype(member, shape):
+    import jax.numpy as jnp
+
+    x = _items(int(np.prod(shape[:-1])), seed=7).reshape(shape)
+    host = member.stream_host(x)
+    y = member.stream(x)
+    assert y.shape == host.shape == (*shape[:-1], member.d_out)
+    assert np.array_equal(np.asarray(y), host)
+    # the caller's dtype comes back, as the host path's upload gave it
+    y16 = member.stream(jnp.asarray(x, jnp.bfloat16))
+    assert y16.dtype == jnp.bfloat16
 
 
 def test_chip_stream_span_through_the_primitive(member, tel, annotations):

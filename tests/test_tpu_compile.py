@@ -26,6 +26,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.chip import compile_chip
 from repro.chip.compile import stream_pipeline
 from repro.core.crossbar_layer import MLPSpec, mlp_init
+from repro.fleet.shard import _first_rows
 from repro.kernels import ops
 from repro.kernels.crossbar_mvm import crossbar_mvm
 from repro.kernels.int8_matmul import int8_matmul
@@ -148,3 +149,18 @@ def test_deep_fleet_step_compiles_for_v5e_2x2(topo, mosaic_kernels,
     compiled = jax.jit(step).lower(plan, x).compile()
     assert ("tpu_custom_call" in compiled.as_text()) == use_kernel
     assert len(compiled.output_shardings.device_set) == 4
+
+
+@pytest.mark.parametrize("rows", [4093, 1])
+def test_ragged_cut_compiles_for_v5e_2x2(topo, rows):
+    """``ShardedChip.stream`` on ragged rows: the four-chip step's
+    output, padded to a multiple of four and sharded over the chips, is
+    cut to the rows sent on the device; for an odd row count that
+    leaves the result replicated on all four."""
+    mesh = Mesh(np.asarray(topo.devices), ("chip",))
+    padded = -(-rows // 4) * 4
+    out = _sds(NamedSharding(mesh, P("chip")), (padded, DEEP[-1]),
+               jnp.float32)
+    compiled = _first_rows.lower(out, rows).compile()
+    (sharding,) = jax.tree.leaves(compiled.output_shardings)
+    assert len(sharding.device_set) == 4 and sharding.is_fully_replicated
