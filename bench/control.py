@@ -5,13 +5,15 @@
 
 For every seed, in one process: deploy the cell's configuration with
 that seed's weights, drive its traffic for ``s`` seconds exactly as a
-benchmark run does, and compare the answers with the reference (the
-program's reading). On the same answers, put the control in the
+benchmark run does (the sensor tenant's weights, deployment, traffic
+and window), and compare the answers with the reference (the program's
+reading). On the same answers, put the control in the
 program's place: the reference one step lower in precision
 (``check.control`` of the configuration) and read the same number (the
 control's reading). The limit lies above the largest program reading
 and below the smallest control reading. The benchmark's own runs never
-run this.
+run this. It knows only the sensor tenant (``tenants/sensor.py``); a
+tenant of another kind brings its own readings.
 """
 import argparse
 import gc
@@ -27,45 +29,34 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 from bench import catalog, run  # noqa: E402
 
 
-def readings(cell_name: str, seed: int, seconds: float, devices) -> dict:
+def readings(cell_name: str, seed: int, seconds: float) -> dict:
     import jax
-    import numpy as np
 
-    from bench import loops, reference, traffic, weights
+    from bench import reference
 
     bench = catalog.benchmark()
     cell = catalog.cell(bench, cell_name)
     cfg = catalog.config(cell["config"])
     mix = catalog.mix(cell["traffic"], cell["name"])
-    app, d_in = cfg["app"], cfg["dims"][0]
-    params = jax.block_until_ready(weights.make(cfg, seed))
-    dep = run.deploy_cell(cfg, params, cell["chips"])
-    if mix["loop"] == "open":
-        sched = traffic.open_schedule(mix, seed, seconds, d_in)
-        loops.warm_open(dep, app, sched, dep.router.slots)
-        win = loops.open_loop(dep, app, sched, seconds,
-                              float(mix["drain_s"]))
-        pool = sched.pool
-    else:
-        batches = traffic.closed_batches(mix, seed, d_in)
-        loops.warm_closed(dep, app, batches)
-        keep = frozenset(range(int(mix["checked_calls"])))
-        win = loops.closed_loop(dep, app, batches, seconds, keep)
-        pool = batches.reshape(-1, d_in)
+    sensor = catalog.tenant("sensor")
+    params = jax.block_until_ready(sensor.make_params(cfg, seed))
+    dep = sensor.deploy(cfg, params, cell["chips"])
+    load = sensor.make_load(cfg, mix, seed, seconds)
+    sensor.warm(dep, cfg, load)
+    win = sensor.window(dep, cfg, mix, load, seconds)
     dep.close()
     del dep
     gc.collect()
-    rows = np.concatenate([r for r, _ in win.answers])
-    ys = np.concatenate([np.asarray(y, np.float32) for _, y in win.answers])
-    ref, near = reference.forward(cfg, params, pool)
-    ctl = reference.control_outputs(cfg, params, pool)
+    rows, ys = sensor.answers(cfg, win)
+    ref, near = reference.forward(cfg, params, load.pool)
+    ctl = reference.control_outputs(cfg, params, load.pool)
     out = {"cell": cell_name, "seed": seed, "answers": int(len(rows)),
            "near": int(near[rows].sum()),
            "failed": win.attempted - win.finished,
            "program": reference.errors(ys, ref[rows], near[rows]),
            "control": reference.errors(ctl[rows], ref[rows], near[rows])}
     if cfg["check"]["control"] == "bf16x3":
-        high = reference.forward(cfg, params, pool, mode="high")[0]
+        high = reference.forward(cfg, params, load.pool, mode="high")[0]
         out["backend_high"] = reference.errors(high[rows], ref[rows],
                                                near[rows])
     return out
@@ -81,13 +72,13 @@ def main(argv=None) -> int:
     cell = catalog.cell(catalog.benchmark(), args.workload)
     run.configure_jax()
     try:
-        devices = run.require_devices(cell["chips"])
+        run.require_devices(cell["chips"])
     except run.NoAccelerator as e:
         print(f"control: {e}", file=sys.stderr)
         return 1
     rows = []
     for seed in (int(s) for s in args.seeds.split(",")):
-        r = readings(args.workload, seed, args.seconds, devices)
+        r = readings(args.workload, seed, args.seconds)
         print(json.dumps(r), flush=True)
         rows.append(r)
     summary = {"cell": args.workload}

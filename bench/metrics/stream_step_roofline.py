@@ -1,11 +1,8 @@
 """Share of its roofline that the stream program reaches on the device:
 the stream calls that ran in the profiled slice, each worth the least
-time a chip could take for its items (the larger of work-count FLOPs
-over peak and work-count bytes over HBM bandwidth), over the device's
-busy time in that slice (profiler trace)."""
-import math
-
-from bench import workcount
+time a chip could take for one call of the cell (the tenant's work
+count: the larger of FLOPs over peak and bytes over HBM bandwidth),
+over the device's busy time in that slice (profiler trace)."""
 
 
 def read(ctx):
@@ -14,10 +11,7 @@ def read(ctx):
         return None
     if not red["modules"] or red["busy_s"] <= 0:
         return None
-    cfg = ctx["config"]
     calls = max(m["calls"] for m in red["modules"].values())
-    items = math.ceil(int(ctx["mix"]["items_per_call"]) / ctx["chips"])
-    t_min = workcount.min_seconds(cfg["dims"], items, cfg["weight_bytes"],
-                                  peaks[cfg["peak"]],
-                                  peaks["hbm_bytes_per_s"])
+    t_min = ctx["tenant"].min_call_seconds(ctx["config"], ctx["mix"],
+                                           ctx["chips"], peaks)
     return calls / red["devices"] * t_min / red["busy_s"] * 100.0

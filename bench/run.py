@@ -3,14 +3,16 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> \
         --trace <0|1>
 
-One process, no children. It looks the cell up in ``BENCHMARK.json``,
-refuses to run without a TPU (or with fewer chips than the cell asks
-for), makes the configuration's weights on the device from the seed,
-deploys them through ``repro.deploy.deploy``, builds the traffic from
-the seed on the host, warms up the cell's own shapes, and measures for
-``s`` seconds. Then it reads the device's peak memory, frees the
-deployment, and checks every answer due in the window (or a seeded
-sample, for the closed loop) against the dense float32 reference.
+One process, no children. It looks the cell up in ``BENCHMARK.json``
+and its configuration's tenant up in ``tenants/`` (``catalog.tenant``),
+and refuses to run without a TPU (or with fewer chips than the cell
+asks for). The tenant makes the parameters on the device from the
+seed, deploys them, builds the load from the seed on the host and warms
+up the cell's own shapes; then the tenant's window measures for ``s``
+seconds. The harness reads the device's peak memory, frees the
+deployment, and has the tenant check the window's answers against its
+plain reference: each number it returns is held to the configuration's
+``check.limits``, and no call or request may fail.
 
 With ``--trace 0`` the result carries the cell's end-to-end metrics;
 with ``--trace 1`` its per-layer metrics, read from the program's
@@ -107,53 +109,27 @@ def span_stats(events) -> dict:
     return out
 
 
-def deploy_cell(cfg: dict, params, chips: int):
-    from repro.deploy import AppSpec, DeploymentSpec, deploy
-
-    app = AppSpec(cfg["app"], cfg["app"], params=params,
-                  system=cfg["system"],
-                  geom=(cfg["core"]["rows"], cfg["core"]["cols"]),
-                  weight_bits=cfg["weight_bits"],
-                  lanes_per_chip=cfg["lanes_per_chip"])
-    dep = deploy(DeploymentSpec(apps=(app,), n_chips=chips,
-                                use_kernel=cfg["use_kernel"]))
-    dims = tuple(dep.chip(cfg["app"]).dims)
-    if dims != tuple(cfg["dims"]):
-        raise ValueError(f"deployed widths {dims} differ from the "
-                         f"configuration's {tuple(cfg['dims'])}")
-    return dep
-
-
-def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, seed: int,
-             seconds: float, trace: bool, devices: list) -> dict:
+def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, tenant,
+             seed: int, seconds: float, trace: bool, devices: list) -> dict:
+    """One run of ``cell``: ``tenant`` (``catalog.tenant``) makes the
+    parameters, the deployment, the load, the window and the check."""
     import jax
     import numpy as np
 
-    from bench import loops, reference, traffic, weights
+    from bench import loops
 
-    app, chips = cfg["app"], cell["chips"]
+    chips = cell["chips"]
     used = devices[:chips]
     kind = used[0].device_kind
     peaks = catalog.peaks(kind) if used[0].platform == "tpu" else None
-    d_in = cfg["dims"][0]
 
-    params = jax.block_until_ready(weights.make(cfg, seed))
+    params = jax.block_until_ready(tenant.make_params(cfg, seed))
     t = time.perf_counter()
-    dep = deploy_cell(cfg, params, chips)
+    dep = tenant.deploy(cfg, params, chips)
     deploy_s = time.perf_counter() - t
     log(f"deploy: {deploy_s:.6f} s; report: {dep.report()}")
-
-    open_loop = mix["loop"] == "open"
-    if open_loop:
-        sched = traffic.open_schedule(mix, seed, seconds, d_in)
-        loops.warm_open(dep, app, sched, dep.router.slots)
-        pool = sched.pool
-    else:
-        batches = traffic.closed_batches(mix, seed, d_in)
-        keep = frozenset(int(k) for k in traffic.rng(seed, 4).integers(
-            0, int(mix["checked_call_range"]), int(mix["checked_calls"])))
-        loops.warm_closed(dep, app, batches)
-        pool = batches.reshape(-1, d_in)
+    load = tenant.make_load(cfg, mix, seed, seconds)
+    tenant.warm(dep, cfg, load)
 
     log_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
     span = jax.profiler.TraceAnnotation if trace else loops.no_span
@@ -166,12 +142,7 @@ def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, seed: int,
     compiles = CompileCounter()
     t_window = time.perf_counter()
     setup_s = t_window - T_PROCESS
-    if open_loop:
-        win = loops.open_loop(dep, app, sched, seconds,
-                              float(mix["drain_s"]), span, slice_)
-    else:
-        win = loops.closed_loop(dep, app, batches, seconds, keep, span,
-                                slice_)
+    win = tenant.window(dep, cfg, mix, load, seconds, span, slice_)
     in_window = compiles.count
     compiles.close()
     log(f"compiles in window: {in_window}")
@@ -190,7 +161,7 @@ def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, seed: int,
             "requests")
     log(f"window: {win.window_s:.6f} s, {win.attempted} attempted, "
         f"{win.finished} finished, {win.items} items, {win.steps} router "
-        f"steps, {win.calls} stream calls")
+        f"steps, {win.calls} calls")
 
     memory_peak = max(int((d.memory_stats() or {}).get(
         "peak_bytes_in_use", 0)) for d in used)
@@ -203,26 +174,19 @@ def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, seed: int,
         red = trace_reduce_dir(log_dir)
         shutil.rmtree(log_dir, ignore_errors=True)
 
-    ref, near = reference.forward(cfg, params, pool)
-    rows = np.concatenate([r for r, _ in win.answers]) if win.answers \
-        else np.zeros(0, np.int64)
-    ys = np.concatenate([np.asarray(y, np.float32).reshape(-1, cfg["dims"][-1])
-                         for _, y in win.answers]) if win.answers \
-        else np.zeros((0, cfg["dims"][-1]), np.float32)
-    errs = reference.errors(ys, ref[rows], near[rows])
+    values, note = tenant.check(cfg, params, load, win)
+    log(f"check: {note}")
     limits = cfg["check"]["limits"]
-    log(f"check: {len(rows)} answers compared, {int(near[rows].sum())} "
-        f"near a threshold and left out of the float comparison")
     failed = win.attempted - win.finished
-    checks = {name: {"value": errs[name], "limit": float(limits[name])}
+    checks = {name: {"value": values[name], "limit": float(limits[name])}
               for name in sorted(limits)}
     checks["failed"] = {"value": failed, "limit": 0}
     correct = all(c["value"] <= c["limit"] for c in checks.values())
 
     ctx = {"cell": cell, "config": cfg, "mix": mix, "chips": chips,
-           "setup_s": setup_s, "deploy_s": deploy_s, "window": win,
-           "obs": obs_snapshot, "spans": spans, "trace": red,
-           "peaks": peaks}
+           "tenant": tenant, "setup_s": setup_s, "deploy_s": deploy_s,
+           "window": win, "obs": obs_snapshot, "spans": spans,
+           "trace": red, "peaks": peaks}
     kind_key = "per_layer" if trace else "end_to_end"
     metrics = {}
     for m in catalog.metrics_for(bench, cell["name"], kind_key):
@@ -261,6 +225,7 @@ def main(argv=None) -> int:
     cell = catalog.cell(bench, args.workload)
     cfg = catalog.config(cell["config"])
     mix = catalog.mix(cell["traffic"], cell["name"])
+    tenant = catalog.tenant(cfg.get("tenant"))
     if cfg["chips"] != cell["chips"]:
         log(f"run: cell {cell['name']} asks for {cell['chips']} chips, "
             f"its configuration states {cfg['chips']}")
@@ -277,8 +242,8 @@ def main(argv=None) -> int:
     except NoAccelerator as e:
         log(f"run: {e}; nothing ran")
         return 1
-    result = run_cell(bench, cell, cfg, mix, args.seed, args.seconds,
-                      bool(args.trace), devices)
+    result = run_cell(bench, cell, cfg, mix, tenant, args.seed,
+                      args.seconds, bool(args.trace), devices)
     for name, c in result["checks"].items():
         log(f"check {name} {c['value']!r} limit {c['limit']!r}")
     print(json.dumps(result), flush=True)

@@ -12,6 +12,7 @@ to finish what arrived in it. A rate is sustained while the last
 third's p95 stays near the first third's and the drain is short; above
 the knee the queue grows all through the window. The benchmark's own
 runs never run this; a cell's rate is fixed in ``cells/<cell>.json``.
+It knows only the sensor tenant (``tenants/sensor.py``).
 """
 import argparse
 import json
@@ -50,11 +51,12 @@ def main(argv=None) -> int:
     import jax
     import numpy as np
 
-    from bench import loops, traffic, weights
+    from bench import loops, traffic
 
+    sensor = catalog.tenant("sensor")
     app, d_in = cfg["app"], cfg["dims"][0]
-    params = jax.block_until_ready(weights.make(cfg, args.seed))
-    dep = run.deploy_cell(cfg, params, cell["chips"])
+    params = jax.block_until_ready(sensor.make_params(cfg, args.seed))
+    dep = sensor.deploy(cfg, params, cell["chips"])
     warmed = False
     for rate in (float(r) for r in args.rates.split(",")):
         m = dict(mix, rate_per_s=rate)

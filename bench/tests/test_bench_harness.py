@@ -1,12 +1,13 @@
 """CPU rehearsals of the benchmark harness.
 
 Each cell of ``BENCHMARK.json`` runs end to end here at a tiny window on
-the CPU backend, and so does the serve cell that waits for its knee
-sweep (``held_cells.json``), laid over ``BENCHMARK.json`` by the
-``harness`` fixture. The harness's look for a TPU is steered in the
-test (``run.require_devices``), and so is its compile-cache setup,
-which would otherwise change a process-wide JAX setting of the test
-worker.
+the CPU backend, at the size its tenant's ``rehearsal`` names (the
+sensor tenant's cells at their own), and so does the serve cell that
+waits for its knee sweep (``held_cells.json``), laid over
+``BENCHMARK.json`` by the ``harness`` fixture. The harness's look for
+a TPU is steered in the test (``run.require_devices``), and so is its
+compile-cache setup, which would otherwise change a process-wide JAX
+setting of the test worker.
 """
 import contextlib
 import io
@@ -21,6 +22,8 @@ from bench import catalog, loops, reference, run, traffic, weights, workcount
 
 CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
                  "checks"}
+TENANT_FUNCTIONS = ("make_params", "deploy", "make_load", "warm", "window",
+                    "check", "flops", "min_call_seconds", "rehearsal")
 with open(os.path.join(os.path.dirname(__file__), "held_cells.json")) as f:
     HELD = json.load(f)
 
@@ -38,6 +41,15 @@ def with_held(bench: dict) -> dict:
                 entries[m["name"]] = m
         out[kind] = list(entries.values())
     return out
+
+
+def rehearse(run_cell):
+    """``run_cell`` at the tenant's CPU rehearsal size
+    (``tenant.rehearsal``), the cell's own where the tenant says so."""
+    def go(bench, cell, cfg, mix, tenant, *rest):
+        return run_cell(bench, cell, *tenant.rehearsal(cfg, mix), tenant,
+                        *rest)
+    return go
 
 
 ONE_CHIP_CELLS = [c["name"] for c in with_held(catalog.benchmark())
@@ -61,6 +73,7 @@ def harness(monkeypatch):
     monkeypatch.setattr(catalog, "mix", mix)
     monkeypatch.setattr(run, "configure_jax", lambda: None)
     monkeypatch.setattr(run, "require_devices", lambda chips: jax.devices())
+    monkeypatch.setattr(run, "run_cell", rehearse(run.run_cell))
 
     def go(cell, seconds=0.2, trace=0, seed=2**40 + 3):
         out, err = io.StringIO(), io.StringIO()
@@ -322,8 +335,22 @@ def test_every_metric_and_cell_has_its_files():
     for c in bench["workloads"]:
         cfg = catalog.config(c["config"])
         assert cfg["chips"] == c["chips"]
+        tenant = catalog.tenant(cfg["tenant"])
+        assert all(callable(getattr(tenant, f)) for f in TENANT_FUNCTIONS)
         assert catalog.mix(c["traffic"], c["name"])["loop"] in \
             ("open", "closed")
     assert catalog.peaks("TPU v5 lite")["bf16_flops"] == 197e12
     with pytest.raises(KeyError):
         catalog.peaks("TPU v9 imaginary")
+
+
+@pytest.mark.parametrize("cell", ONE_CHIP_CELLS)
+def test_sensor_cells_rehearse_at_their_own_size(cell):
+    """The CPU rehearsal of a sensor cell is the cell as the chip runs
+    it, so the fault tests above break the timed sizes."""
+    c = catalog.cell(with_held(catalog.benchmark()), cell)
+    cfg = catalog.config(c["config"])
+    mix = catalog.mix(c["traffic"], cell)
+    tenant = catalog.tenant(cfg["tenant"])
+    assert cfg["tenant"] == "sensor"
+    assert tenant.rehearsal(cfg, mix) == (cfg, mix)

@@ -9,10 +9,9 @@ from bench import trace_reduce as tr
 from bench.tests.test_bench_harness import CONTRACT_KEYS, harness  # noqa: F401
 
 CHILD_READERS = {"member.h2d_ms_per_call.bulk": "fleet.h2d",
-                 "member.program_ms_per_call.bulk": "fleet.program",
-                 "member.d2h_ms_per_call.bulk": "fleet.d2h",
-                 "stream.result_ms_per_call.bulk": "fleet.result_to_device"}
+                 "member.program_ms_per_call.bulk": "fleet.program"}
 NEW_READERS = sorted(CHILD_READERS) + ["member.h2d_gbps.bulk"]
+CALL_READER = "member.stream_ms_per_call.bulk"
 
 
 def test_traced_bulk_run_reports_the_member_split(harness):  # noqa: F811
@@ -23,22 +22,18 @@ def test_traced_bulk_run_reports_the_member_split(harness):  # noqa: F811
     got = res["metrics"]
     # the CPU has no device plane and no peaks: the device_trace and
     # peak readers find nothing, and their metrics are left out
-    assert set(got) == {"setup.deploy_s", "member.ms_per_call.bulk",
-                        *NEW_READERS}
+    assert set(got) == {"setup.deploy_s", CALL_READER, *NEW_READERS}
     assert all(got[name]["value"] > 0 for name in NEW_READERS)
     assert got["member.h2d_gbps.bulk"]["unit"] == "GB/s"
-    parts = sum(got[name]["value"] for name in
-                ("member.h2d_ms_per_call.bulk",
-                 "member.program_ms_per_call.bulk",
-                 "member.d2h_ms_per_call.bulk"))
-    assert parts <= got["member.ms_per_call.bulk"]["value"]
+    parts = sum(got[name]["value"] for name in CHILD_READERS)
+    assert parts <= got[CALL_READER]["value"]
 
 
 @pytest.mark.parametrize("name", NEW_READERS)
 def test_member_readers_find_nothing_without_the_spans(name):
     read = catalog.reader(name)
     closed, open_ = {"loop": "closed"}, {"loop": "open"}
-    call = {"fleet.stream_host": {"count": 3, "seconds": 0.02}}
+    call = {"fleet.stream": {"count": 3, "seconds": 0.02}}
     # a program that opens only the whole call's span (and no counter)
     assert read({"mix": closed, "spans": call,
                  "obs": {"counters": {}}}) is None

@@ -1,4 +1,5 @@
-"""The one traffic generator: every mix is a parameter file it reads.
+"""The sensor tenant's traffic generator: every sensor mix is a
+parameter file it reads.
 
 Two loops exist. ``"loop": "open"`` is independent senders: requests
 arrive on a schedule fixed before the window, whatever the system
