@@ -51,6 +51,28 @@ class ModelConfig:
     num_shared_experts: int = 0
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # DeepSeek-V3 routing: "sigmoid" scores, a per-expert correction
+    # bias added for selection only (``noaux_tc``), the top-k weights
+    # (always normalised) scaled by ``routed_scaling_factor``
+    router_score: str = "softmax"      # softmax | sigmoid
+    router_bias: bool = False
+    router_scale: float = 1.0
+    # the routed experts this chip holds, as ids among ``num_experts``
+    # (the router's width): the layer routes over all of them and
+    # computes only its own experts' share, drop-free. () = all, with
+    # capacity-factor dispatch.
+    held_experts: Tuple[int, ...] = ()
+    # leading dense layers before the MoE stack (first_k_dense_replace)
+    # and their SwiGLU width (intermediate_size; d_ff is the expert's)
+    first_dense_layers: int = 0
+    dense_d_ff: int = 0
+    # multi-head latent attention (DeepSeek-V2/V3): kv_lora_rank > 0
+    # caches the normed latent (kv_lora_rank) plus one roped key
+    # (qk_rope_head_dim) per token in place of K/V
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # SSM (Mamba2)
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -76,8 +98,9 @@ class ModelConfig:
     # activation all-reduces; False leaves weights DP-replicated
     # (vocab/embedding sharding is separate and stays on).
     tp_shard: bool = True
-    # KV-cache dtype for serving: bfloat16 | int8 (quantize-on-write,
-    # Perf cell C: the paper's 8-bit ex-situ theme applied to decode)
+    # KV-cache dtype for serving: bfloat16 | float32 | int8
+    # (quantize-on-write, Perf cell C: the paper's 8-bit ex-situ theme
+    # applied to decode)
     kv_cache_dtype: str = "bfloat16"
     # misc
     act: str = "silu"                  # silu | gelu
@@ -97,6 +120,15 @@ class ModelConfig:
     def padded_vocab(self) -> int:
         """Vocab rounded up for even TP sharding (MaxText-style)."""
         return _round_up(self.vocab_size, 512) if self.vocab_size else 0
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def n_held_experts(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return len(self.held_experts) or self.num_experts
 
     @property
     def q_per_kv(self) -> int:

@@ -233,17 +233,27 @@ class Deployment:
                                 geometry=app.geom,
                                 tokens_per_second=app.items_per_second,
                                 seed=app.seed)
-        # same fleet-scope SLO validation as the analytic sensor path:
-        # compile_lm defers, the one diagnostic carries both levels
-        validate_stream_rate(
-            app.items_per_second, clm.chip.replication * app_chips,
-            clm.chip.route, spec.strict_rate, context="deploy",
-            fabric=(f"fleet replica(s) ({app_chips} chip(s) x "
-                    f"{clm.chip.replication} replica(s))"),
-            remedy=("Add chips of this app's system, use a larger "
-                    "core geometry, or lower the app's tokens/second "
-                    "SLO."),
-            stacklevel=5, chip_replicas=clm.chip.replication)
+        if clm.chip is None:
+            # too many tiles to route (compile_lm.ROUTED_TILE_LIMIT):
+            # served, but neither priced nor rate-checked
+            if app.items_per_second:
+                raise ValueError(
+                    f"app {app.name!r}: a tokens/second SLO needs the "
+                    "routed cost chip, which this LM's fabric is too "
+                    "large for; deploy it with items_per_second=0")
+        else:
+            # same fleet-scope SLO validation as the analytic sensor
+            # path: compile_lm defers, the one diagnostic carries both
+            # levels
+            validate_stream_rate(
+                app.items_per_second, clm.chip.replication * app_chips,
+                clm.chip.route, spec.strict_rate, context="deploy",
+                fabric=(f"fleet replica(s) ({app_chips} chip(s) x "
+                        f"{clm.chip.replication} replica(s))"),
+                remedy=("Add chips of this app's system, use a larger "
+                        "core geometry, or lower the app's tokens/second "
+                        "SLO."),
+                stacklevel=5, chip_replicas=clm.chip.replication)
         member = lm_lib.LMMember(
             clm, lanes=app.lanes_per_chip * app_chips,
             cache_len=app.cache_len or lm_lib.DEFAULT_CACHE_LEN,
@@ -512,7 +522,9 @@ class Deployment:
         if self.router is not None and self.router.steps:
             served = self.stats_global() if self.is_distributed \
                 else self.stats()
-        chips = {name: m.chip for name, m in self._members.items()}
+        # an LM tenant too large to route has no cost chip: unpriced
+        chips = {name: m.chip for name, m in self._members.items()
+                 if m.chip is not None}
         if self.chip_systems is None:
             return deployment_report(chips, self.n_chips, served)
         # heterogeneous: each app's row scales by ITS submesh size

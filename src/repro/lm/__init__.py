@@ -1,6 +1,6 @@
 """repro.lm — language-model tenants on the crossbar fabric.
 
-:func:`compile_lm` maps a dense transformer's per-layer linears onto
+:func:`compile_lm` maps a dense or MoE transformer's linears onto
 programmed tile grids (same split→pack→place→route→program pipeline as
 the sensor apps; attention/rotary/KV-cache glue stays host-graph), and
 :class:`LMMember` serves the result as an ordinary ``deploy()`` tenant

@@ -24,7 +24,14 @@ nothing is re-emitted (the scheduler's ``pos`` survives the trip).
 
 Token telemetry rides the ``repro.obs`` registry: ``lm.tokens``
 (counter, one per live lane per step), ``lm.prefill_tokens`` and a
-per-token ``lm.decode_latency_s`` histogram.
+per-token ``lm.decode_latency_s`` histogram. Spans: ``lm.prefill`` (one
+admission: prefill, slot write, first token on the host) and
+``lm.decode`` (the batched decode, from dispatch to the tokens on the
+host). A MoE tenant's dispatch counts come back with the step's tokens
+in the same read-back, never by a sync of their own:
+``lm.expert_assignments`` (token-expert pairs on held experts),
+``lm.expert_rows`` (rows the grouped expert layer computed) and
+``lm.expert_dropped`` (pairs that found no slot; 0 by construction).
 """
 from __future__ import annotations
 
@@ -32,10 +39,11 @@ import dataclasses
 import time
 from typing import List, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.obs.core import current as _obs_current
+from repro.obs.core import current as _obs_current, span as _obs_span
 from repro.serving import kvcache
 from repro.serving.engine import ItemRequest
 
@@ -101,10 +109,19 @@ class LMMember:
         self.n_local_chips = int(n_chips)
         self.prefill_tokens = 0
         self.decode_steps = 0
+        self.last_logits = None     # (lanes, vocab) on the device
+        self.last_live = 0          # lanes the last step decoded for
+        # MoE dispatch counts summed over steps: assignments, rows,
+        # dropped (zeros for a dense model)
+        self.expert_counts = np.zeros((3,), np.int64)
         self._alloc(self.lanes)
 
     def _alloc(self, lanes: int) -> None:
-        self.cache = self.clm.init_cache(lanes, self.cache_len)
+        # bfloat16 unless the config asks for a float32 cache (int8
+        # caches are laid out by the config itself)
+        dtype = jnp.float32 if self.cfg.kv_cache_dtype == "float32" \
+            else jnp.bfloat16
+        self.cache = self.clm.init_cache(lanes, self.cache_len, dtype)
         self._next_tok = np.zeros((lanes,), np.int32)
         self._pos = np.zeros((lanes,), np.int32)
         self._live: set = set()
@@ -127,11 +144,12 @@ class LMMember:
             # lane; positions restart, so this is the documented lossy
             # fallback (CI sizes cache_len >= prompt + max_new_tokens)
             context = context[-self.cache_len:]
-        logits, one_cache = self.clm.prefill(
-            jnp.asarray(context, jnp.int32)[None, :])
-        self.cache = kvcache.write_slot(self.cache, one_cache,
-                                        jnp.int32(lane))
-        self._next_tok[lane] = int(jnp.argmax(logits[0]))
+        with _obs_span("lm.prefill", cat="lm"):
+            logits, one_cache = self.clm.prefill(
+                jnp.asarray(context, jnp.int32)[None, :])
+            self.cache = kvcache.write_slot(self.cache, one_cache,
+                                            jnp.int32(lane))
+            self._next_tok[lane] = int(jnp.argmax(logits[0]))
         self._pos[lane] = len(context)
         self._live.add(lane)
         self.prefill_tokens += len(context)
@@ -151,21 +169,27 @@ class LMMember:
         at its own position) stages the next."""
         out = self._next_tok.astype(np.float32)[:, None]
         t0 = time.perf_counter()
-        logits, self.cache = self.clm.decode(
-            self.cache, self._next_tok[:, None], self._pos,
-            use_kernel=use_kernel)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1)).astype(np.int32)
+        with _obs_span("lm.decode", cat="lm"):
+            nxt, self.last_logits, counts, self.cache = self.clm.step(
+                self.cache, self._next_tok[:, None], self._pos,
+                use_kernel=use_kernel)
+            nxt, counts = jax.device_get((nxt, counts))
         dt = time.perf_counter() - t0
         for lane in self._live:
             self._next_tok[lane] = nxt[lane]
             self._pos[lane] += 1
         self.decode_steps += 1
-        live = len(self._live)
+        self.expert_counts += counts
+        live = self.last_live = len(self._live)
         tel = _obs_current()
         if tel.active and live:
             m = tel.metrics
             m.counter("lm.tokens").inc(live)
             m.histogram("lm.decode_latency_s").record(dt / live)
+            if counts[1]:
+                m.counter("lm.expert_assignments").inc(int(counts[0]))
+                m.counter("lm.expert_rows").inc(int(counts[1]))
+                m.counter("lm.expert_dropped").inc(int(counts[2]))
         return out
 
     # ---------------- elastic resize -------------------------------- #

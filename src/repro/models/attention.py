@@ -11,6 +11,13 @@ cache is never materialized at full head count.
 Sharding (logical names; resolved by the launcher's rules):
   train/prefill: "act_kv" -> model (head parallel), "act_kvseq" -> None
   decode:        "act_kv" -> None,  "act_kvseq" -> model (seq-parallel KV)
+
+Latent attention (MLA, DeepSeek-V2/V3; ``cfg.kv_lora_rank > 0``) has
+its own init/apply below: the cache holds the normed latent ``c_kv``
+and one roped key ``k_pe`` per token. Prefill expands K/V through
+``wkv_b``; decode uses the absorbed form, taking each head's query into
+the latent space through ``wkv_b``'s key half and the attended latent
+back out through its value half, so the cache is never expanded.
 """
 from __future__ import annotations
 
@@ -19,7 +26,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import apply_rope, dense_init, softcap
+from repro.models.layers import apply_rope, dense_init, rms_norm, softcap
 from repro.sharding import shard
 
 NEG_INF = -2.0e38
@@ -29,6 +36,8 @@ NEG_INF = -2.0e38
 # params
 # --------------------------------------------------------------------- #
 def attn_init(key, cfg) -> Dict:
+    if cfg.mla:
+        return mla_init(key, cfg)
     d, H, KH, dh = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     ks = jax.random.split(key, 4)
     dt = jnp.dtype(cfg.param_dtype)
@@ -46,6 +55,8 @@ def attn_init(key, cfg) -> Dict:
 
 
 def attn_specs(cfg) -> Dict:
+    if cfg.mla:
+        return mla_specs(cfg)
     s = {
         "wq": ("embed", "heads", None),
         "wk": ("embed", "kv_heads", None),
@@ -106,7 +117,8 @@ def attend(q, k, v, q_pos, k_pos, *, window=None, cap=0.0, scale=1.0,
                                  window=window, cap=cap, scale=scale)
 
     _, out = jax.lax.scan(body, (), (qs, ps), unroll=unroll)
-    return out.swapaxes(0, 1).reshape(B, Sq, *q.shape[2:])
+    # v's head size, which latent attention sets apart from q's
+    return out.swapaxes(0, 1).reshape(B, Sq, *out.shape[3:])
 
 
 # --------------------------------------------------------------------- #
@@ -128,6 +140,11 @@ def _dequant_kv(q, scale, dtype):
 
 
 def init_attn_cache(cfg, batch: int, cache_len: int, dtype) -> Dict:
+    if cfg.mla:
+        return {"c_kv": jnp.zeros((batch, cache_len, cfg.kv_lora_rank),
+                                  dtype),
+                "k_pe": jnp.zeros((batch, cache_len,
+                                   cfg.qk_rope_head_dim), dtype)}
     KH_eff = cfg.num_kv_heads * cfg.kv_repeat
     shp = (batch, cache_len, KH_eff, cfg.head_dim)
     if cfg.kv_cache_dtype == "int8":
@@ -140,6 +157,9 @@ def init_attn_cache(cfg, batch: int, cache_len: int, dtype) -> Dict:
 
 
 def attn_cache_specs(cfg) -> Dict:
+    if cfg.mla:
+        return {"c_kv": ("batch", "act_kvseq", None),
+                "k_pe": ("batch", "act_kvseq", None)}
     s = {"k": ("batch", "act_kvseq", "act_kv", None),
          "v": ("batch", "act_kvseq", "act_kv", None)}
     if cfg.kv_cache_dtype == "int8":
@@ -152,6 +172,19 @@ def _ring_positions(pos: jax.Array, T: int) -> jax.Array:
     """Absolute position stored in each ring slot after writing `pos`."""
     j = jnp.arange(T, dtype=jnp.int32)
     return pos - ((pos % T - j) % T)
+
+
+def _slot_write(leaf: jax.Array, layer, bidx: jax.Array, idx: jax.Array,
+                val: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Write ``val`` (B, ...) at ring slot ``idx`` (B,) of every lane
+    → (new leaf, this layer's view). With ``layer`` set the leaf is the
+    whole layer-stacked cache (L, B, T, ...) and is updated in place
+    at that layer, so a donated cache is never copied."""
+    if layer is None:
+        new = leaf.at[bidx, idx].set(val.astype(leaf.dtype))
+        return new, new
+    new = leaf.at[layer, bidx, idx].set(val.astype(leaf.dtype))
+    return new, new[layer]
 
 
 def _store_prefill(cache_len: int, k: jax.Array) -> jax.Array:
@@ -180,8 +213,8 @@ def _store_prefill_scale(cache_len: int, s: jax.Array) -> jax.Array:
 # --------------------------------------------------------------------- #
 def attn_apply(p: Dict, cfg, x: jax.Array, *, positions: jax.Array,
                mode: str, cache: Optional[Dict] = None,
-               window=None, project=None) -> Tuple[jax.Array,
-                                                   Optional[Dict]]:
+               window=None, project=None,
+               layer=None) -> Tuple[jax.Array, Optional[Dict]]:
     """x: (B, S, d). positions: (B, S) absolute token positions.
 
     mode: "train" (no cache), "prefill" (build cache), "decode" (S == 1,
@@ -194,7 +227,14 @@ def attn_apply(p: Dict, cfg, x: jax.Array, *, positions: jax.Array,
     rope, softmax, and cache surgery below stay host-graph glue. QKV
     biases are still added here, so a projection backend must not fold
     them in.
+
+    layer: per-slot decode only — ``cache`` is then the whole
+    layer-stacked cache, written in place at this layer and returned
+    whole (``repro.lm``'s unrolled decode).
     Returns (out (B, S, d), new_cache)."""
+    if cfg.mla:
+        return mla_apply(p, cfg, x, positions=positions, mode=mode,
+                         cache=cache, project=project, layer=layer)
     dt = x.dtype
     B, S, _ = x.shape
     H, KH, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -243,15 +283,22 @@ def attn_apply(p: Dict, cfg, x: jax.Array, *, positions: jax.Array,
         if cfg.decode_per_slot:
             # continuous batching: every slot decodes at its own position
             pos_b = positions[:, 0]                      # (B,)
+            T = cache["k"].shape[-3]
             idx = pos_b % T
             bidx = jnp.arange(B)
-            ck = cache["k"].at[bidx, idx].set(kq[:, 0])
-            cv = cache["v"].at[bidx, idx].set(vq[:, 0])
+            full = {}
+            full["k"], ck = _slot_write(cache["k"], layer, bidx, idx,
+                                        kq[:, 0])
+            full["v"], cv = _slot_write(cache["v"], layer, bidx, idx,
+                                        vq[:, 0])
             if quant:
-                cks = cache["ks"].at[bidx, idx].set(ks_new[:, 0])
-                cvs = cache["vs"].at[bidx, idx].set(vs_new[:, 0])
+                full["ks"], cks = _slot_write(cache["ks"], layer, bidx,
+                                              idx, ks_new[:, 0])
+                full["vs"], cvs = _slot_write(cache["vs"], layer, bidx,
+                                              idx, vs_new[:, 0])
             k_pos = jax.vmap(_ring_positions, (0, None))(pos_b, T)
         else:
+            assert layer is None, "layer-indexed decode is per-slot"
             pos = positions[0, 0]  # lockstep decode: scalar position
             idx = pos % T
             ck = jax.lax.dynamic_update_slice(cache["k"], kq, (0, idx, 0, 0))
@@ -272,6 +319,8 @@ def attn_apply(p: Dict, cfg, x: jax.Array, *, positions: jax.Array,
         else:
             new_cache = {"k": ck, "v": cv}
             k_att, v_att = ck, cv
+        if layer is not None:
+            new_cache = full
         out = _attend_block(q, k_att, v_att, positions, k_pos,
                             window=window, cap=cfg.attn_softcap, scale=scale)
     else:
@@ -298,4 +347,129 @@ def attn_apply(p: Dict, cfg, x: jax.Array, *, positions: jax.Array,
         out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
     else:
         out = project("wo", out.reshape(B, S, H * dh))
+    return out, new_cache
+
+
+# --------------------------------------------------------------------- #
+# multi-head latent attention (MLA)
+# --------------------------------------------------------------------- #
+# the per-head halves of ``wkv_b`` as linears of their own: the key
+# half takes a head's q_nope into the latent (absorbed decode), the
+# value half takes the attended latent back out
+MLA_HEAD_LINEARS = ("wkv_b.k", "wkv_b.v")
+
+
+def mla_init(key, cfg) -> Dict:
+    d, H, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+    dt = jnp.dtype(cfg.param_dtype)
+    return {
+        "wq": dense_init(ks[0], (d, H, dn + dr), dt, fan_in=d),
+        "wkv_a": dense_init(ks[1], (d, r + dr), dt, fan_in=d),
+        "kv_norm": jnp.ones((r,), dt),
+        "wkv_b": dense_init(ks[2], (r, H, dn + dv), dt, fan_in=r),
+        "wo": dense_init(ks[3], (H, dv, d), dt, fan_in=H * dv),
+    }
+
+
+def mla_specs(cfg) -> Dict:
+    return {"wq": ("embed", "heads", None), "wkv_a": ("embed", None),
+            "kv_norm": (None,), "wkv_b": (None, "heads", None),
+            "wo": ("heads", None, "embed")}
+
+
+def mla_linears(cfg, p: Dict) -> Dict[str, jax.Array]:
+    """The layer's matmuls as (d_in, d_out) matrices, and the per-head
+    ``wkv_b`` halves as (H, d_in, d_out) stacks (``MLA_HEAD_LINEARS``)."""
+    dn = cfg.qk_nope_head_dim
+    wkv_b = p["wkv_b"]                                   # (r, H, dn + dv)
+    return {
+        "wq": p["wq"].reshape(p["wq"].shape[0], -1),
+        "wkv_a": p["wkv_a"],
+        "wkv_b": wkv_b.reshape(wkv_b.shape[0], -1),
+        "wo": p["wo"].reshape(-1, p["wo"].shape[-1]),
+        "wkv_b.k": jnp.transpose(wkv_b[:, :, :dn], (1, 2, 0)),  # (H, dn, r)
+        "wkv_b.v": jnp.transpose(wkv_b[:, :, dn:], (1, 0, 2)),  # (H, r, dv)
+    }
+
+
+def _mla_dense_projector(cfg, p: Dict):
+    mats = mla_linears(cfg, p)
+
+    def project(name: str, x: jax.Array) -> jax.Array:
+        w = mats[name].astype(x.dtype)
+        if name in MLA_HEAD_LINEARS:                     # x (N, H, d_in)
+            return jnp.einsum("nhi,hio->nho", x, w)
+        return jnp.einsum("...i,io->...o", x, w)
+    return project
+
+
+def mla_apply(p: Dict, cfg, x: jax.Array, *, positions: jax.Array,
+              mode: str, cache: Optional[Dict] = None, project=None,
+              layer=None) -> Tuple[jax.Array, Optional[Dict]]:
+    """DeepSeek-V3 attention with no q-LoRA. x: (B, S, d).
+
+    q = x·wq split into q_nope (dn) and q_pe (dr, roped); x·wkv_a splits
+    into the latent c (rank r, rms-normed by ``kv_norm``) and one roped
+    key k_pe shared by every head. Scores are (q_nope·k_nope + q_pe·k_pe)
+    · scale with scale 1/sqrt(dn + dr) unless ``cfg.attn_scale``.
+
+    prefill/train: k_nope and v = c·wkv_b per head, plain causal
+    attention; prefill returns the cache {c_kv: c, k_pe}.
+    decode (S == 1): c and k_pe are written at each lane's ring slot,
+    then q_nope·k_nope = (wkv_b_k q_nope)·c (absorbed) and the output
+    is wkv_b_v·(Σ_t a_t c_t), read straight from the latent cache.
+
+    ``project(name, x)`` overrides the matmuls (``mla_linears`` names;
+    the per-head ``MLA_HEAD_LINEARS`` take and give (N, H, ·));
+    ``layer`` as in :func:`attn_apply`."""
+    dt = x.dtype
+    B, S, _ = x.shape
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    scale = cfg.attn_scale if cfg.attn_scale else (dn + dr) ** -0.5
+    if project is None:
+        project = _mla_dense_projector(cfg, p)
+
+    q = project("wq", x).reshape(B, S, H, dn + dr)
+    q_nope = q[..., :dn]
+    q_pe = apply_rope(q[..., dn:], positions, cfg.rope_theta)
+    kv = project("wkv_a", x)
+    c = rms_norm(kv[..., :r], p["kv_norm"], cfg.norm_eps)
+    k_pe = apply_rope(kv[..., None, r:], positions, cfg.rope_theta)[:, :, 0]
+
+    if mode == "decode":
+        assert cache is not None and S == 1
+        pos_b = positions[:, 0]                          # (B,)
+        T = cache["c_kv"].shape[-2]
+        idx = pos_b % T
+        bidx = jnp.arange(B)
+        full_c, c_all = _slot_write(cache["c_kv"], layer, bidx, idx,
+                                    c[:, 0])
+        full_pe, pe_all = _slot_write(cache["k_pe"], layer, bidx, idx,
+                                      k_pe[:, 0])
+        k_pos = jax.vmap(_ring_positions, (0, None))(pos_b, T)  # (B, T)
+        c_all, pe_all = c_all.astype(dt), pe_all.astype(dt)
+        q_lat = project("wkv_b.k", q_nope.reshape(B, H, dn))    # (B, H, r)
+        scores = (jnp.einsum("bhr,btr->bht", q_lat, c_all,
+                             preferred_element_type=jnp.float32) +
+                  jnp.einsum("bhe,bte->bht", q_pe[:, 0], pe_all,
+                             preferred_element_type=jnp.float32)) * scale
+        mask = (k_pos <= pos_b[:, None]) & (k_pos >= 0)
+        w = _masked_softmax(scores, mask[:, None, :])
+        o_lat = jnp.einsum("bht,btr->bhr", w.astype(dt), c_all,
+                           preferred_element_type=jnp.float32).astype(dt)
+        out = project("wkv_b.v", o_lat).reshape(B, S, H * dv)
+        return project("wo", out), {"c_kv": full_c, "k_pe": full_pe}
+
+    kvb = project("wkv_b", c).reshape(B, S, H, dn + dv)
+    k = jnp.concatenate(
+        [kvb[..., :dn], jnp.broadcast_to(k_pe[:, :, None, :],
+                                         (B, S, H, dr))], axis=-1)
+    qf = jnp.concatenate([q_nope, q_pe], axis=-1)[:, :, :, None, :]
+    out = attend(qf, k, kvb[..., dn:], positions, positions, scale=scale,
+                 unroll=not cfg.scan_layers)             # (B, S, H, 1, dv)
+    out = project("wo", out.reshape(B, S, H * dv))
+    new_cache = {"c_kv": c, "k_pe": k_pe} if mode == "prefill" else None
     return out, new_cache

@@ -163,8 +163,8 @@ def count_params(cfg, active_only: bool = False) -> int:
     total = sum(math.prod(l.shape) for l in jax.tree.leaves(shapes))
     if active_only and cfg.num_experts:
         per_expert = 3 * cfg.d_model * cfg.d_ff
-        inactive = (cfg.num_experts - cfg.top_k) * per_expert
-        total -= cfg.num_layers * inactive
+        inactive = max(cfg.n_held_experts - cfg.top_k, 0) * per_expert
+        total -= (cfg.num_layers - cfg.first_dense_layers) * inactive
     return int(total)
 
 

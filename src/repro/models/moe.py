@@ -10,6 +10,13 @@ is judged against. Static shapes throughout; overflow tokens are dropped
 
 Sharding: experts -> "exp" (model axis, EP), capacity -> "cap" (data
 axis), so the (E, C, d) blocks are 2-D sharded.
+
+Routing (``route``) is softmax top-k, or DeepSeek-V3's sigmoid scores
+with a selection-only correction bias (``noaux_tc``), the chosen
+weights normalised and scaled. A chip of an expert-parallel group
+(``cfg.held_experts``) routes over every expert and computes only its
+held experts' share of the result, drop-free (``held_dispatch``): each
+held expert gets a slot for every token, so nothing overflows.
 """
 from __future__ import annotations
 
@@ -26,16 +33,27 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
+# spread of the seeded ``noaux_tc`` correction bias: the learned
+# buffer is not in the published config; small beside the sigmoid
+# scores' spacing at the top-k boundary (~1e-2), yet it moves many
+# selections, so a path that ignores it does not pass for one that uses it
+ROUTER_BIAS_STD = 0.01
+
+
 def moe_init(key, cfg) -> Dict:
     d, f, E = cfg.d_model, cfg.d_ff, cfg.num_experts
-    ks = jax.random.split(key, 5)
+    Eh = cfg.n_held_experts
+    ks = jax.random.split(key, 6)
     dt = jnp.dtype(cfg.param_dtype)
     p = {
         "router": dense_init(ks[0], (d, E), dt, fan_in=d),
-        "w1": dense_init(ks[1], (E, d, f), dt, fan_in=d),
-        "w3": dense_init(ks[2], (E, d, f), dt, fan_in=d),
-        "w2": dense_init(ks[3], (E, f, d), dt, fan_in=f),
+        "w1": dense_init(ks[1], (Eh, d, f), dt, fan_in=d),
+        "w3": dense_init(ks[2], (Eh, d, f), dt, fan_in=d),
+        "w2": dense_init(ks[3], (Eh, f, d), dt, fan_in=f),
     }
+    if cfg.router_bias:
+        p["router_bias"] = (ROUTER_BIAS_STD * jax.random.normal(
+            ks[5], (E,), jnp.float32)).astype(dt)
     if cfg.num_shared_experts:
         fs = f * cfg.num_shared_experts
         k1, k2, k3 = jax.random.split(ks[4], 3)
@@ -54,10 +72,100 @@ def moe_specs(cfg) -> Dict:
         "w3": ("exp", "embed", None),
         "w2": ("exp", None, "embed"),
     }
+    if cfg.router_bias:
+        s["router_bias"] = (None,)
     if cfg.num_shared_experts:
         s["shared"] = {"w1": ("embed", "ff"), "w3": ("embed", "ff"),
                        "w2": ("ff", "embed")}
     return s
+
+
+def route(cfg, logits: jax.Array, bias=None):
+    """Router logits (..., E) f32 → (weights (..., K), expert ids
+    (..., K), scores (..., E)). Selection ranks ``scores + bias``; the
+    weights are the chosen experts' unbiased scores, normalised over
+    the k chosen (DeepSeek-V3's ``norm_topk_prob``, with its 1e-20 in
+    the sum), then scaled by ``cfg.router_scale``."""
+    if cfg.router_score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif cfg.router_score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown router score {cfg.router_score!r}")
+    choice = scores if bias is None else scores + bias.astype(scores.dtype)
+    _, ids = jax.lax.top_k(choice, cfg.top_k)
+    w = jnp.take_along_axis(scores, ids, axis=-1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return w * cfg.router_scale, ids, scores
+
+
+def held_dispatch(cfg, w: jax.Array, ids: jax.Array):
+    """Drop-free dispatch of T tokens' top-k choices to the held
+    experts: → (token ids (Eh, T), gates (Eh, T), stats). Expert j's
+    slots list the tokens that chose ``held_experts[j]`` first, in
+    token order, then padding rows of gate 0; every expert has T slots,
+    so no token is dropped. ``stats`` counts, as int32, the
+    token-expert pairs on held experts (``assignments``), the rows the
+    expert layer computes (``rows``) and the pairs that found no slot
+    (``dropped``)."""
+    T = ids.shape[0]
+    held = jnp.asarray(cfg.held_experts or range(cfg.num_experts),
+                       jnp.int32)
+    match = ids[None, :, :] == held[:, None, None]          # (Eh, T, K)
+    gate = jnp.where(match, w[None], 0.0).sum(-1)           # (Eh, T)
+    chosen = match.any(-1)
+    order = jnp.argsort(~chosen, axis=-1, stable=True)      # (Eh, T)
+    count = chosen.sum(-1)
+    valid = jnp.arange(T, dtype=jnp.int32)[None, :] < count[:, None]
+    gates = jnp.take_along_axis(gate, order, axis=-1) * valid
+    assigned = count.sum().astype(jnp.int32)
+    stats = {"assignments": assigned,
+             "rows": jnp.int32(held.shape[0] * T),
+             "dropped": assigned - valid.sum().astype(jnp.int32)}
+    return order, gates, stats
+
+
+def held_combine(y_e: jax.Array, order: jax.Array, gates: jax.Array,
+                 T: int) -> jax.Array:
+    """Sum each held expert's gated rows back onto their tokens:
+    y_e (Eh, T, d) → (T, d) f32."""
+    d = y_e.shape[-1]
+    y = (y_e.astype(jnp.float32) * gates[..., None]).reshape(-1, d)
+    return jax.ops.segment_sum(y, order.reshape(-1), num_segments=T)
+
+
+def _moe_held(p: Dict, cfg, xt: jax.Array) -> Tuple[jax.Array, Dict]:
+    """This chip's share of the layer for xt (T, d): routing over every
+    expert, the held experts' SwiGLU on their dispatched tokens."""
+    dt = xt.dtype
+    T = xt.shape[0]
+    logits = jnp.einsum("td,de->te", xt, p["router"].astype(dt),
+                        preferred_element_type=jnp.float32)
+    w, ids, scores = route(cfg, logits, p.get("router_bias"))
+    order, gates, stats = held_dispatch(cfg, w, ids)
+    x_e = jnp.take(xt, order, axis=0)                       # (Eh, T, d)
+    h = jnp.einsum("etd,edf->etf", x_e, p["w1"].astype(dt))
+    g = jnp.einsum("etd,edf->etf", x_e, p["w3"].astype(dt))
+    h = act_fn(cfg.act)(h) * g
+    y_e = jnp.einsum("etf,efd->etd", h, p["w2"].astype(dt))
+    y = held_combine(y_e, order, gates, T).astype(dt)
+    me = scores.mean(axis=0)
+    ce = jax.nn.one_hot(ids[:, 0], cfg.num_experts,
+                        dtype=jnp.float32).mean(axis=0)
+    aux_loss = cfg.num_experts * jnp.sum(me * ce)
+    return y, {"aux_loss": aux_loss,
+               "drop_frac": stats["dropped"] / jnp.maximum(
+                   stats["assignments"], 1)}
+
+
+def shared_mlp(p: Dict, cfg, x: jax.Array) -> jax.Array:
+    """The shared experts (one SwiGLU of width d_ff x shared count)."""
+    dt = x.dtype
+    sh = p["shared"]
+    hs = act_fn(cfg.act)(jnp.einsum("bsd,df->bsf", x, sh["w1"].astype(dt)))
+    hs = hs * jnp.einsum("bsd,df->bsf", x, sh["w3"].astype(dt))
+    hs = shard(hs, "batch", None, "ff")
+    return jnp.einsum("bsf,fd->bsd", hs, sh["w2"].astype(dt))
 
 
 def capacity(tokens: int, cfg) -> int:
@@ -74,12 +182,16 @@ def moe_apply(p: Dict, cfg, x: jax.Array) -> Tuple[jax.Array, Dict]:
     MoE block emits **no collectives** for dispatch/combine — only the
     expert einsums touch the (model-sharded) weights. Measured on
     dbrx-132b prefill_32k: 65.4 s -> 1.9 s collective term (§Perf B1).
+    A chip that holds a slice of the experts (``cfg.held_experts``)
+    takes its share drop-free (``_moe_held``).
     """
-    dt = x.dtype
     B, S, d = x.shape
     G = max(cfg.moe_groups, 1)
     T = B * S
-    if G > 1 and T % G == 0:
+    if cfg.held_experts:
+        y, aux = _moe_held(p, cfg, x.reshape(T, d))
+        y = y.reshape(B, S, d)
+    elif G > 1 and T % G == 0:
         y, aux = _moe_grouped(p, cfg, x.reshape(G, T // G, d))
         y = y.reshape(B, S, d)
     else:
@@ -88,12 +200,7 @@ def moe_apply(p: Dict, cfg, x: jax.Array) -> Tuple[jax.Array, Dict]:
     y = shard(y, "batch", "seq", None)
 
     if cfg.num_shared_experts:
-        sh = p["shared"]
-        hs = act_fn(cfg.act)(jnp.einsum("bsd,df->bsf", x,
-                                        sh["w1"].astype(dt)))
-        hs = hs * jnp.einsum("bsd,df->bsf", x, sh["w3"].astype(dt))
-        hs = shard(hs, "batch", None, "ff")
-        y = y + jnp.einsum("bsf,fd->bsd", hs, sh["w2"].astype(dt))
+        y = y + shared_mlp(p, cfg, x)
     return y, aux
 
 
@@ -108,10 +215,8 @@ def _moe_tokens(p: Dict, cfg, xt: jax.Array, *, constrain: bool
     # -- routing -------------------------------------------------------- #
     logits = jnp.einsum("td,de->te", xt, p["router"].astype(dt),
                         preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)                      # (T, E) f32
-    gate_vals, expert_ids = jax.lax.top_k(probs, K)              # (T, K)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)                  # renormalize
+    gate_vals, expert_ids, probs = route(cfg, logits,
+                                         p.get("router_bias"))  # (T, K)
 
     # load-balance aux (Switch): E * mean(frac_tokens_e * mean_prob_e)
     me = probs.mean(axis=0)                                       # (E,)
@@ -179,10 +284,8 @@ def _moe_grouped(p: Dict, cfg, xg: jax.Array) -> Tuple[jax.Array, Dict]:
 
     logits = jnp.einsum("gtd,de->gte", xg, p["router"].astype(dt),
                         preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)                  # (G, Tg, E)
-    gate_vals, expert_ids = jax.lax.top_k(probs, K)          # (G, Tg, K)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
+    gate_vals, expert_ids, probs = route(cfg, logits,
+                                         p.get("router_bias"))
 
     me = probs.mean(axis=1)                                  # (G, E)
     ce = jax.nn.one_hot(expert_ids[:, :, 0], E,

@@ -77,7 +77,7 @@ def _block_init(key, cfg, use_moe: bool):
         "mlp_norm": jnp.ones((d,), dt),
     }
     p["mlp"] = moe_mod.moe_init(k2, cfg) if use_moe else \
-        mlp_init(k2, d, cfg.d_ff, dt)
+        mlp_init(k2, d, cfg.dense_d_ff or cfg.d_ff, dt)
     if cfg.post_block_norm:
         p["attn_post"] = jnp.ones((d,), dt)
         p["mlp_post"] = jnp.ones((d,), dt)
@@ -98,16 +98,18 @@ def _block_specs(cfg, use_moe: bool):
 
 
 def _block_apply(p, cfg, h, *, positions, mode, cache, window, use_moe,
-                 project=None, mlp_fn=None):
-    """project/mlp_fn: optional linear-projection overrides (see
-    ``attn.attn_apply``); ``repro.lm`` substitutes crossbar-mapped tile
-    grids for the block's seven matmuls while norms, residuals, rope,
-    softmax, and cache surgery stay in this host graph."""
+                 project=None, mlp_fn=None, layer=None):
+    """project/mlp_fn: optional overrides of the attention's linear
+    projections (see ``attn.attn_apply``) and of the whole MLP or MoE
+    layer; ``repro.lm`` substitutes crossbar-mapped tile grids for the
+    block's matmuls while norms, residuals, rope, softmax, routing and
+    cache surgery stay in this host graph. ``layer``: see
+    ``attn.attn_apply``."""
     a_in = rms_norm(h, p["attn_norm"], cfg.norm_eps)
     a_out, new_cache = attn.attn_apply(p["attn"], cfg, a_in,
                                        positions=positions, mode=mode,
                                        cache=cache, window=window,
-                                       project=project)
+                                       project=project, layer=layer)
     if cfg.post_block_norm:
         a_out = rms_norm(a_out, p["attn_post"], cfg.norm_eps)
     h = h + a_out
@@ -115,10 +117,10 @@ def _block_apply(p, cfg, h, *, positions, mode, cache, window, use_moe,
 
     m_in = rms_norm(h, p["mlp_norm"], cfg.norm_eps)
     aux = {}
-    if use_moe:
-        m_out, aux = moe_mod.moe_apply(p["mlp"], cfg, m_in)
-    elif mlp_fn is not None:
+    if mlp_fn is not None:
         m_out = mlp_fn(p["mlp"], m_in)
+    elif use_moe:
+        m_out, aux = moe_mod.moe_apply(p["mlp"], cfg, m_in)
     else:
         m_out = mlp_apply(p["mlp"], m_in, cfg.act, m_in.dtype)
     if cfg.post_block_norm:
@@ -178,7 +180,54 @@ class DenseStack:
 
 
 class MoEStack(DenseStack):
+    """MoE blocks; with ``cfg.first_dense_layers`` = k the first k
+    blocks are dense (width ``cfg.dense_d_ff``) and the parameters are
+    {"dense": k stacked blocks, "moe": the rest}. The cache stays one
+    stack over all layers."""
     use_moe = True
+
+    @classmethod
+    def init(cls, key, cfg):
+        k = cfg.first_dense_layers
+        if not k:
+            return super().init(key, cfg)
+        kd, km = jax.random.split(key)
+        return {"dense": stack_init(lambda kk: _block_init(kk, cfg, False),
+                                    kd, k),
+                "moe": stack_init(lambda kk: _block_init(kk, cfg, True),
+                                  km, cfg.num_layers - k)}
+
+    @classmethod
+    def specs(cls, cfg):
+        if not cfg.first_dense_layers:
+            return super().specs(cfg)
+        return {"dense": _block_specs(cfg, False),
+                "moe": _block_specs(cfg, True)}
+
+    @classmethod
+    def apply(cls, p, cfg, h, *, positions, mode, cache=None):
+        k = cfg.first_dense_layers
+        if not k:
+            return super().apply(p, cfg, h, positions=positions,
+                                 mode=mode, cache=cache)
+        windows = _layer_windows(cfg)
+        aux0 = {"aux_loss": jnp.zeros((), jnp.float32),
+                "drop_frac": jnp.zeros((), jnp.float32)}
+
+        def part(use_moe, params, c, w, h):
+            def step(p_i, h, c_i, w_i):
+                return _block_apply(p_i, cfg, h, positions=positions,
+                                    mode=mode, cache=c_i, window=w_i,
+                                    use_moe=use_moe)
+            return scan_stack(step, cfg, mode, h, params, c, w, aux0)
+
+        cut = (lambda lo, hi: None) if cache is None else \
+            (lambda lo, hi: jax.tree.map(lambda x: x[lo:hi], cache))
+        h, c_dense, _ = part(False, p["dense"], cut(0, k), windows[:k], h)
+        h, c_moe, aux = part(True, p["moe"], cut(k, None), windows[k:], h)
+        new_cache = c_moe if c_moe is None else jax.tree.map(
+            lambda a, b: jnp.concatenate([a, b]), c_dense, c_moe)
+        return h, new_cache, aux
 
 
 # ===================================================================== #

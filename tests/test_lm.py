@@ -100,8 +100,9 @@ def test_compile_lm_rejects_wrong_inputs(setup):
     with pytest.raises(TypeError, match="ModelConfig or "
                                         "TransformerParams"):
         compile_lm(MLPSpec((4, 2)))
-    with pytest.raises(NotImplementedError, match="dense transformer"):
-        compile_lm(cfg.replace(family="moe"))
+    with pytest.raises(NotImplementedError,
+                       match="dense and MoE transformer"):
+        compile_lm(cfg.replace(family="ssm"))
 
 
 def test_compile_chip_points_model_configs_at_compile_lm(setup):
@@ -294,3 +295,219 @@ def test_two_device_sensor_lm_duo_subprocess(sim_subprocess):
     assert res["fleet_items"] == res["lm_items"] + res["sensor_items"]
     assert res["lanes_exact"] and res["requests_exact"]
     assert res["report_apps"] == ["lm", "sensor"]
+
+
+# ------------------------------------------------------------------- #
+# the moe family: latent attention, a dense prefix, held experts
+# ------------------------------------------------------------------- #
+def _moonlight(**kw):
+    """The reduced Moonlight block in float32 with a float32 latent
+    cache, so the mapped path meets the dense one at float rounding;
+    16 router outputs, 8 of them held here."""
+    from repro.configs import moonshot_v1_16b_a3b
+
+    return moonshot_v1_16b_a3b.reduced().replace(
+        compute_dtype="float32", decode_per_slot=True, num_experts=16,
+        top_k=4, held_experts=tuple(range(8)), kv_cache_dtype="float32",
+        **kw)
+
+
+@pytest.fixture(scope="module")
+def moe_setup():
+    cfg = _moonlight()
+    params = model_lib.init_params(cfg, jax.random.PRNGKey(4))
+    return cfg, params
+
+
+def _full_logits(cfg, params, toks):
+    """Every position's logits of the dense forward (no cache)."""
+    with jax.default_matmul_precision("highest"):
+        h, _, _ = model_lib.forward(cfg, params, {"tokens": toks},
+                                    mode="train")
+        return np.asarray(model_lib._head(cfg, params, h))
+
+
+def test_moe_mla_prefill_then_decode_matches_full_forward(moe_setup):
+    """compile_lm on the moe family: prefill, then decode through the
+    latent cache lane by lane, against the dense model's full forward
+    over the same tokens (logits)."""
+    cfg, params = moe_setup
+    clm = compile_lm(TransformerParams(cfg, params))
+    assert set(clm.plans[0]) == {"wq", "wkv_a", "wkv_b", "wo", "wkv_b.k",
+                                 "wkv_b.v", "w1", "w3", "w2"}
+    assert {"router", "experts.w1", "shared.w2"} <= set(clm.plans[1])
+    rng = np.random.default_rng(2)
+    toks = rng.integers(0, cfg.vocab_size, size=(2, 12))
+    full = _full_logits(cfg, params, toks)
+    logits, cache = clm.prefill(toks[:, :8])
+    assert _rel(logits, full[:, 7]) <= 1e-6
+    ring = clm.init_cache(2, 16, jnp.float32)
+    from repro.serving import kvcache
+    for lane in range(2):
+        one = jax.tree.map(lambda x, i=lane: x[:, i:i + 1], cache)
+        ring = kvcache.write_slot(ring, one, jnp.int32(lane))
+    for pos in range(8, 12):
+        nxt, logits, counts, ring = clm.step(
+            ring, toks[:, pos:pos + 1], np.full((2,), pos))
+        assert _rel(logits, full[:, pos]) <= 1e-6
+        assert np.array_equal(np.asarray(nxt),
+                              np.argmax(np.asarray(logits), -1))
+        # two MoE layers, 2 lanes, 8 held experts: 32 rows, none dropped
+        assert counts.tolist()[1:] == [2 * 2 * 8, 0]
+        assert 0 < int(counts[0]) <= 2 * 2 * cfg.top_k
+
+
+def test_absorbed_decode_matches_expanded_attention():
+    """One latent-attention layer: the decode step's absorbed form
+    (q_nope into the latent through wkv_b's key half, the output back
+    through its value half) against the prefill's expanded K/V at the
+    same position."""
+    from repro.models import attention as attn
+
+    cfg = _moonlight()
+    p = attn.mla_init(jax.random.PRNGKey(1), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 9, cfg.d_model))
+    pos = jnp.broadcast_to(jnp.arange(9)[None], (2, 9))
+    with jax.default_matmul_precision("highest"):
+        full, _ = attn.mla_apply(p, cfg, x, positions=pos, mode="train")
+        _, cache = attn.mla_apply(p, cfg, x[:, :8], positions=pos[:, :8],
+                                  mode="prefill")
+        cache = jax.tree.map(
+            lambda c: jnp.pad(c, ((0, 0), (0, 4), (0, 0))), cache)
+        step, new = attn.mla_apply(p, cfg, x[:, 8:], positions=pos[:, 8:],
+                                   mode="decode", cache=cache)
+    assert _rel(step[:, 0], full[:, 8]) <= 1e-5
+    # the cache holds the latent and the roped key, nothing expanded
+    assert new["c_kv"].shape == (2, 12, cfg.kv_lora_rank)
+    assert new["k_pe"].shape == (2, 12, cfg.qk_rope_head_dim)
+
+
+def test_route_sigmoid_bias_normalise_scale():
+    """DeepSeek-V3 routing by hand: selection ranks sigmoid + bias, the
+    weights are the unbiased sigmoid scores of the chosen, normalised
+    and scaled."""
+    from repro.models import moe
+
+    cfg = _moonlight().replace(num_experts=4, top_k=2, router_scale=2.5)
+    logits = jnp.asarray([[2.0, 1.0, 0.0, -1.0]])
+    bias = jnp.asarray([0.0, 0.0, 0.6, 0.0])
+    w, ids, scores = moe.route(cfg, logits, bias)
+    s = 1.0 / (1.0 + np.exp(-np.asarray([2.0, 1.0, 0.0, -1.0])))
+    # s + bias = [.881, .731, 1.1, .269]: experts 2 then 0
+    assert ids.tolist() == [[2, 0]]
+    want = np.asarray([s[2], s[0]]) / (s[2] + s[0]) * 2.5
+    np.testing.assert_allclose(np.asarray(w[0]), want, rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(scores[0]), s, rtol=1e-6)
+    # without the bias the top two are experts 0 and 1
+    _, ids, _ = moe.route(cfg, logits)
+    assert ids.tolist() == [[0, 1]]
+
+
+def test_held_expert_shares_sum_to_the_uncut_layer():
+    """Guide §4: at a small size, the shares of chips that each hold
+    a slice of the experts, with the shared expert counted once, add
+    up to the whole layer computed uncut (the capacity dispatch with
+    every expert, drop-free at this capacity factor)."""
+    from repro.models import moe
+
+    whole = _moonlight().replace(held_experts=())
+    p = moe.moe_init(jax.random.PRNGKey(3), whole)
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 6, whole.d_model))
+    with jax.default_matmul_precision("highest"):
+        y_all, _ = moe.moe_apply(p, whole, x)
+        shared = moe.shared_mlp(p, whole, x)
+        total = -(whole.num_experts // 8 - 1) * shared
+        for chip in range(whole.num_experts // 8):
+            held = tuple(range(8 * chip, 8 * chip + 8))
+            share = dict(p, **{k: p[k][8 * chip:8 * chip + 8]
+                               for k in ("w1", "w3", "w2")})
+            y, aux = moe.moe_apply(share, whole.replace(held_experts=held),
+                                   x)
+            assert float(aux["drop_frac"]) == 0.0
+            total = total + y
+    assert _rel(total, y_all) <= 1e-5
+
+
+def test_kvcache_slot_surgery_moves_one_latent_lane():
+    """write_slot / clear_slot on the latent cache (c_kv, k_pe): the
+    lane written holds the prefilled latents, every other lane is
+    untouched."""
+    from repro.serving import kvcache
+
+    cfg = _moonlight()
+    cache = model_lib.init_cache(cfg, 3, 8, jnp.float32)
+    assert set(cache) == {"c_kv", "k_pe"}
+    rng = np.random.default_rng(0)
+    cache = jax.tree.map(
+        lambda c: jnp.asarray(rng.standard_normal(c.shape), c.dtype), cache)
+    before = jax.tree.map(np.asarray, cache)
+    one = jax.tree.map(
+        lambda c: jnp.asarray(rng.standard_normal(
+            (c.shape[0], 1, 5) + c.shape[3:]), c.dtype), cache)
+    out = kvcache.write_slot(cache, one, jnp.int32(1))
+    for name in ("c_kv", "k_pe"):
+        got = np.asarray(out[name])
+        np.testing.assert_array_equal(got[:, 1, :5],
+                                      np.asarray(one[name])[:, 0])
+        np.testing.assert_array_equal(got[:, 1, 5:], before[name][:, 1, 5:])
+        np.testing.assert_array_equal(got[:, [0, 2]], before[name][:, [0, 2]])
+    out = kvcache.clear_slot(out, jnp.int32(2))
+    for name in ("c_kv", "k_pe"):
+        got = np.asarray(out[name])
+        assert not got[:, 2].any()
+        np.testing.assert_array_equal(got[:, 0], before[name][:, 0])
+        np.testing.assert_array_equal(got[:, 1, :5],
+                                      np.asarray(one[name])[:, 0])
+
+
+def test_moe_tenant_serves_through_deploy(moe_setup):
+    """deploy() → compile_lm → LMMember → the router for the moe
+    family: greedy tokens equal the dense engine's over the same
+    weights, and the step counts expert rows without a drop."""
+    cfg, params = moe_setup
+    from repro.deploy import AppSpec, deploy
+
+    dep = deploy(AppSpec("lm", cfg, params=params, cache_len=32,
+                         lanes_per_chip=2))
+    rng = np.random.default_rng(6)
+    prompts = [list(rng.integers(0, cfg.vocab_size, size=n))
+               for n in (5, 7, 4)]
+    for p in prompts:
+        assert dep.submit_tokens("lm", p, max_new_tokens=4)
+    dep.run_until_drained()
+    got = dep.generated_tokens("lm")
+    with jax.default_matmul_precision("highest"):
+        oracle = _engine_oracle(cfg, params, prompts, 4, cache_len=32)
+    assert [got[uid] for uid in sorted(got)] == oracle
+    member = dep.router.members["lm"]
+    assigned, rows, dropped = member.expert_counts.tolist()
+    assert rows > 0 and 0 < assigned <= rows and dropped == 0
+    assert member.cache["c_kv"].dtype == jnp.float32
+    dep.close()
+
+
+
+def test_lm_above_the_routed_tile_limit_is_served_unpriced(setup,
+                                                          monkeypatch):
+    """Above ``ROUTED_TILE_LIMIT`` tiles compile_lm builds no cost chip:
+    the tenant is served, left out of the report, and refuses a
+    tokens/second SLO it could not check."""
+    from repro.deploy import AppSpec, deploy
+    from repro.lm import compile as lm_compile
+
+    cfg, params = setup
+    monkeypatch.setattr(lm_compile, "ROUTED_TILE_LIMIT", 1)
+    clm = compile_lm(TransformerParams(cfg, params))
+    assert clm.chip is None
+    with pytest.raises(ValueError, match="not priced"):
+        clm.report()
+    dep = deploy(AppSpec("lm", cfg, params=params, cache_len=16,
+                         lanes_per_chip=1))
+    assert dep.submit_tokens("lm", [1, 2, 3], max_new_tokens=2)
+    dep.run_until_drained()
+    assert len(dep.generated_tokens("lm")) == 1
+    assert "lm" not in dep.report().apps
+    dep.close()
+    with pytest.raises(ValueError, match="tokens/second"):
+        deploy(AppSpec("lm", cfg, params=params, cache_len=16,
+                       lanes_per_chip=1, items_per_second=10.0))
