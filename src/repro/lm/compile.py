@@ -244,8 +244,9 @@ def _lm_forward(clm: "CompiledLM", batch, mode: str, cache,
     cache is bit-compatible with the dense engine's, which is what lets
     ``serving.kvcache`` slot surgery work unchanged. Decode writes each
     layer's new cache row in place into the whole (donated) cache.
-    Returns (h, cache, stats): ``stats`` int32 (3,) sums the MoE
-    layers' (assignments, rows, dropped)."""
+    Returns (h, cache, counts, read): ``counts`` int32 (3,) sums the
+    MoE layers' (assignments, rows, dropped), ``read`` int32 (2,) the
+    latent-attention decodes' (slots read per lane, ring slots)."""
     cfg, params = clm.cfg, clm.params
     dtype = jnp.dtype(cfg.compute_dtype)
     h = model_lib._embed_in(cfg, params, batch, dtype)
@@ -257,7 +258,7 @@ def _lm_forward(clm: "CompiledLM", batch, mode: str, cache,
         positions = jnp.broadcast_to(
             jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
     windows = tf._layer_windows(cfg)
-    caches, stats = [], []
+    caches, stats, reads = [], [], []
     for layer, (p_l, moe) in enumerate(_layer_params(cfg,
                                                      params["stack"])):
         plans = clm.plans[layer]
@@ -268,7 +269,7 @@ def _lm_forward(clm: "CompiledLM", batch, mode: str, cache,
             p_l, cfg, h, positions=positions, mode=mode,
             cache=cache if decode else None, window=windows[layer],
             use_moe=moe, project=project, mlp_fn=mlp,
-            layer=layer if decode else None)
+            layer=layer if decode else None, attn_stats=reads)
         if decode:
             cache = c_new
         else:
@@ -281,15 +282,16 @@ def _lm_forward(clm: "CompiledLM", batch, mode: str, cache,
     for st in stats:
         counts = counts + jnp.stack([st["assignments"], st["rows"],
                                      st["dropped"]])
-    return h, cache, counts
+    read = sum(reads, jnp.zeros((2,), jnp.int32))
+    return h, cache, counts, read
 
 
 @partial(jax.jit, static_argnames=("use_kernel",))
 def _prefill(clm: "CompiledLM", tokens: jax.Array,
              use_kernel: bool = False):
     with jax.default_matmul_precision("highest"):
-        h, cache, _ = _lm_forward(clm, {"tokens": tokens}, "prefill",
-                                  None, use_kernel)
+        h, cache, _, _ = _lm_forward(clm, {"tokens": tokens},
+                                     "prefill", None, use_kernel)
         logits = model_lib._head(clm.cfg, clm.params,
                                  h[:, -1:, :])[:, 0, :]
     return logits, cache
@@ -300,12 +302,12 @@ def _prefill(clm: "CompiledLM", tokens: jax.Array,
 def _decode(clm: "CompiledLM", cache, tokens: jax.Array,
             pos: jax.Array, use_kernel: bool = False):
     with jax.default_matmul_precision("highest"):
-        h, new_cache, counts = _lm_forward(
+        h, new_cache, counts, read = _lm_forward(
             clm, {"tokens": tokens, "pos": pos}, "decode", cache,
             use_kernel)
         logits = model_lib._head(clm.cfg, clm.params, h)[:, 0, :]
     next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return next_tokens, logits, counts, new_cache
+    return next_tokens, logits, counts, read, new_cache
 
 
 # --------------------------------------------------------------------- #
@@ -365,6 +367,14 @@ class CompiledLM:
         """One serving step: → (greedy next tokens (B,) int32, logits
         (B, vocab), MoE dispatch counts int32 (assignments, rows,
         dropped), new_cache), all left on the device."""
+        nxt, logits, counts, _, new_cache = self.serve_step(
+            cache, tokens, pos, use_kernel=use_kernel)
+        return nxt, logits, counts, new_cache
+
+    def serve_step(self, cache, tokens, pos, *, use_kernel: bool = False):
+        """:meth:`step` with, before the cache, the latent attention's
+        read: int32 (slots its branch read per lane, ring slots), summed
+        over the layers; (0, 0) without latent attention."""
         return _decode(self, cache, jnp.asarray(tokens, jnp.int32),
                        jnp.asarray(pos, jnp.int32), use_kernel)
 

@@ -32,6 +32,10 @@ in the same read-back, never by a sync of their own:
 ``lm.expert_assignments`` (token-expert pairs on held experts),
 ``lm.expert_rows`` (rows the grouped expert layer computed) and
 ``lm.expert_dropped`` (pairs that found no slot; 0 by construction).
+A latent-attention tenant also counts, per step and summed over its
+layers, the ring slots its decode's branch read per lane and the
+ring's slots: ``lm.attn_slots_read`` and ``lm.attn_slots_ring``, read
+back with the tokens too.
 """
 from __future__ import annotations
 
@@ -160,6 +164,9 @@ class LMMember:
     def on_release(self, lane: int) -> None:
         self.cache = kvcache.clear_slot(self.cache, jnp.int32(lane))
         self._live.discard(lane)
+        # a free lane decodes junk at its position: at 0 it does not
+        # hold the latent decode's prefix at the ring's length
+        self._pos[lane] = 0
 
     # ---------------- one batched decode step ----------------------- #
     def stream_host(self, batch: np.ndarray, *,
@@ -170,10 +177,10 @@ class LMMember:
         out = self._next_tok.astype(np.float32)[:, None]
         t0 = time.perf_counter()
         with _obs_span("lm.decode", cat="lm"):
-            nxt, self.last_logits, counts, self.cache = self.clm.step(
-                self.cache, self._next_tok[:, None], self._pos,
-                use_kernel=use_kernel)
-            nxt, counts = jax.device_get((nxt, counts))
+            nxt, self.last_logits, counts, read, self.cache = \
+                self.clm.serve_step(self.cache, self._next_tok[:, None],
+                                    self._pos, use_kernel=use_kernel)
+            nxt, counts, read = jax.device_get((nxt, counts, read))
         dt = time.perf_counter() - t0
         for lane in self._live:
             self._next_tok[lane] = nxt[lane]
@@ -190,6 +197,9 @@ class LMMember:
                 m.counter("lm.expert_assignments").inc(int(counts[0]))
                 m.counter("lm.expert_rows").inc(int(counts[1]))
                 m.counter("lm.expert_dropped").inc(int(counts[2]))
+            if read[1]:
+                m.counter("lm.attn_slots_read").inc(int(read[0]))
+                m.counter("lm.attn_slots_ring").inc(int(read[1]))
         return out
 
     # ---------------- elastic resize -------------------------------- #

@@ -30,6 +30,8 @@ from repro.models.layers import apply_rope, dense_init, rms_norm, softcap
 from repro.sharding import shard
 
 NEG_INF = -2.0e38
+# ring slots per block of the latent decode's prefix (mla_apply)
+DECODE_BLOCK = 512
 
 
 # --------------------------------------------------------------------- #
@@ -174,6 +176,17 @@ def _ring_positions(pos: jax.Array, T: int) -> jax.Array:
     return pos - ((pos % T - j) % T)
 
 
+def _decode_prefix(pos_b, T: int):
+    """The latent decode's prefixes of a ring of ``T`` slots: their
+    lengths, whole blocks of ``DECODE_BLOCK`` slots from slot 0 (the
+    last one cut at T), and the index of the shortest that holds every
+    lane's written slots once each lane has written position ``pos_b``
+    (B,): the whole ring once a lane has wrapped. numpy or jax."""
+    block = min(DECODE_BLOCK, T)
+    ends = [min(n, T) for n in range(block, T + block, block)]
+    return ends, pos_b.max().clip(max=T - 1) // block
+
+
 def _slot_write(leaf: jax.Array, layer, bidx: jax.Array, idx: jax.Array,
                 val: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Write ``val`` (B, ...) at ring slot ``idx`` (B,) of every lane
@@ -213,8 +226,8 @@ def _store_prefill_scale(cache_len: int, s: jax.Array) -> jax.Array:
 # --------------------------------------------------------------------- #
 def attn_apply(p: Dict, cfg, x: jax.Array, *, positions: jax.Array,
                mode: str, cache: Optional[Dict] = None,
-               window=None, project=None,
-               layer=None) -> Tuple[jax.Array, Optional[Dict]]:
+               window=None, project=None, layer=None,
+               stats=None) -> Tuple[jax.Array, Optional[Dict]]:
     """x: (B, S, d). positions: (B, S) absolute token positions.
 
     mode: "train" (no cache), "prefill" (build cache), "decode" (S == 1,
@@ -231,10 +244,12 @@ def attn_apply(p: Dict, cfg, x: jax.Array, *, positions: jax.Array,
     layer: per-slot decode only — ``cache`` is then the whole
     layer-stacked cache, written in place at this layer and returned
     whole (``repro.lm``'s unrolled decode).
+    stats: see :func:`mla_apply` (latent attention only).
     Returns (out (B, S, d), new_cache)."""
     if cfg.mla:
         return mla_apply(p, cfg, x, positions=positions, mode=mode,
-                         cache=cache, project=project, layer=layer)
+                         cache=cache, project=project, layer=layer,
+                         stats=stats)
     dt = x.dtype
     B, S, _ = x.shape
     H, KH, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -407,7 +422,7 @@ def _mla_dense_projector(cfg, p: Dict):
 
 def mla_apply(p: Dict, cfg, x: jax.Array, *, positions: jax.Array,
               mode: str, cache: Optional[Dict] = None, project=None,
-              layer=None) -> Tuple[jax.Array, Optional[Dict]]:
+              layer=None, stats=None) -> Tuple[jax.Array, Optional[Dict]]:
     """DeepSeek-V3 attention with no q-LoRA. x: (B, S, d).
 
     q = x·wq split into q_nope (dn) and q_pe (dr, roped); x·wkv_a splits
@@ -419,7 +434,11 @@ def mla_apply(p: Dict, cfg, x: jax.Array, *, positions: jax.Array,
     attention; prefill returns the cache {c_kv: c, k_pe}.
     decode (S == 1): c and k_pe are written at each lane's ring slot,
     then q_nope·k_nope = (wkv_b_k q_nope)·c (absorbed) and the output
-    is wkv_b_v·(Σ_t a_t c_t), read straight from the latent cache.
+    is wkv_b_v·(Σ_t a_t c_t), read straight from the latent cache, over
+    the ``_decode_prefix`` that holds every lane's written slots: those
+    past every lane's newest are unwritten and weigh exactly 0. A list
+    ``stats`` gets, per decode, int32 (2,): the slots each lane's
+    attention read, from the branch the step took, and the ring's.
 
     ``project(name, x)`` overrides the matmuls (``mla_linears`` names;
     the per-head ``MLA_HEAD_LINEARS`` take and give (N, H, ·));
@@ -445,21 +464,40 @@ def mla_apply(p: Dict, cfg, x: jax.Array, *, positions: jax.Array,
         T = cache["c_kv"].shape[-2]
         idx = pos_b % T
         bidx = jnp.arange(B)
-        full_c, c_all = _slot_write(cache["c_kv"], layer, bidx, idx,
-                                    c[:, 0])
-        full_pe, pe_all = _slot_write(cache["k_pe"], layer, bidx, idx,
-                                      k_pe[:, 0])
-        k_pos = jax.vmap(_ring_positions, (0, None))(pos_b, T)  # (B, T)
-        c_all, pe_all = c_all.astype(dt), pe_all.astype(dt)
+        full_c, _ = _slot_write(cache["c_kv"], layer, bidx, idx, c[:, 0])
+        full_pe, _ = _slot_write(cache["k_pe"], layer, bidx, idx,
+                                 k_pe[:, 0])
         q_lat = project("wkv_b.k", q_nope.reshape(B, H, dn))    # (B, H, r)
-        scores = (jnp.einsum("bhr,btr->bht", q_lat, c_all,
-                             preferred_element_type=jnp.float32) +
-                  jnp.einsum("bhe,bte->bht", q_pe[:, 0], pe_all,
-                             preferred_element_type=jnp.float32)) * scale
-        mask = (k_pos <= pos_b[:, None]) & (k_pos >= 0)
-        w = _masked_softmax(scores, mask[:, None, :])
-        o_lat = jnp.einsum("bht,btr->bhr", w.astype(dt), c_all,
-                           preferred_element_type=jnp.float32).astype(dt)
+
+        def attend_prefix(n):
+            # the ring's first n slots, sliced from the written cache
+            # itself (layer-stacked or not): no layer slab is copied
+            def prefix(full):
+                part = full[:, :n] if layer is None else full[layer, :, :n]
+                return part.astype(dt)
+
+            def branch(full_c, full_pe, q_lat, q_pe):
+                c_all, pe_all = prefix(full_c), prefix(full_pe)
+                scores = (jnp.einsum("bhr,btr->bht", q_lat, c_all,
+                                     preferred_element_type=jnp.float32) +
+                          jnp.einsum("bhe,bte->bht", q_pe, pe_all,
+                                     preferred_element_type=jnp.float32)
+                          ) * scale
+                kp = jax.vmap(_ring_positions, (0, None))(pos_b, T)[:, :n]
+                mask = (kp <= pos_b[:, None]) & (kp >= 0)
+                w = _masked_softmax(scores, mask[:, None, :])
+                return jnp.einsum("bht,btr->bhr", w.astype(dt), c_all,
+                                  preferred_element_type=jnp.float32
+                                  ), jnp.int32(n)
+            return branch
+
+        ends, i = _decode_prefix(pos_b, T)
+        o_lat, read = jax.lax.switch(
+            i, [attend_prefix(n) for n in ends],
+            full_c, full_pe, q_lat, q_pe[:, 0])
+        if stats is not None:
+            stats.append(jnp.stack([read, jnp.int32(T)]))
+        o_lat = o_lat.astype(dt)
         out = project("wkv_b.v", o_lat).reshape(B, S, H * dv)
         return project("wo", out), {"c_kv": full_c, "k_pe": full_pe}
 

@@ -98,18 +98,19 @@ def _block_specs(cfg, use_moe: bool):
 
 
 def _block_apply(p, cfg, h, *, positions, mode, cache, window, use_moe,
-                 project=None, mlp_fn=None, layer=None):
+                 project=None, mlp_fn=None, layer=None, attn_stats=None):
     """project/mlp_fn: optional overrides of the attention's linear
     projections (see ``attn.attn_apply``) and of the whole MLP or MoE
     layer; ``repro.lm`` substitutes crossbar-mapped tile grids for the
     block's matmuls while norms, residuals, rope, softmax, routing and
     cache surgery stay in this host graph. ``layer``: see
-    ``attn.attn_apply``."""
+    ``attn.attn_apply``; ``attn_stats``: its ``stats``."""
     a_in = rms_norm(h, p["attn_norm"], cfg.norm_eps)
     a_out, new_cache = attn.attn_apply(p["attn"], cfg, a_in,
                                        positions=positions, mode=mode,
                                        cache=cache, window=window,
-                                       project=project, layer=layer)
+                                       project=project, layer=layer,
+                                       stats=attn_stats)
     if cfg.post_block_norm:
         a_out = rms_norm(a_out, p["attn_post"], cfg.norm_eps)
     h = h + a_out

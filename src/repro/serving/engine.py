@@ -706,6 +706,7 @@ class Engine(SlotScheduler):
 
     def _release(self, st: RequestState) -> None:
         self.cache = kvcache.clear_slot(self.cache, jnp.int32(st.slot))
+        self._pos[st.slot] = 0      # see LMMember.on_release
 
     def _step_active(self) -> int:
         """ONE batched decode for all active lanes, each at its own

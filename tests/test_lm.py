@@ -382,6 +382,146 @@ def test_absorbed_decode_matches_expanded_attention():
     assert new["k_pe"].shape == (2, 12, cfg.qk_rope_head_dim)
 
 
+def _full_ring_decode(p, cfg, x, positions, cache, layer=None):
+    """The latent decode over every one of the ring's T slots, as it
+    was before the prefix: the oracle of the prefix's answer."""
+    from repro.models import attention as attn
+    from repro.models.layers import apply_rope, rms_norm
+
+    dt = x.dtype
+    B = x.shape[0]
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    scale = (dn + dr) ** -0.5
+    project = attn._mla_dense_projector(cfg, p)
+    q = project("wq", x).reshape(B, 1, H, dn + dr)
+    q_pe = apply_rope(q[..., dn:], positions, cfg.rope_theta)
+    kv = project("wkv_a", x)
+    c = rms_norm(kv[..., :r], p["kv_norm"], cfg.norm_eps)
+    k_pe = apply_rope(kv[..., None, r:], positions, cfg.rope_theta)[:, :, 0]
+    pos_b = positions[:, 0]
+    T = cache["c_kv"].shape[-2]
+    idx, bidx = pos_b % T, jnp.arange(B)
+    full_c, c_all = attn._slot_write(cache["c_kv"], layer, bidx, idx, c[:, 0])
+    full_pe, pe_all = attn._slot_write(cache["k_pe"], layer, bidx, idx,
+                                       k_pe[:, 0])
+    k_pos = jax.vmap(attn._ring_positions, (0, None))(pos_b, T)
+    q_lat = project("wkv_b.k", q[..., :dn].reshape(B, H, dn))
+    scores = (jnp.einsum("bhr,btr->bht", q_lat, c_all) +
+              jnp.einsum("bhe,bte->bht", q_pe[:, 0], pe_all)) * scale
+    mask = (k_pos <= pos_b[:, None]) & (k_pos >= 0)
+    w = attn._masked_softmax(scores, mask[:, None, :])
+    o_lat = jnp.einsum("bht,btr->bhr", w.astype(dt), c_all)
+    out = project("wkv_b.v", o_lat).reshape(B, 1, H * dv)
+    return project("wo", out), {"c_kv": full_c, "k_pe": full_pe}
+
+
+# ring of four whole blocks and a short fifth; the lanes' positions:
+# a block's first and last slot, inside the fourth, the ring's last
+# slot, and one lane that has wrapped
+_BLOCK = 512
+_RING = 4 * _BLOCK + 100
+_LANE_POS = (0, _BLOCK - 1, _BLOCK, 3 * _BLOCK + 5, _RING - 1, _RING + 7)
+
+
+@pytest.mark.parametrize("layer", [None, 1])
+@pytest.mark.parametrize("lanes,blocks", [(1, 1), (2, 1), (3, 2), (4, 4),
+                                          (5, 5), (6, 5)])
+def test_latent_decode_prefix_matches_the_full_ring(lanes, blocks, layer):
+    """mla_apply's decode attends over the ring's first written blocks
+    only; with the lanes at mixed positions (the newest one setting
+    the prefix, a wrapped one the whole ring) it matches the full-ring
+    formula, output and written cache, and the unwritten slots, filled
+    with noise here, take no part; ``stats`` gets the taken branch's
+    slots and the ring's."""
+    from repro.models import attention as attn
+
+    assert attn.DECODE_BLOCK == _BLOCK
+    cfg = _moonlight()
+    p = attn.mla_init(jax.random.PRNGKey(1), cfg)
+    pos = jnp.asarray(_LANE_POS[:lanes], jnp.int32)[:, None]
+    ends, i = attn._decode_prefix(pos[:, 0], _RING)
+    assert int(i) + 1 == blocks
+    x = jax.random.normal(jax.random.PRNGKey(2), (lanes, 1, cfg.d_model))
+    lead = (lanes, _RING) if layer is None else (2, lanes, _RING)
+    ks = jax.random.split(jax.random.PRNGKey(3))
+    cache = {"c_kv": jax.random.normal(ks[0], lead + (cfg.kv_lora_rank,)),
+             "k_pe": jax.random.normal(ks[1],
+                                       lead + (cfg.qk_rope_head_dim,))}
+    stats = []
+    with jax.default_matmul_precision("highest"):
+        got, got_cache = attn.mla_apply(p, cfg, x, positions=pos,
+                                        mode="decode", cache=cache,
+                                        layer=layer, stats=stats)
+        want, want_cache = _full_ring_decode(p, cfg, x, pos, cache, layer)
+    # float32 rounding of a shorter reduction, relative to the largest
+    assert _rel(got, want) <= 1e-6
+    assert [s.tolist() for s in stats] == [[ends[blocks - 1], _RING]]
+    for name in ("c_kv", "k_pe"):
+        np.testing.assert_array_equal(np.asarray(got_cache[name]),
+                                      np.asarray(want_cache[name]))
+
+
+@pytest.mark.parametrize("ring,newest,blocks", [
+    (4096, 0, 1), (4096, 511, 1), (4096, 512, 2), (4096, 1023, 2),
+    (4096, 1024, 3), (4096, 2559, 5), (4096, 2560, 6), (4096, 4095, 8),
+    (4096, 4096, 8), (4096, 70000, 8), (2148, 2047, 4), (2148, 2048, 5),
+    (2148, 2147, 5), (2148, 2148, 5), (600, 599, 2), (64, 63, 1),
+    (64, 64, 1)])
+def test_written_slots_at_the_block_boundaries(ring, newest, blocks):
+    """The fewest blocks from slot 0 that hold every lane's written
+    slots: the newest lane's position + 1, over the block, rounded up;
+    the whole ring (a short last block counted) once a lane wraps;
+    numpy positions (the host's counter) and jax ones (the step) alike;
+    the prefixes are whole blocks, the last one cut at the ring."""
+    from repro.models.attention import DECODE_BLOCK, _decode_prefix
+
+    block = min(DECODE_BLOCK, ring)
+    pos = np.asarray([0, newest // 2, newest], np.int32)
+    ends, i = _decode_prefix(pos, ring)
+    assert ends == [min(n * block, ring) for n in range(1, len(ends) + 1)]
+    assert ends[-1] == ring and int(i) + 1 == blocks
+    assert int(jax.jit(lambda q: _decode_prefix(q, ring)[1])(
+        jnp.asarray(pos))) + 1 == blocks
+
+
+def test_lm_member_counts_the_prefix_and_frees_lanes_at_zero(moe_setup):
+    """A latent-attention member counts, per decode step and layer,
+    the slots its decode's branch read per lane (``lm.attn_slots_read``:
+    one 512-slot block while every lane is short) and the ring
+    (``lm.attn_slots_ring``); a released lane's position is 0, so a
+    free lane does not hold the prefix at the ring's length."""
+    from repro import obs
+    from repro.deploy import AppSpec, deploy
+    from repro.models.attention import _decode_prefix
+
+    cfg, params = moe_setup
+    ring = 1100
+    dep = deploy(AppSpec("lm", cfg, params=params, cache_len=ring,
+                         lanes_per_chip=2))
+    member = dep.router.members["lm"]
+    rng = np.random.default_rng(8)
+    for n in (5, 7):
+        assert dep.submit_tokens("lm", list(rng.integers(
+            0, cfg.vocab_size, size=n)), max_new_tokens=3)
+    tel = obs.configure(metrics=True, trace=False)
+    try:
+        steps = 0
+        while dep.router.active or dep.router.queue:
+            dep.step()
+            steps += member.last_live > 0
+        counters = tel.metrics.snapshot()["counters"]
+    finally:
+        obs.disable()
+        dep.close()
+    assert steps > 0
+    layers = cfg.num_layers
+    assert counters["lm.attn_slots_read"] == 512 * layers * steps
+    assert counters["lm.attn_slots_ring"] == ring * layers * steps
+    assert member._pos.tolist() == [0, 0]
+    assert int(_decode_prefix(member._pos, ring)[1]) == 0
+
+
 def test_route_sigmoid_bias_normalise_scale():
     """DeepSeek-V3 routing by hand: selection ranks sigmoid + bias, the
     weights are the unbiased sigmoid scores of the chosen, normalised
