@@ -281,7 +281,8 @@ _FLEET_SCRIPT = textwrap.dedent("""
     import numpy as np
     from repro.chip import compile_chip
     from repro.core.crossbar_layer import MLPSpec, mlp_init
-    from repro.fleet import FleetRouter, shard_chip
+    from repro.deploy import MultiAppRouter
+    from repro.fleet import shard_chip
     from repro.serving.engine import ItemRequest
 
     DIMS = %r
@@ -299,12 +300,17 @@ _FLEET_SCRIPT = textwrap.dedent("""
     # ragged lengths (6..10 items): requests retire continuously, so
     # throughput measures backfill under churn, not lockstep waves
     bursts = [[ItemRequest(uid=i, items=rng.uniform(
-                   0, 1, (6 + i %% 5, DIMS[0])))
+                   0, 1, (6 + i %% 5, DIMS[0])), key="app")
                for i in range(N_REQ)] for _ in range(ROUNDS)]
 
 
+    def router_for(fleet):
+        return MultiAppRouter({"app": fleet},
+                              lanes={"app": LANES * fleet.n_chips})
+
+
     def one_burst(fleet, burst):
-        router = FleetRouter(fleet, lanes_per_chip=LANES)
+        router = router_for(fleet)
         for r in burst:
             router.submit(r)
         t0 = time.perf_counter()
@@ -313,9 +319,10 @@ _FLEET_SCRIPT = textwrap.dedent("""
 
     fleets = {1: shard_chip(chip, 1), %d: shard_chip(chip, %d)}
     for fleet in fleets.values():    # trace + compile the step shapes
-        w = FleetRouter(fleet, lanes_per_chip=LANES)
+        w = router_for(fleet)
         w.submit(ItemRequest(uid=-1,
-                             items=rng.uniform(0, 1, (2, DIMS[0]))))
+                             items=rng.uniform(0, 1, (2, DIMS[0])),
+                             key="app"))
         w.run_until_drained()
     # interleave rounds so a noisy window on this shared box hits both
     # fleet sizes alike; best-of per size is then comparable
@@ -338,8 +345,9 @@ _FLEET_SCRIPT = textwrap.dedent("""
 # --------------------------------------------------------------------- #
 # Subprocess for the same simulated-device reason as _fleet_serve. Each
 # round serves the same burst twice: once healthy on 4 chips end to end,
-# once losing a chip mid-drain (router.resize(3) — the same zero-compile
-# re-placement repro.fleet.ha's degraded mode uses), timing only the
+# once losing a chip mid-drain (the member's resize(3) and the router's
+# resize_lanes — the same zero-compile re-placement and lane rebuild
+# repro.fleet.ha's degraded mode uses), timing only the
 # post-loss window. The gates pin the three degraded-mode promises:
 # throughput stays proportional to surviving capacity (>= 0.6x of the
 # 3/4 expectation — the backfill scheduler must keep the surviving
@@ -355,7 +363,8 @@ _DEGRADED_SCRIPT = textwrap.dedent("""
     import numpy as np
     from repro.chip import compile_chip, compile_count
     from repro.core.crossbar_layer import MLPSpec, mlp_init
-    from repro.fleet import FleetRouter, shard_chip
+    from repro.deploy import MultiAppRouter
+    from repro.fleet import shard_chip
     from repro.serving.engine import ItemRequest
 
     DIMS = %r
@@ -371,15 +380,20 @@ _DEGRADED_SCRIPT = textwrap.dedent("""
     chip = compile_chip(spec, params=params)
     rng = np.random.default_rng(0)
     bursts = [[ItemRequest(uid=i, items=rng.uniform(
-                   0, 1, (6 + i %% 5, DIMS[0])))
+                   0, 1, (6 + i %% 5, DIMS[0])), key="app")
                for i in range(N_REQ)] for _ in range(ROUNDS)]
     fleet = shard_chip(chip, DEVICES)
     c0 = compile_count()
 
 
+    def router_for(fleet):
+        return MultiAppRouter({"app": fleet},
+                              lanes={"app": LANES * fleet.n_chips})
+
+
     def healthy(burst):
         fleet.resize(DEVICES)
-        router = FleetRouter(fleet, lanes_per_chip=LANES)
+        router = router_for(fleet)
         for r in burst:
             router.submit(r)
         t0 = time.perf_counter()
@@ -389,14 +403,15 @@ _DEGRADED_SCRIPT = textwrap.dedent("""
 
     def degraded(burst):
         fleet.resize(DEVICES)
-        router = FleetRouter(fleet, lanes_per_chip=LANES)
+        router = router_for(fleet)
         for r in burst:
             router.submit(r)
         for _ in range(2):
             router.step()               # lanes busy: a real mid-serve
         at_loss = router.items_emitted  # loss, not a cold restart
         t0 = time.perf_counter()
-        router.resize(SURVIVORS)
+        fleet.resize(SURVIVORS)
+        router.resize_lanes({"app": LANES * SURVIVORS})
         done = router.run_until_drained()
         ips = (router.items_emitted - at_loss) / \
             (time.perf_counter() - t0)
@@ -413,9 +428,10 @@ _DEGRADED_SCRIPT = textwrap.dedent("""
     # warm both mesh shapes so neither config pays first-trace costs
     for n in (DEVICES, SURVIVORS):
         fleet.resize(n)
-        w = FleetRouter(fleet, lanes_per_chip=LANES)
+        w = router_for(fleet)
         w.submit(ItemRequest(uid=-1,
-                             items=rng.uniform(0, 1, (2, DIMS[0]))))
+                             items=rng.uniform(0, 1, (2, DIMS[0])),
+                             key="app"))
         w.run_until_drained()
     rounds = {"healthy": [], "degraded": []}
     rel = 0.0
@@ -480,8 +496,8 @@ def _fleet_degraded() -> dict:
 # --------------------------------------------------------------------- #
 # Subprocess for the same simulated-device reason as _fleet_serve. Three
 # configurations share interleaved rounds on the same bursts:
-#   legacy        — compile_chip → shard_chip → FleetRouter (the PR-3/4
-#                   path, the committed fleet_serve baseline's shape)
+#   legacy        — compile_chip → shard_chip → MultiAppRouter wired by
+#                   hand (the fleet_serve baseline's shape)
 #   deploy_single — the SAME single app through repro.deploy (gate: the
 #                   declarative surface must not tax the single-app
 #                   case — this ratio is the no-regression check)
@@ -494,8 +510,9 @@ _DEPLOY_SCRIPT = textwrap.dedent("""
     import numpy as np
     from repro.chip import compile_chip
     from repro.core.crossbar_layer import MLPSpec, mlp_init
-    from repro.deploy import AppSpec, DeploymentSpec, deploy
-    from repro.fleet import FleetRouter, shard_chip
+    from repro.deploy import (AppSpec, DeploymentSpec, MultiAppRouter,
+                              deploy)
+    from repro.fleet import shard_chip
     from repro.serving.engine import ItemRequest
 
     DEEP = %r
@@ -522,9 +539,10 @@ _DEPLOY_SCRIPT = textwrap.dedent("""
     fleet = shard_chip(chip, 4)
 
     def legacy_round(burst):
-        router = FleetRouter(fleet, lanes_per_chip=LANES)
+        router = MultiAppRouter({"deep": fleet},
+                                lanes={"deep": LANES * fleet.n_chips})
         for i, items in enumerate(burst):
-            router.submit(ItemRequest(uid=i, items=items))
+            router.submit(ItemRequest(uid=i, items=items, key="deep"))
         t0 = time.perf_counter()
         router.run_until_drained()
         return router.items_emitted / (time.perf_counter() - t0)

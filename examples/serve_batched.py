@@ -5,12 +5,11 @@ Part 1 submits a burst of mixed-length LM requests, reports per-request
 latency, engine throughput and slot utilization. The decode step is the
 exact function the multi-pod dry-run lowers for the ``decode_*`` shapes.
 
-Part 2 is the paper's own serving story through the SAME scheduler,
-now behind ``repro.deploy``: one declarative spec compiles an MLP
-classifier onto simulated 1T1M crossbars, fans it over the visible
-devices and wires the continuous-batching router — what previously
-took four hand-assembled modules (``compile_chip`` → ``shard_chip`` →
-``FleetRouter`` → sources) is one ``deploy()`` call.
+Part 2 is the paper's own serving story on the same slot-scheduling
+contract: one declarative ``deploy()`` spec compiles an MLP classifier
+onto simulated 1T1M crossbars, places it over the visible devices as a
+fleet member, and wires the continuous-batching router
+(``deploy()`` → ``MultiAppRouter`` → member).
 
 Part 3 is what the deployment API adds: a SECOND tenant co-resident on
 the same fabric (the paper's multi-application story, Tables II–VI),
@@ -65,8 +64,7 @@ def main():
 def serve_crossbar_stream(n_requests: int = 12, slots: int = 4):
     """Deploy a classifier app once, then let the continuous-batching
     router serve a burst of item streams against the programmed state
-    (§III.D stream-many — one declarative call instead of the old
-    compile_chip → shard_chip → FleetRouter wiring, same semantics)."""
+    (§III.D stream-many — deploy() → MultiAppRouter → member)."""
     print("\n== compiled-chip classifier serving (repro.deploy) ==")
     spec = MLPSpec((64, 48, 10), activation="threshold",
                    out_activation="linear")

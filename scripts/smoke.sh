@@ -24,9 +24,6 @@ fi
 echo "== repro.chip --selftest =="
 python -m repro.chip --selftest
 
-echo "== repro.fleet --selftest =="
-python -m repro.fleet --selftest
-
 echo "== repro.fleet --distributed-selftest =="
 python -m repro.fleet --distributed-selftest
 

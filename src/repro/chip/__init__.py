@@ -4,7 +4,6 @@
                       items_per_second=...)
   y = chip.stream(x)          # the mapped dataflow, programmed once
   r = chip.report()           # Tables II–VI accounting in one record
-  eng = chip.serve(slots=4)   # slot-scheduled streaming engine
 
 See :mod:`repro.chip.compile` for the full design notes.
 Self-check:  PYTHONPATH=src python -m repro.chip --selftest
@@ -15,11 +14,9 @@ from repro.chip.compile import (ChipRateWarning, CompiledChip,
                                 reprogram_chip, stream_pipeline,
                                 validate_stream_rate)
 from repro.chip.report import ChipReport, chip_report
-from repro.chip.serving import ChipEngine, ChipRequest, ChipRequestState
 
 __all__ = ["ChipRateWarning", "CompiledChip", "StreamLayer",
            "compile_app", "compile_chip", "compile_count",
            "program_plan", "reprogram_chip", "stream_pipeline",
            "validate_stream_rate",
-           "ChipReport", "chip_report",
-           "ChipEngine", "ChipRequest", "ChipRequestState"]
+           "ChipReport", "chip_report"]

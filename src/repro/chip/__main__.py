@@ -2,8 +2,9 @@
 
 Compiles the paper's deep-app MLP (784→200→100→10) onto 1T1M cores,
 checks that the mapped stream matches the programmed dense oracle, that
-the report reproduces the published core count, and that the serving
-engine drains a small request burst correctly. Exit code 0 iff all
+the report reproduces the published core count, and that a one-chip
+``deploy()`` of the same network drains a small request burst through
+the router correctly. Exit code 0 iff all
 checks pass.
 """
 from __future__ import annotations
@@ -17,9 +18,10 @@ def selftest(verbose: bool = True) -> bool:
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.chip import ChipRequest, compile_chip
+    from repro.chip import compile_chip
     from repro.core.crossbar_layer import (MLPSpec, mlp_init, program_mlp,
                                            programmed_mlp_apply)
+    from repro.deploy import AppSpec, deploy
 
     ok = True
 
@@ -68,20 +70,21 @@ def selftest(verbose: bool = True) -> bool:
             overlaps += a1 > b0
     check("TDM schedule is conflict-free", overlaps == 0)
 
-    eng = chip.serve(slots=3)
+    d = deploy(AppSpec("deep", spec, params=params, system="memristor",
+                       items_per_second=1000.0, lanes_per_chip=3),
+               n_chips=1)
     rng = np.random.default_rng(2)
-    reqs = [ChipRequest(uid=i, items=rng.uniform(0, 1, (2 + i, 784)))
-            for i in range(5)]
-    for r in reqs:
-        eng.submit(r)
-    done = eng.run_until_drained()
-    check("serving engine drains all requests", len(done) == 5)
+    for i in range(5):
+        d.submit("deep", rng.uniform(0, 1, (2 + i, 784)))
+    done = d.run_until_drained()
+    check("router drains all requests", len(done) == 5)
     served_ok = all(
         np.allclose(st.result,
                     np.asarray(chip.stream(jnp.asarray(st.request.items))),
                     atol=1e-5)
         for st in done)
     check("served outputs match direct stream", served_ok)
+    d.close()
 
     if verbose:
         print(f"selftest: {'PASS' if ok else 'FAIL'}")

@@ -5,7 +5,7 @@ fixed-geometry cores (§IV.C), the resulting flows are statically routed
 over the 2-D mesh (§II.B), every mapped core is programmed once
 (§III.D), and the programmed chip then streams items at a fixed rate.
 ``compile_chip`` runs that whole pipeline and returns a
-:class:`CompiledChip` with three verbs:
+:class:`CompiledChip` with two verbs:
 
   chip.stream(x)   — execute the *mapped* dataflow functionally:
                      stage-ordered group evaluation, per-row-chunk
@@ -15,9 +15,9 @@ over the 2-D mesh (§II.B), every mapped core is programmed once
   chip.report()    — the unified area/power/throughput accounting the
                      Tables II–VI benchmarks previously assembled by
                      hand from mapping + routing + costmodel.
-  chip.serve(...)  — a slot-scheduled streaming engine over the chip
-                     (the same scheduler that drives transformer
-                     decode in ``repro.serving``).
+
+Serving goes through ``repro.deploy.deploy``, which places the chip on
+a ``"chip"`` mesh and routes requests to it through the one router.
 
 A CompiledChip is a jit-able pytree: the programmed conductance tiles,
 fold scales and biases are array leaves; geometry, placement, stage
@@ -72,20 +72,6 @@ _COMPILE_COUNT = 0
 def compile_count() -> int:
     """Monotone count of :func:`compile_chip` passes in this process."""
     return _COMPILE_COUNT
-
-
-# legacy serving-assembly entry points warn ONCE per process when used
-# directly (repro.deploy is the supported surface); keyed so tests can
-# reset and assert the exactly-once contract
-_DEPRECATION_WARNED: set = set()
-
-
-def warn_once_deprecated(key: str, message: str, *,
-                         stacklevel: int = 3) -> None:
-    if key in _DEPRECATION_WARNED:
-        return
-    _DEPRECATION_WARNED.add(key)
-    warnings.warn(message, DeprecationWarning, stacklevel=stacklevel)
 
 
 def _static():
@@ -386,7 +372,7 @@ class CompiledChip:
             raise ValueError(
                 "this chip was compiled from bare network shapes "
                 "(no weights), so it is analytic-only: report() works, "
-                "but stream() and serve() need programmed state. "
+                "but stream() needs programmed state. "
                 "Re-compile with compile_chip(spec, params=...) or "
                 "from a ProgrammedMLP.")
         lead = x.shape[:-1]
@@ -419,21 +405,6 @@ class CompiledChip:
         """Unified area/power/throughput accounting (Tables II–VI)."""
         from repro.chip.report import chip_report
         return chip_report(self)
-
-    def serve(self, *, slots: int = 4, **kw):
-        """A :class:`repro.serving.StreamingEngine` over this chip.
-
-        Deprecated as a user entry point: ``repro.deploy.deploy`` builds
-        the same engine (and the fleet/multi-app variants) from one
-        declarative spec. Semantics unchanged; warns once per process.
-        """
-        warn_once_deprecated(
-            "CompiledChip.serve",
-            "CompiledChip.serve() is deprecated as a direct entry "
-            "point; declare the app with repro.deploy.deploy(spec) and "
-            "use Deployment.submit/serve (same engine underneath)")
-        from repro.chip.serving import ChipEngine
-        return ChipEngine(self, slots=slots, **kw)
 
 
 def _chip_flatten(chip: CompiledChip):

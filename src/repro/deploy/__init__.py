@@ -3,8 +3,9 @@
 The paper's processor serves five sensor applications from one
 core/fabric design (Tables II–VI); this package is that multi-tenancy
 as an API. Declare WHAT runs — apps, systems, SLOs, lane budgets, one
-fabric topology — and ``deploy`` wires the whole serving stack the
-legacy path hand-assembled from four modules:
+fabric topology — and ``deploy`` compiles, places and routes every app
+(``compile_chip`` → ``ShardedChip`` → :class:`MultiAppRouter`, the one
+router):
 
   from repro.deploy import AppSpec, DeploymentSpec, deploy
 
@@ -22,8 +23,8 @@ legacy path hand-assembled from four modules:
 
 Self-check:  PYTHONPATH=src python -m repro.deploy --selftest
 (2 simulated devices, 2 co-resident apps; asserts the per-app stats
-roll-up is exact and the single-app stream matches the legacy
-compile→shard→route path at rel 0.0).
+roll-up is exact and the single-app stream matches
+``compile_chip`` → ``shard_chip`` at rel 0.0).
 
 Submodule imports are lazy (PEP 562) so ``python -m repro.deploy`` can
 pin ``--xla_force_host_platform_device_count`` before jax initializes,

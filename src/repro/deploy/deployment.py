@@ -5,10 +5,9 @@ name / MLPSpec+params / ProgrammedMLP), ``compile_chip`` it for the
 app's system at its SLO (one full map→route→program pass), then place
 the programmed plan ONCE on the one shared ``"chip"`` mesh
 (:class:`repro.fleet.ShardedChip` → ``replicate_to_mesh``). The
-returned :class:`Deployment` owns the multi-app router over those
-members and speaks every serving verb the legacy four-module wiring
-spoke — plus the two the multi-tenant story adds: per-app stats inside
-one fleet roll-up, and :meth:`Deployment.reprogram`, the live §III.D
+returned :class:`Deployment` owns the one router over those members
+and speaks every serving verb — among them the two the multi-tenant
+story adds: per-app stats inside one fleet roll-up, and :meth:`Deployment.reprogram`, the live §III.D
 weight swap that re-encodes ONE tenant's tiles with no recompile of
 anything (asserted via :func:`repro.chip.compile_count`).
 """
@@ -182,9 +181,9 @@ class Deployment:
             # each router schedules lanes for the chips it can address:
             # all of the member's chips single-process (its own submesh
             # on a heterogeneous fleet), only the LOCAL ones on a
-            # distributed mesh (same contract as DistributedFleetRouter
-            # — every rank runs lanes_per_chip × n_local_chips, so the
-            # fleet-wide budget still sums to lanes_per_chip × n_chips)
+            # distributed mesh (every rank runs lanes_per_chip ×
+            # n_local_chips, so the fleet-wide budget still sums to
+            # lanes_per_chip × n_chips)
             def lane_chips(m):
                 return m.n_local_chips if self.is_distributed \
                     else m.n_chips

@@ -1,11 +1,11 @@
 """Declarative deployment specs.
 
 The paper's processor is explicitly multi-application — Tables II–VI
-size the SAME core/fabric design for five sensor benchmarks — but until
-now serving one app meant hand-wiring four modules (``compile_chip`` →
-``shard_chip`` → ``FleetRouter`` → ``StreamSource``), and serving two
-meant doing it twice with nothing shared. A :class:`DeploymentSpec`
-says WHAT should run — which apps, on which system, at what rate, with
+size the SAME core/fabric design for five sensor benchmarks — and
+serving one app by hand would mean wiring ``compile_chip`` →
+``shard_chip`` → a router → ``StreamSource``, and serving two doing it
+twice with nothing shared. A :class:`DeploymentSpec` says WHAT should
+run — which apps, on which system, at what rate, with
 what lane/admission budget — and one fabric topology for all of them;
 :func:`repro.deploy.deploy` turns it into a live
 :class:`repro.deploy.Deployment`.
@@ -193,8 +193,8 @@ class DeploymentSpec:
 def single_app(network, params=None, *, name: str = "app",
                system: str = "memristor", n_chips: Optional[int] = None,
                **kw) -> DeploymentSpec:
-    """Shorthand for the one-tenant spec (the legacy
-    compile→shard→route path as one call)."""
+    """Shorthand for the one-tenant spec (compile, place and route
+    one network in one call)."""
     app_kw = {k: kw.pop(k) for k in
               ("items_per_second", "lanes_per_chip", "queue_limit",
                "seed", "weight_bits", "analytic", "noise", "geom",

@@ -1,24 +1,26 @@
-"""repro.fleet — multi-chip streaming fabric with continuous batching.
+"""repro.fleet — multi-chip streaming fabric.
 
-One :func:`repro.chip.compile_chip` result, served as a fleet:
+One :func:`repro.chip.compile_chip` result, placed as a fleet:
 
   fleet = shard_chip(chip, n_chips)        # one chip copy per device
   y = fleet.stream(x)                      # == chip.stream(x), rel 0.0
-  router = fleet.serve(lanes_per_chip=8)   # continuous-batching router
-  router.serve(StreamSource(SensorPipeline()))   # sensor-fed loop
-  print(fleet.report(router))              # hardware + served roll-up
+  y = fleet.stream_host(x)                 # numpy in, numpy out
+  print(fleet.report())                    # Tables II–VI, x n_chips
 
-Self-check:  PYTHONPATH=src python -m repro.fleet --selftest
-(runs itself on 2 simulated host devices). The multi-process fabric
-has its own: ``python -m repro.fleet --distributed-selftest``
-self-spawns N localhost ``jax.distributed`` ranks (gloo collectives),
-checks ``ShardedChip.stream_local`` == single-chip at rel 0.0, drives
-the lockstep ``DistributedFleetRouter`` off per-host
-``StreamSource.for_host`` feeders, and rolls router stats up across
-hosts. Fault tolerance has a third: ``python -m repro.fleet
---chaos-selftest`` kills a worker mid-serve and asserts the survivors
-degrade, absorb the dead rank's feed, and account for every admitted
-item exactly once (see :mod:`repro.fleet.ha`).
+Routed serving over fleet members is :func:`repro.deploy.deploy` →
+:class:`repro.deploy.MultiAppRouter`, fed by this package's bounded
+sensor sources (:class:`StreamSource`).
+
+The multi-process fabric has a self-check: ``python -m repro.fleet
+--distributed-selftest`` self-spawns N localhost ``jax.distributed``
+ranks (gloo collectives), checks ``ShardedChip.stream_local`` ==
+single-chip at rel 0.0, drives the lockstep
+``DistributedMultiAppRouter`` off per-host ``StreamSource.for_host``
+feeders, and rolls router stats up across hosts. Fault tolerance has a
+second: ``python -m repro.fleet --chaos-selftest`` kills a worker
+mid-serve and asserts the survivors degrade, absorb the dead rank's
+feed, and account for every admitted item exactly once (see
+:mod:`repro.fleet.ha`).
 
 Submodule imports are lazy (PEP 562) so importing ``repro.fleet`` —
 and in particular ``python -m repro.fleet`` booting this package —
@@ -33,11 +35,6 @@ _EXPORTS = {
     "ShardedChip": "repro.fleet.shard",
     "shard_chip": "repro.fleet.shard",
     "replicate_to_mesh": "repro.fleet.shard",
-    "FleetRouter": "repro.fleet.router",
-    "DistributedFleetRouter": "repro.fleet.router",
-    "FleetRequest": "repro.fleet.router",
-    "RouterStats": "repro.fleet.router",
-    "merge_stats": "repro.fleet.router",
     "BoundedQueue": "repro.fleet.source",
     "StreamSource": "repro.fleet.source",
     "FleetReport": "repro.fleet.report",

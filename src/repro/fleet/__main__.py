@@ -1,14 +1,4 @@
-"""CI smoke entry points.
-
-``PYTHONPATH=src python -m repro.fleet --selftest`` — single-process,
-simulated host devices (default 2; ``--devices N``): the flag is pinned
-into XLA_FLAGS before jax initializes, which is why ``repro.fleet``'s
-package imports are lazy. Checks that the sharded fleet stream is
-bit-identical to the single chip across ≥2 devices, that the
-continuous-batching router backfills ragged traffic and its outputs
-match the direct stream, that the sensor-stream frontend respects
-backpressure, that the fleet report composes the per-chip accounting,
-and that compile-time rate validation fires.
+"""CI smoke entry points for the multi-process fabric.
 
 ``PYTHONPATH=src python -m repro.fleet --distributed-selftest`` — the
 multi-PROCESS fabric: self-spawns N localhost worker processes
@@ -18,7 +8,8 @@ cross-process collectives. Every worker checks, against a locally
 recomputed single-chip reference (everything is a pure function of
 (seed, step), so no reference data crosses hosts): the distributed
 ``stream_local`` equals the single-chip stream at rel 0.0 on its row
-block; the lockstep :class:`DistributedFleetRouter` drains per-host
+block; the lockstep :class:`repro.deploy.DistributedMultiAppRouter`
+that ``deploy()`` builds on the distributed mesh drains per-host
 sensor feeders and its outputs match the direct stream; and the
 ``stats_global`` roll-up accounts for every host's requests, items and
 lanes. The parent supervises the workers (any death kills the rest)
@@ -40,136 +31,6 @@ import dataclasses
 import json
 import os
 import sys
-
-
-def selftest(verbose: bool = True) -> bool:
-    import warnings
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from repro.chip import ChipRateWarning, compile_chip
-    from repro.core.crossbar_layer import MLPSpec, mlp_init
-    from repro.data.pipeline import SensorPipeline
-    from repro.fleet import FleetRouter, StreamSource, shard_chip
-    from repro.serving.engine import ItemRequest
-
-    ok = True
-
-    def check(name, cond, detail=""):
-        nonlocal ok
-        ok = ok and bool(cond)
-        if verbose:
-            print(f"  [{'ok' if cond else 'FAIL'}] {name}"
-                  f"{'  (' + detail + ')' if detail else ''}")
-
-    n_dev = len(jax.devices())
-    check("simulated fleet devices", n_dev >= 2, f"{n_dev} devices")
-
-    # one compiled chip, fanned out over every device
-    dims = (784, 200, 100, 10)
-    spec = MLPSpec(dims, activation="threshold", out_activation="linear")
-    params = mlp_init(jax.random.PRNGKey(0), spec)
-    chip = compile_chip(spec, params=params, system="memristor")
-    fleet = shard_chip(chip)
-    check("fleet spans all devices", fleet.n_chips == n_dev)
-
-    x = jax.random.uniform(jax.random.PRNGKey(1), (4 * n_dev + 3, 784),
-                           minval=0, maxval=1)
-    y, ref = fleet.stream(x), chip.stream(x)
-    rel = float(jnp.max(jnp.abs(y - ref)) /
-                jnp.maximum(jnp.max(jnp.abs(ref)), 1e-12))
-    check("sharded stream == single chip (rel 0.0)", rel == 0.0,
-          f"rel {rel:.1e} over {fleet.n_chips} chips")
-
-    # continuous batching: ragged burst + mid-stream arrivals backfill
-    router = FleetRouter(fleet, lanes_per_chip=2)
-    rng = np.random.default_rng(2)
-    first = [ItemRequest(uid=i, items=rng.uniform(0, 1, (3 + 2 * i, 784)))
-             for i in range(3)]
-    for r in first:
-        router.submit(r)
-    for _ in range(2):
-        router.step()
-    late = [ItemRequest(uid=10 + i, items=rng.uniform(0, 1, (2, 784)))
-            for i in range(2 * n_dev)]
-    for r in late:
-        router.submit(r)
-    done = router.run_until_drained()
-    check("router drains ragged + late traffic",
-          len(done) == len(first) + len(late))
-    match = all(
-        np.allclose(st.result,
-                    np.asarray(chip.stream(jnp.asarray(st.request.items))),
-                    atol=1e-5) for st in done)
-    check("routed outputs match direct stream", match)
-    lat_ok = all(st.request.t_submit <= st.t_admit <= st.t_done
-                 for st in done)
-    check("latency accounting is monotonic", lat_ok)
-    stats = router.stats()
-    check("router stats roll up", stats.requests == len(done) and
-          stats.items == sum(st.result.shape[0] for st in done),
-          str(stats))
-
-    # sensor-stream frontend: windowed items, bounded-queue backpressure
-    pipe = SensorPipeline(window=28, stride=18, frames_per_step=1)
-    check("sensor windows are chip items", pipe.d_item == dims[0] and
-          pipe.items_per_step == 9)
-    src = StreamSource(pipe, n_requests=12, capacity=3)
-    made = src.pump()
-    check("backpressure caps production", made == 3 and
-          src.pump() == 0 and src.stalls == 2,
-          f"{made} staged of 12, capacity 3, {src.stalls} stalls")
-    router2 = FleetRouter(fleet, lanes_per_chip=2, queue_limit=4)
-    done2 = router2.serve(src)
-    all_items = jnp.concatenate(
-        [jnp.asarray(st.request.items) for st in
-         sorted(done2, key=lambda s: s.request.uid)])
-    want = chip.stream(all_items)
-    got = np.concatenate([st.result for st in
-                          sorted(done2, key=lambda s: s.request.uid)])
-    check("sensor-fed serve loop drains the stream",
-          len(done2) == 12 and src.exhausted)
-    check("sensor-fed outputs match direct stream",
-          np.allclose(got, np.asarray(want), atol=1e-5))
-
-    # fleet report composes the per-chip accounting linearly
-    rep = fleet.report(router2)
-    chip_rep = chip.report()
-    check("fleet report composes per-chip accounting",
-          rep.n_chips == n_dev and
-          abs(rep.power_mw - n_dev * chip_rep.power_mw) < 1e-9 and
-          abs(rep.area_mm2 - n_dev * chip_rep.area_mm2) < 1e-9 and
-          rep.served is not None and rep.served.items > 0)
-
-    # compile-time TDM rate validation (satellite: both sides)
-    feasible_ok = True
-    try:
-        with warnings.catch_warnings():
-            warnings.simplefilter("error", ChipRateWarning)
-            compile_chip(spec, params=params, items_per_second=1e4)
-    except Exception:
-        feasible_ok = False
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        probe = compile_chip(spec, params=params)
-        bad = 1e3 * probe.route.max_items_per_second
-        compile_chip(spec, params=params, items_per_second=bad)
-        warned = any(issubclass(w.category, ChipRateWarning)
-                     for w in caught)
-    raised = False
-    try:
-        compile_chip(spec, params=params, items_per_second=bad,
-                     strict_rate=True)
-    except ValueError:
-        raised = True
-    check("rate validation: feasible silent, infeasible warns/raises",
-          feasible_ok and warned and raised)
-
-    if verbose:
-        print(f"selftest: {'PASS' if ok else 'FAIL'}")
-    return ok
 
 
 def distributed_worker(verbose: bool = True) -> int:
@@ -201,10 +62,10 @@ def distributed_worker(verbose: bool = True) -> int:
     import numpy as np
     import jax.numpy as jnp
 
-    from repro.chip import compile_chip
     from repro.core.crossbar_layer import MLPSpec, mlp_init
     from repro.data.pipeline import SensorPipeline
-    from repro.fleet import StreamSource, shard_chip
+    from repro.deploy import AppSpec, deploy
+    from repro.fleet import StreamSource
     from repro.launch.mesh import make_distributed_fleet_mesh
 
     ok = True
@@ -232,8 +93,11 @@ def distributed_worker(verbose: bool = True) -> int:
     spec = MLPSpec(dims, activation="threshold",
                    out_activation="linear")
     params = mlp_init(jax.random.PRNGKey(0), spec)
-    chip = compile_chip(spec, params=params, system="memristor")
-    fleet = shard_chip(chip, mesh=mesh)
+    d = deploy(AppSpec("app", spec, params=params, system="memristor",
+                       lanes_per_chip=2, queue_limit=4), mesh=mesh)
+    chip = d.chip("app")
+    router = d.router
+    fleet = router.members["app"]
     check("fleet is distributed",
           fleet.is_distributed and fleet.n_chips == mesh.devices.size
           and fleet.n_local_chips == n_local)
@@ -263,8 +127,7 @@ def distributed_worker(verbose: bool = True) -> int:
     n_req = 6
     pipe = SensorPipeline(window=28, stride=18, frames_per_step=1)
     src = StreamSource.for_host(pipe, n_requests=n_req, capacity=3)
-    router = fleet.serve(lanes_per_chip=2, queue_limit=4)
-    done = router.serve(src)
+    done = d.serve(src)
     out["drained"] = len(done)
     check("per-host feeder drains through the lockstep router",
           len(done) == n_req and src.exhausted)
@@ -279,8 +142,9 @@ def distributed_worker(verbose: bool = True) -> int:
           all(st.request.t_submit <= st.t_admit <= st.t_first
               <= st.t_done for st in done))
 
-    local_stats = router.stats()
-    global_stats = router.stats_global()
+    local_stats = router.stats().fleet
+    global_all = router.stats_global()
+    global_stats = global_all.fleet
     out["stats_local"] = dataclasses.asdict(local_stats)
     out["stats_global"] = dataclasses.asdict(global_stats)
     items_per_host = n_req * pipe.items_per_step
@@ -290,9 +154,9 @@ def distributed_worker(verbose: bool = True) -> int:
           global_stats.lanes == 2 * fleet.n_chips and
           global_stats.steps >= local_stats.steps)
 
-    # fleet.report(router) must fold the CROSS-HOST served stats into
-    # the fleet-wide hardware envelope (collective, like every verb)
-    rep = fleet.report(router)
+    # the member's report folds the CROSS-HOST served row into the
+    # fleet-wide hardware envelope
+    rep = fleet.report(global_all.apps["app"])
     check("fleet report serves the global roll-up",
           rep.n_chips == fleet.n_chips and
           rep.served is not None and
@@ -597,12 +461,6 @@ def run_distributed_selftest(processes: int = 2,
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="python -m repro.fleet")
-    ap.add_argument("--selftest", action="store_true",
-                    help="run the shard→route→serve smoke check")
-    ap.add_argument("--devices", type=int, default=2,
-                    help="simulated host devices (default 2; ignored "
-                         "when jax is already initialized or XLA_FLAGS "
-                         "is set)")
     ap.add_argument("--distributed-selftest", action="store_true",
                     help="self-spawn a localhost jax.distributed fleet "
                          "and check the multi-process fabric")
@@ -638,16 +496,8 @@ def main(argv=None) -> int:
         return 0 if run_chaos_selftest(
             args.processes, kill_rank=args.kill_rank,
             kill_step=args.kill_step) else 1
-    if not args.selftest:
-        ap.print_help()
-        return 2
-    if "jax" not in sys.modules and "XLA_FLAGS" not in os.environ:
-        os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_"
-                                   f"count={args.devices}")
-        # the device-count flag only multiplies CPU devices; with an
-        # accelerator visible the simulated fleet would never exist
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    return 0 if selftest() else 1
+    ap.print_help()
+    return 2
 
 
 if __name__ == "__main__":

@@ -16,8 +16,7 @@ Failure model (measured, not assumed — see the chaos suite):
     milliseconds), after which local jax work keeps running. So a
     lockstep router CAN detect a peer death at the collective step and
     degrade in place: that is :class:`StepGuard` +
-    :func:`degrade_to_local` on ``DistributedFleetRouter`` /
-    ``DistributedMultiAppRouter``.
+    :func:`degrade_to_local` on ``DistributedMultiAppRouter``.
   * The *coordinator* (rank 0) of a ``jax.distributed`` job is a hard
     runtime-level single point of failure: its death makes the
     coordination service ABORT every surviving rank within seconds.
@@ -49,11 +48,10 @@ from typing import Dict, List, Optional, Sequence, Set
 
 import numpy as np
 
-from repro.fleet.router import (RouterStats, assemble_stats,
-                                latency_arrays)
 from repro.launch.simdev import board_path, read_board
 from repro.obs.core import current as _obs_current
 from repro.serving.engine import ItemRequest, ItemRequestState
+from repro.serving.stats import RouterStats, assemble_stats
 
 
 class MembershipChange(RuntimeError):
@@ -280,7 +278,7 @@ class FailureDetector:
 class StepGuard:
     """Heartbeat/step-deadline instrumentation around a router's
     (possibly collective) engine step — attach with
-    ``router.attach_ha(guard)`` (:class:`repro.fleet.TimedStepMixin`).
+    ``router.attach_ha(guard)`` (:class:`repro.deploy.MultiAppRouter`).
 
     Every guarded step: publish a beat BEFORE entering the collective
     (so peers watching this rank's deadline see progress), check the
@@ -416,7 +414,7 @@ def degrade_to_local(router, mesh=None) -> None:
     drop/dup/re-stream), and drop the collective control plane — the
     router keeps its counters and finished states, so accounting
     survives the failure. After this, the router behaves exactly like
-    its single-process parent class.
+    its single-process parent, :class:`repro.deploy.MultiAppRouter`.
 
     Default mesh: all local devices when the death was detected
     BEFORE a collective entered (step-deadline poll — clean runtime),
@@ -429,13 +427,9 @@ def degrade_to_local(router, mesh=None) -> None:
         mesh = local_fleet_mesh()
         if mesh.devices.size > 1 and not _mesh_dispatches(mesh):
             mesh = local_fleet_mesh(1)
-    members = getattr(router, "members", None)
-    if members is not None:                # multi-app router
-        for member in members.values():
-            member.resize(mesh=mesh)
-        router.resize_lanes({})            # rebuild blocks, evict+requeue
-    else:
-        router.resize(mesh=mesh)
+    for member in router.members.values():
+        member.resize(mesh=mesh)
+    router.resize_lanes({})                # rebuild blocks, evict+requeue
     router._local_stream = False
     router._spmd_lockstep = False
     router.step_when_idle = False
@@ -448,14 +442,15 @@ class HAFleetServer:
     """Drive one host's router + source as a member of a fault-
     tolerant fleet.
 
-    Works over both fleet shapes: a *federated* host (plain
-    ``FleetRouter``/``MultiAppRouter`` over a local mesh — survives
-    ANY peer's death, including rank 0's) and a *lockstep* host
-    (``Distributed*Router`` — a :class:`StepGuard` is attached so the
-    collective step itself detects peer death, and on
-    :class:`MembershipChange` the router is degraded to local in
-    place). Each loop tick: pump/admit from the bounded source,
-    publish a heartbeat (beat counter, engine step, source snapshot,
+    Works over both fleet shapes: a *federated* host (a
+    ``MultiAppRouter`` over a local mesh — survives ANY peer's death,
+    including rank 0's) and a *lockstep* host
+    (``DistributedMultiAppRouter`` — the collective step itself
+    detects peer death, and on :class:`MembershipChange` the router is
+    degraded to local in place). A :class:`StepGuard` is attached to
+    either. The source feeds one app: ``key`` names it, and its
+    requests are tagged with it on admission. Each loop tick:
+    pump/admit from the bounded source, publish a heartbeat (beat counter, engine step, source snapshot,
     completed/rejected uid journal, live counters + raw latencies),
     poll the failure detector, then step/skip/stop. A declared death
     triggers the deterministic takeover assignment
@@ -467,7 +462,7 @@ class HAFleetServer:
 
     ``stats_global()`` is the failover roll-up: assembled from the
     board by ANY surviving rank — no host-0 pinning, no collective —
-    through the same :func:`repro.fleet.router.assemble_stats` formula
+    through the same :func:`repro.serving.stats.assemble_stats` formula
     as the lockstep gather, so the two paths cannot drift."""
 
     def __init__(self, router, source, *, board: HeartbeatBoard,
@@ -490,9 +485,7 @@ class HAFleetServer:
         self._t_failure: Optional[float] = None
         self._t_done: Optional[float] = None
         self._items_at_failure = 0
-        if hasattr(router, "attach_ha"):
-            router.attach_ha(StepGuard(self.detector,
-                                       publish=self.publish))
+        router.attach_ha(StepGuard(self.detector, publish=self.publish))
 
     # ---------------- heartbeat / journal --------------------------- #
     def publish(self, status: str = "serving") -> None:
@@ -502,7 +495,7 @@ class HAFleetServer:
         needs. Atomic on the board."""
         self._beat_n += 1
         r = self.router
-        lat, wait = self._bounded_latencies()
+        lat, wait = r._latency_arrays()
         payload = {
             "rank": self.rank,
             "beat": self._beat_n,
@@ -525,15 +518,6 @@ class HAFleetServer:
             # histogram reservoirs cap the payload)
             payload["metrics"] = tel.metrics.snapshot()
         self.board.publish(self.rank, payload)
-
-    def _bounded_latencies(self):
-        """The router's bounded latency reservoirs when it keeps them
-        (every repro router does); raw extraction only for toy
-        routers in the property tests."""
-        arrays = getattr(self.router, "_latency_arrays", None)
-        if arrays is not None:
-            return arrays()
-        return latency_arrays(self.router.finished)
 
     # ---------------- failure handling ------------------------------ #
     def _journaled_or_held_uids(self) -> Set[int]:
@@ -571,7 +555,7 @@ class HAFleetServer:
         if newly and self._t_failure is None:
             self._t_failure = time.perf_counter()
             self._items_at_failure = self.router.items_emitted
-        if getattr(self.router, "_spmd_lockstep", False):
+        if self.router._spmd_lockstep:
             degrade_to_local(self.router)
         # deterministic assignment over ALL dead ranks (revisited each
         # failure, so a cascade — the absorber itself dying — reassigns
@@ -659,7 +643,7 @@ class HAFleetServer:
     def _decision(self) -> str:
         more_local = bool(self.router.queue or self.router.active
                           or not self.source.exhausted)
-        if getattr(self.router, "_spmd_lockstep", False):
+        if self.router._spmd_lockstep:
             return "step" if self.router._any_across_hosts(more_local) \
                 else "stop"
         if more_local:
@@ -706,7 +690,7 @@ class HAFleetServer:
         precisely the work it provably delivered). Exact when peers
         are done; a live peer's row is as fresh as its last beat."""
         r = self.router
-        lat, wait = self._bounded_latencies()
+        lat, wait = r._latency_arrays()
         rows = [[len(r.finished), r.items_emitted, r.steps,
                  r.rejected, r.slots]]
         walls = [r._wall_s()]
@@ -732,7 +716,7 @@ class HAFleetServer:
         the board (peers' last-published rows; for a dead peer, what
         it provably recorded) plus this rank's live registry — the
         no-collective twin of
-        :meth:`repro.fleet.DistributedFleetRouter.metrics_global`,
+        :meth:`repro.deploy.DistributedMultiAppRouter.metrics_global`,
         callable by any surviving rank."""
         from repro.obs import current, merge_snapshots
 

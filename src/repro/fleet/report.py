@@ -5,8 +5,9 @@ composes linearly from one :class:`repro.chip.ChipReport` (areas and
 powers add, per-item energy is unchanged, capacity multiplies). The
 *served* side does not — it is whatever the continuous-batching router
 actually achieved against real traffic — so the report carries both:
-the analytic envelope and, when a router is given, the measured
-:class:`RouterStats` with the achieved fraction of capacity.
+the analytic envelope and, when served stats are given, the measured
+:class:`repro.serving.stats.RouterStats` with the achieved fraction of
+capacity.
 """
 from __future__ import annotations
 
@@ -44,29 +45,22 @@ class FleetReport:
         return s
 
 
-def fleet_report(fleet, router=None) -> FleetReport:
+def fleet_report(fleet, served=None) -> FleetReport:
     """Assemble the roll-up for a :class:`repro.fleet.ShardedChip`,
-    optionally folding in a router's measured serving stats.
+    optionally folding in the measured serving stats of this member
+    (one app's :class:`RouterStats` row, e.g.
+    ``router.stats_app(app)``).
 
     The hardware envelope always spans the WHOLE fleet, so on a
-    distributed fleet the served side must too: a
-    :class:`DistributedFleetRouter` contributes its exact cross-host
-    ``stats_global()`` (a collective — every rank must assemble the
-    report together, the same lockstep rule as every other verb),
-    never its process-local counters, which would understate served
-    throughput by ~n_processes× against the fleet-wide capacity.
+    distributed fleet the served side must too: pass the row of the
+    router's exact cross-host ``stats_global()``, never the
+    process-local counters, which would understate served throughput
+    by ~n_processes× against the fleet-wide capacity.
     """
     chip_rep: ChipReport = fleet.chip.report()
     n = fleet.n_chips
     cap = chip_rep.capacity_items_per_second * \
         chip_rep.replication * n
-    if router is None:
-        served = None
-    elif getattr(fleet, "is_distributed", False) and \
-            hasattr(router, "stats_global"):
-        served = router.stats_global()
-    else:
-        served = router.stats()
     return FleetReport(
         n_chips=n,
         chip=chip_rep,

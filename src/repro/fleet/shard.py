@@ -43,8 +43,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.chip.compile import (CompiledChip, reprogram_chip,
-                                stream_pipeline, validate_stream_rate,
-                                warn_once_deprecated)
+                                stream_pipeline, validate_stream_rate)
 from repro.launch.mesh import make_fleet_mesh, mesh_spans_processes
 from repro.obs.core import current as _obs_current, span as _obs_span
 
@@ -113,9 +112,10 @@ class ShardedChip:
     to a multiple of the fleet size where it must be), runs the mapped
     dataflow on every device, and returns the outputs as one array —
     semantically identical to the single chip, ``n_chips``× the lanes.
-    ``serve``/``report`` mirror the CompiledChip verbs at fleet scale.
-    On a multi-process mesh use ``stream_local`` (and
-    ``serve(distributed=True)``); see the module docstring.
+    ``report`` mirrors the CompiledChip verb at fleet scale. On a
+    multi-process mesh use ``stream_local``; see the module docstring.
+    Routed serving is :func:`repro.deploy.deploy`'s router, which
+    calls ``stream_host`` (``stream_local`` when distributed).
 
     ``items_per_second`` is an optional FLEET-level target rate: the
     compile already validated the chip's own target against its routed
@@ -443,33 +443,12 @@ class ShardedChip:
         self.chip = reprogram_chip(self.chip, params, **kw)
         self._plan = replicate_to_mesh(self.chip.plan, self.mesh)
 
-    def serve(self, *, lanes_per_chip: int = 4, **kw):
-        """A continuous-batching router over this fleet: a
-        :class:`repro.fleet.FleetRouter`, or its SPMD lockstep variant
-        :class:`repro.fleet.DistributedFleetRouter` when the mesh spans
-        processes.
-
-        Deprecated as a user entry point: ``repro.deploy.deploy`` wires
-        the same router from one declarative spec (and adds multi-app
-        co-residency). Semantics unchanged; warns once per process.
-        """
-        warn_once_deprecated(
-            "ShardedChip.serve",
-            "ShardedChip.serve() is deprecated as a direct entry "
-            "point; declare the fleet with repro.deploy.deploy(spec) "
-            "and use Deployment.submit/serve (same router underneath)")
-        if self.is_distributed:
-            from repro.fleet.router import DistributedFleetRouter
-            return DistributedFleetRouter(self,
-                                          lanes_per_chip=lanes_per_chip,
-                                          **kw)
-        from repro.fleet.router import FleetRouter
-        return FleetRouter(self, lanes_per_chip=lanes_per_chip, **kw)
-
-    def report(self, router=None):
-        """Fleet-level roll-up of the per-chip Tables II–VI report."""
+    def report(self, served=None):
+        """Fleet-level roll-up of the per-chip Tables II–VI report,
+        with this member's served :class:`RouterStats` row folded in
+        when given."""
         from repro.fleet.report import fleet_report
-        return fleet_report(self, router)
+        return fleet_report(self, served)
 
 
 def shard_chip(chip: CompiledChip, n_chips: Optional[int] = None, *,

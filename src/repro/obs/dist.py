@@ -1,7 +1,7 @@
 """Cross-host aggregation of registry snapshots.
 
 The same wire discipline as the router's stat gathers
-(:mod:`repro.fleet.router`): tiny fixed-size collectives, every rank
+(:mod:`repro.deploy.router`): tiny fixed-size collectives, every rank
 calls together, any rank can report the merged result. A snapshot is
 variable-size JSON, so it rides as a two-phase gather — lengths first
 (the int32-halves trick of ``allgather_i64``), then zero-padded uint8

@@ -245,7 +245,7 @@ def merge_snapshots(snapshots: Iterable[Optional[dict]]) -> dict:
     """Fleet-wide roll-up of per-host registry snapshots: counters
     add, gauges take the max, histograms merge exactly on
     count/sum/min/max and concatenate (bounded) reservoir samples —
-    the same spirit as :func:`repro.fleet.router.assemble_stats`, for
+    the same spirit as :func:`repro.serving.stats.assemble_stats`, for
     the whole registry at once."""
     snaps = [s for s in snapshots if s]
     out: dict = {"counters": {}, "gauges": {}, "histograms": {}}

@@ -1,14 +1,18 @@
 """Continuous-batching serving (the inference-side driver).
 
-Two layers:
+Three layers:
 
   * :class:`StreamingEngine` / :class:`SlotScheduler` — the generic
     slot-scheduled streaming contract: a fixed pool of lanes, arriving
     requests admitted into free lanes without stalling others, ONE
     batched step for all active lanes per engine step, lanes retiring
-    the moment their request completes. The scheduler is payload-
-    agnostic: it drives the transformer decode step below and the
-    sensor-app chip stream (``repro.chip.serving.ChipEngine``) alike.
+    the moment their request completes.
+
+  * :class:`KeyedItemStreamScheduler` — item streams on that contract,
+    with the lane pool carved into one block per payload key and one
+    batched payload call per key per engine step. It is the engine
+    under :class:`repro.deploy.MultiAppRouter`, which keys it by app
+    and dispatches each key's batch to that app's member.
 
   * :class:`Engine` — the transformer instantiation: a vLLM-style
     continuous-batching decoder where every lane owns one slot of a
@@ -82,8 +86,8 @@ class SlotScheduler:
     ``step_when_idle`` makes ``step()`` run ``_step_active`` even with
     no active lane. A single-process engine never wants this (an idle
     step is wasted work), but an SPMD engine whose step is a collective
-    over a multi-process fleet (:class:`repro.fleet.DistributedFleetRouter`)
-    MUST enter the batched computation on every rank in lockstep — a
+    over a multi-process fleet
+    (:class:`repro.deploy.DistributedMultiAppRouter`) MUST enter the batched computation on every rank in lockstep — a
     locally idle rank that skipped it would deadlock the ranks that
     still have traffic.
     """
@@ -215,10 +219,9 @@ class ItemRequest:
     """A stream of items: (n_items, d_in) float array (a single
     (d_in,) item is promoted to a 1-item stream).
 
-    ``key`` names the payload stream this request belongs to on a
+    ``key`` names the payload stream this request belongs to on the
     payload-keyed scheduler (``repro.deploy`` tags it with the app
-    name); ``None`` is the single anonymous stream every legacy engine
-    schedules."""
+    name); ``None`` until a router or source stamps it."""
     uid: int
     items: np.ndarray
     t_submit: float = 0.0               # stamped by submit()
@@ -286,11 +289,9 @@ class KeyedItemStreamScheduler(SlotScheduler):
     every step runs each key's one compiled shape — no retracing as
     lanes retire.
 
-    With a single anonymous stream this is exactly the historic
-    single-payload scheduler (:class:`ItemStreamScheduler`, the facade
-    the chip engine and fleet router subclass); with one stream per
-    app it is the multi-tenant engine under
-    :class:`repro.deploy.MultiAppRouter`.
+    With one stream per app it is the multi-tenant engine under
+    :class:`repro.deploy.MultiAppRouter`; a single-app deployment is
+    the one-key case.
 
     ``step_when_idle`` additionally pins the *dispatch schedule*: every
     key's stream function runs on every step, idle or not, in stream
@@ -597,45 +598,6 @@ class KeyedItemStreamScheduler(SlotScheduler):
                 for slot, st in by_key.get(key, ()):
                     self._maybe_finish(st)
         return emitted
-
-
-class ItemStreamScheduler(KeyedItemStreamScheduler):
-    """The single-payload facade over the keyed scheduler: one
-    anonymous stream (key ``None``) spanning all ``slots`` lanes,
-    advanced through one ``_stream_batch`` call per engine step — the
-    historic contract the compiled chip
-    (:class:`repro.chip.ChipEngine`) and the sharded multi-chip fleet
-    (:class:`repro.fleet.FleetRouter`) plug into.
-    """
-
-    def __init__(self, d_in: int, *, slots: int = 4,
-                 queue_limit: Optional[int] = None,
-                 step_when_idle: bool = False,
-                 latency_reservoir: int = DEFAULT_RESERVOIR):
-        super().__init__({None: StreamSpec(d_in, slots, queue_limit)},
-                         step_when_idle=step_when_idle,
-                         latency_reservoir=latency_reservoir)
-        self.d_in = d_in
-        self.queue_limit = queue_limit
-        self._batch = self._batches[None]
-
-    def resize_streams(self, streams) -> List[ItemRequestState]:
-        evicted = super().resize_streams(streams)
-        self._batch = self._batches[None]       # refresh the alias
-        return evicted
-
-    def resize_slots(self, slots: int) -> List[ItemRequestState]:
-        """Live lane-count change for the anonymous stream (see
-        :meth:`KeyedItemStreamScheduler.resize_streams`)."""
-        return self.resize_streams(
-            {None: StreamSpec(self.d_in, slots, self.queue_limit)})
-
-    def _stream_batch(self, batch: np.ndarray) -> np.ndarray:
-        """(slots, d_in) → (slots, d_out), one batched payload step."""
-        raise NotImplementedError
-
-    def _stream_batch_key(self, key, batch: np.ndarray) -> np.ndarray:
-        return self._stream_batch(batch)
 
 
 # --------------------------------------------------------------------- #

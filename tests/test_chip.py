@@ -21,13 +21,13 @@ try:
 except ImportError:         # property tests skip; parametrized cases run
     HAVE_HYPOTHESIS = False
 
-from repro.chip import (ChipRateWarning, ChipRequest, CompiledChip,
-                        compile_chip)
+from repro.chip import ChipRateWarning, CompiledChip, compile_chip
 from repro.configs.paper_apps import APPS
 from repro.core.costmodel import specialized_cost
 from repro.core.crossbar_layer import (MLPSpec, mlp_init, program_mlp,
                                        programmed_mlp_apply)
 from repro.core.neural_core import CoreGeometry
+from repro.deploy import AppSpec, deploy
 
 
 def _rel(a, b):
@@ -313,19 +313,22 @@ if HAVE_HYPOTHESIS:
         _assert_schedule_conflict_free(chip.route)
 
 
-# -------------------- serving --------------------------------------- #
+# -------------------- serving: a one-chip deploy() ------------------- #
+def _one_chip(spec, params, lanes):
+    return deploy(AppSpec("app", spec, params=params,
+                          lanes_per_chip=lanes), n_chips=1)
+
+
 def test_serve_drains_and_matches_stream():
     spec = MLPSpec((30, 16, 4), activation="threshold",
                    out_activation="linear")
     params = mlp_init(jax.random.PRNGKey(11), spec)
-    chip = compile_chip(spec, params=params)
-    eng = chip.serve(slots=2)
+    d = _one_chip(spec, params, lanes=2)
+    chip = d.chip("app")
     rng = np.random.default_rng(12)
-    reqs = [ChipRequest(uid=i, items=rng.uniform(-1, 1, (1 + i, 30)))
-            for i in range(5)]
-    for r in reqs:
-        eng.submit(r)
-    done = eng.run_until_drained()
+    for i in range(5):
+        assert d.submit("app", rng.uniform(-1, 1, (1 + i, 30)))
+    done = d.run_until_drained()
     assert len(done) == 5
     assert sorted(st_.request.uid for st_ in done) == list(range(5))
     for st_ in done:
@@ -335,9 +338,10 @@ def test_serve_drains_and_matches_stream():
 
 
 def test_serve_rejects_analytic_chip():
-    chip = compile_chip((1, (8, 4)))
+    d = deploy(AppSpec("app", (1, (8, 4))), n_chips=1)
+    assert d.chip("app").plan is None and d.router is None
     with pytest.raises(ValueError, match="analytic-only"):
-        chip.serve()
+        d.submit("app", np.zeros((1, 8), np.float32))
 
 
 def test_serve_backfills_ragged_arrivals_without_starvation():
@@ -348,18 +352,14 @@ def test_serve_backfills_ragged_arrivals_without_starvation():
     spec = MLPSpec((30, 16, 4), activation="threshold",
                    out_activation="linear")
     params = mlp_init(jax.random.PRNGKey(23), spec)
-    chip = compile_chip(spec, params=params)
-    eng = chip.serve(slots=2)
+    d = _one_chip(spec, params, lanes=2)
+    chip, eng = d.chip("app"), d.router
     rng = np.random.default_rng(24)
-    long = ChipRequest(uid=0, items=rng.uniform(-1, 1, (20, 30)))
-    eng.submit(long)
+    long = eng.submit_app("app", rng.uniform(-1, 1, (20, 30)))
     eng.step()                          # long resident, one lane free
     # ragged arrivals while the long stream is mid-flight
-    shorts = [ChipRequest(uid=1 + i,
-                          items=rng.uniform(-1, 1, (2 + i % 3, 30)))
+    shorts = [eng.submit_app("app", rng.uniform(-1, 1, (2 + i % 3, 30)))
               for i in range(5)]
-    for r in shorts:
-        eng.submit(r)
     while len(eng.finished) < len(shorts):
         had_waiting = bool(eng.queue)
         emitted = eng.step()
@@ -371,7 +371,7 @@ def test_serve_backfills_ragged_arrivals_without_starvation():
     # all shorts retired while the long request is STILL streaming
     assert {st.request.uid for st in eng.finished} == \
         {r.uid for r in shorts}
-    assert 0 in {st.request.uid for st in eng.active.values()}
+    assert long.uid in {st.request.uid for st in eng.active.values()}
     done = eng.run_until_drained()
     assert len(done) == 6
     for st in done:

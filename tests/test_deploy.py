@@ -2,10 +2,10 @@
 
 Covers the PR-5 acceptance surface:
 
-  * shim equivalence — the legacy ``compile_chip`` → ``shard_chip`` →
-    ``FleetRouter`` wiring vs ``deploy()``'s single-app path at
-    rel 0.0, memristor AND digital, for both the direct stream and the
-    routed serving loop; the deprecated serve shims warn exactly once;
+  * single-app equivalence — ``compile_chip`` → ``shard_chip`` vs
+    ``deploy()``'s single-app path at rel 0.0, memristor AND digital,
+    for the direct stream, and the routed serving loop against the
+    member's own ``ShardedChip.stream_host`` of the same items;
   * the system-name alias matrix through the one normalize helper;
   * multi-app co-residency — per-app lanes/admission isolation on one
     shared mesh, routed outputs matching each tenant's own programmed
@@ -33,7 +33,7 @@ from repro.core.systems import (CANONICAL_SYSTEMS, normalize_system,
                                 system_mode)
 from repro.deploy import (AppSpec, DeploymentSpec, deploy,
                           deployment_report)
-from repro.fleet import FleetRouter, shard_chip
+from repro.fleet import shard_chip
 from repro.serving.engine import (ItemRequest, KeyedItemStreamScheduler,
                                   StreamSpec)
 
@@ -119,7 +119,7 @@ def test_appspec_normalizes_system_eagerly():
 
 
 # ------------------------------------------------------------------- #
-# shim equivalence (satellite): legacy wiring vs deploy()
+# single-app equivalence: hand wiring vs deploy()
 # ------------------------------------------------------------------- #
 @pytest.mark.parametrize("system", ["memristor", "digital"])
 def test_single_app_deploy_matches_legacy_path(system, spec_a,
@@ -132,21 +132,22 @@ def test_single_app_deploy_matches_legacy_path(system, spec_a,
                                       (9, DIMS_A[0])), np.float32)
     assert _rel(d.stream("app", x), fleet.stream(x)) == 0.0
 
-    # the routed serving loop too: same ragged burst through the legacy
-    # router and the deployment, outputs identical per request
+    # the routed serving loop too: a ragged burst through the
+    # deployment, each served result identical to the member's own
+    # host stream of the same items (the verb the router calls)
     rng = np.random.default_rng(5)
     bursts = [rng.uniform(0, 1, (2 + i, DIMS_A[0])).astype(np.float32)
               for i in range(4)]
-    legacy_router = FleetRouter(fleet, lanes_per_chip=4)
-    for i, items in enumerate(bursts):
-        legacy_router.submit(ItemRequest(uid=i, items=items.copy()))
+    for items in bursts:
         assert d.submit("app", items.copy())
-    legacy_done = legacy_router.run_until_drained()
     deploy_done = d.run_until_drained()
-    assert len(legacy_done) == len(deploy_done) == len(bursts)
-    for lst, dst in zip(sorted(legacy_done, key=lambda s: s.request.uid),
-                        sorted(deploy_done, key=lambda s: s.request.uid)):
-        assert _rel(dst.result, lst.result) == 0.0
+    assert len(deploy_done) == len(bursts)
+    member = d.router.members["app"]
+    for uid, dst in enumerate(sorted(deploy_done,
+                                     key=lambda s: s.request.uid)):
+        want = member.stream_host(bursts[uid])
+        assert _rel(dst.result, fleet.stream_host(bursts[uid])) == 0.0
+        assert _rel(dst.result, want) == 0.0
     st = d.stats()
     assert st.fleet.requests == len(bursts)
     assert st.apps["app"].items == st.fleet.items == \
@@ -189,24 +190,6 @@ def test_stream_input_may_be_overwritten_on_return(spec_a, params_a):
         assert np.array_equal(np.asarray(y), np.asarray(want))
         x[:] = x_was
     d.close()
-
-
-def test_serve_shims_warn_exactly_once(spec_a, params_a):
-    chip = compile_chip(spec_a, params=params_a)
-    fleet = shard_chip(chip)
-    chip_compile._DEPRECATION_WARNED.clear()
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        chip.serve(slots=2)
-        chip.serve(slots=2)
-        fleet.serve(lanes_per_chip=1)
-        fleet.serve(lanes_per_chip=1)
-    dep = [w for w in caught
-           if issubclass(w.category, DeprecationWarning)]
-    msgs = sorted(str(w.message)[:20] for w in dep)
-    assert len(dep) == 2, msgs           # once per shim, not per call
-    assert any("CompiledChip.serve" in str(w.message) for w in dep)
-    assert any("ShardedChip.serve" in str(w.message) for w in dep)
 
 
 # ------------------------------------------------------------------- #

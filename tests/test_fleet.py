@@ -1,9 +1,10 @@
-"""repro.fleet — multi-chip streaming fabric with continuous batching.
+"""repro.fleet — multi-chip streaming fabric, served through the router.
 
 In-process tests run on the parent's single CPU device (a 1-chip fleet
-must already be exact and serve correctly); the ≥2-device sharding
-equality runs in a subprocess so XLA's host-device count can be pinned
-before jax initializes (same pattern as test_elastic).
+must already be exact and serve correctly through a one-app
+:class:`repro.deploy.MultiAppRouter`); the ≥2-device sharding equality
+runs in a subprocess so XLA's host-device count can be pinned before
+jax initializes (same pattern as test_elastic).
 """
 import textwrap
 import warnings
@@ -16,10 +17,24 @@ import pytest
 from repro.chip import ChipRateWarning, compile_chip
 from repro.core.crossbar_layer import MLPSpec, mlp_init
 from repro.data.pipeline import SensorPipeline
-from repro.fleet import (BoundedQueue, DistributedFleetRouter,
-                         FleetRouter, StreamSource, merge_stats,
-                         shard_chip)
+from repro.deploy import DistributedMultiAppRouter, MultiAppRouter
+from repro.fleet import BoundedQueue, StreamSource, shard_chip
 from repro.serving.engine import ItemRequest
+from repro.serving.stats import merge_stats
+
+APP = "app"
+
+
+def one_app_router(fleet, *, lanes_per_chip, queue_limit=None, **kw):
+    """The router over one fleet member: ``lanes_per_chip × n_chips``
+    lanes, one admission queue."""
+    return MultiAppRouter({APP: fleet},
+                          lanes={APP: lanes_per_chip * fleet.n_chips},
+                          queue_limits={APP: queue_limit}, **kw)
+
+
+def request(uid, items):
+    return ItemRequest(uid=uid, items=items, key=APP)
 
 
 @pytest.fixture(scope="module")
@@ -50,9 +65,9 @@ def test_fleet_rejects_analytic_chip():
     analytic = compile_chip((1, (8, 4)))
     with pytest.raises(ValueError, match="analytic-only"):
         shard_chip(analytic, 1)
-    # the router's bare-CompiledChip path guards the same way
+    # the router guards a bare analytic chip the same way
     with pytest.raises(ValueError, match="analytic-only"):
-        FleetRouter(analytic)
+        MultiAppRouter({APP: analytic}, lanes={APP: 2})
 
 
 def test_fleet_requires_visible_devices(chip):
@@ -70,7 +85,8 @@ def test_sharded_stream_matches_single_chip_across_devices(
         import jax, jax.numpy as jnp
         from repro.chip import compile_chip
         from repro.core.crossbar_layer import MLPSpec, mlp_init
-        from repro.fleet import FleetRouter, shard_chip
+        from repro.deploy import MultiAppRouter
+        from repro.fleet import shard_chip
         from repro.serving.engine import ItemRequest
         import numpy as np
 
@@ -87,12 +103,14 @@ def test_sharded_stream_matches_single_chip_across_devices(
         local_same = bool(np.array_equal(fleet.stream_local(np.asarray(x)),
                                          fleet.stream_host(np.asarray(x))))
         # routed serving must match the direct stream too
-        router = FleetRouter(fleet, lanes_per_chip=2)
+        router = MultiAppRouter({"app": fleet},
+                                lanes={"app": 2 * fleet.n_chips})
         rng = np.random.default_rng(0)
         for i in range(5):
             router.submit(ItemRequest(uid=i,
                                       items=rng.uniform(0, 1,
-                                                        (2 + i, 784))))
+                                                        (2 + i, 784)),
+                                      key="app"))
         done = router.run_until_drained()
         served_ok = all(
             np.allclose(st.result,
@@ -113,9 +131,9 @@ def test_sharded_stream_matches_single_chip_across_devices(
 # -------------------- router ------------------------------------------ #
 def test_router_drains_and_matches_stream(chip):
     fleet = shard_chip(chip, 1)
-    router = FleetRouter(fleet, lanes_per_chip=3)
+    router = one_app_router(fleet, lanes_per_chip=3)
     rng = np.random.default_rng(1)
-    reqs = [ItemRequest(uid=i, items=rng.uniform(-1, 1, (1 + i, 64)))
+    reqs = [request(i, rng.uniform(-1, 1, (1 + i, 64)))
             for i in range(6)]
     for r in reqs:
         assert router.submit(r)
@@ -129,32 +147,28 @@ def test_router_drains_and_matches_stream(chip):
 
 def test_router_admission_control(chip):
     fleet = shard_chip(chip, 1)
-    router = FleetRouter(fleet, lanes_per_chip=2, queue_limit=2)
+    router = one_app_router(fleet, lanes_per_chip=2, queue_limit=2)
     rng = np.random.default_rng(2)
-    results = [router.submit(ItemRequest(uid=i,
-                                         items=rng.uniform(0, 1,
-                                                           (2, 64))))
+    results = [router.submit(request(i, rng.uniform(0, 1, (2, 64))))
                for i in range(5)]
     assert results == [True, True, False, False, False]
     assert router.rejected == 3
     router.step()                     # admits 2 into lanes, queue frees
-    assert router.submit(ItemRequest(uid=9,
-                                     items=rng.uniform(0, 1, (2, 64))))
+    assert router.submit(request(9, rng.uniform(0, 1, (2, 64))))
 
 
 def test_router_latency_accounting(chip):
     fleet = shard_chip(chip, 1)
-    router = FleetRouter(fleet, lanes_per_chip=2)
+    router = one_app_router(fleet, lanes_per_chip=2)
     rng = np.random.default_rng(3)
     for i in range(4):
-        router.submit(ItemRequest(uid=i,
-                                  items=rng.uniform(0, 1, (3, 64))))
+        router.submit(request(i, rng.uniform(0, 1, (3, 64))))
     done = router.run_until_drained()
     for st in done:
         assert st.request.t_submit <= st.t_admit <= st.t_first \
             <= st.t_done
         assert st.done_step >= st.admit_step
-    stats = router.stats()
+    stats = router.stats_app(APP)
     assert stats.requests == 4 and stats.items == 12
     assert stats.items_per_second > 0
     assert 0 < stats.occupancy <= 1
@@ -213,10 +227,10 @@ def test_router_serve_rejects_zero_capacity_queue(chip):
     instead of spinning (max_steps bounds iterations regardless)."""
     pipe = SensorPipeline(window=8, stride=8, height=16, width=16)
     src = StreamSource(pipe, n_requests=3, capacity=2)
-    router = FleetRouter(shard_chip(chip, 1), lanes_per_chip=2,
-                         queue_limit=0)
+    router = one_app_router(shard_chip(chip, 1), lanes_per_chip=2,
+                            queue_limit=0)
     with pytest.raises(ValueError, match="queue_limit"):
-        router.serve(src, max_steps=5)
+        router.serve({APP: src}, max_steps=5)
 
 
 def test_stream_host_matches_stream(chip):
@@ -234,8 +248,8 @@ def test_router_serve_loop_end_to_end(chip):
     pipe = SensorPipeline(window=8, stride=8, height=16, width=16)
     src = StreamSource(pipe, n_requests=7, capacity=2)
     fleet = shard_chip(chip, 1)
-    router = FleetRouter(fleet, lanes_per_chip=2, queue_limit=3)
-    done = router.serve(src)
+    router = one_app_router(fleet, lanes_per_chip=2, queue_limit=3)
+    done = router.serve({APP: src})
     assert len(done) == 7 and src.exhausted
     for st in done:
         want = np.asarray(chip.stream(jnp.asarray(st.request.items)))
@@ -258,8 +272,9 @@ def test_stream_local_matches_stream_host(chip):
 
 
 def test_distributed_router_requires_distributed_fleet(chip):
-    with pytest.raises(ValueError, match="spans processes"):
-        DistributedFleetRouter(shard_chip(chip, 1))
+    with pytest.raises(ValueError, match="span processes"):
+        DistributedMultiAppRouter({APP: shard_chip(chip, 1)},
+                                  lanes={APP: 2})
 
 
 def test_stream_source_for_host_partitions_the_stream(chip):
@@ -293,12 +308,12 @@ def test_router_step_when_idle_keeps_stepping(chip):
     step (zero rows) so a multi-process collective can't deadlock on a
     locally drained rank."""
     fleet = shard_chip(chip, 1)
-    router = FleetRouter(fleet, lanes_per_chip=2, step_when_idle=True)
+    router = one_app_router(fleet, lanes_per_chip=2, step_when_idle=True)
     assert router.step() == 0 and router.steps == 1   # idle, but ran
-    router.submit(ItemRequest(
-        uid=0, items=np.random.default_rng(0).uniform(0, 1, (2, 64))))
+    router.submit(request(
+        0, np.random.default_rng(0).uniform(0, 1, (2, 64))))
     router.run_until_drained()
-    idle = FleetRouter(fleet, lanes_per_chip=2)       # default: skip
+    idle = one_app_router(fleet, lanes_per_chip=2)    # default: skip
     assert idle.step() == 0 and idle.steps == 0
 
 
@@ -307,12 +322,11 @@ def test_merge_stats_rolls_up_counters(chip):
     rng = np.random.default_rng(7)
 
     def run_router(n_req):
-        router = FleetRouter(fleet, lanes_per_chip=2)
+        router = one_app_router(fleet, lanes_per_chip=2)
         for i in range(n_req):
-            router.submit(ItemRequest(uid=i,
-                                      items=rng.uniform(0, 1, (2, 64))))
+            router.submit(request(i, rng.uniform(0, 1, (2, 64))))
         router.run_until_drained()
-        return router.stats()
+        return router.stats().fleet
 
     a, b = run_router(2), run_router(3)
     m = merge_stats([a, b])
@@ -352,13 +366,12 @@ def test_fleet_level_rate_validation(chip):
 # -------------------- fleet report ------------------------------------ #
 def test_fleet_report_composes_chip_report(chip):
     fleet = shard_chip(chip, 1)
-    router = FleetRouter(fleet, lanes_per_chip=2)
+    router = one_app_router(fleet, lanes_per_chip=2)
     rng = np.random.default_rng(4)
     for i in range(3):
-        router.submit(ItemRequest(uid=i,
-                                  items=rng.uniform(0, 1, (2, 64))))
+        router.submit(request(i, rng.uniform(0, 1, (2, 64))))
     router.run_until_drained()
-    rep = fleet.report(router)
+    rep = fleet.report(router.stats_app(APP))
     chip_rep = chip.report()
     assert rep.n_chips == 1
     assert rep.cores == chip_rep.cores

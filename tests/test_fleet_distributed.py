@@ -12,7 +12,7 @@ pins is exactly what ``--distributed-selftest`` ships:
   * ``ShardedChip.stream_local`` == single-chip stream at rel 0.0 on
     every rank's row block (each rank recomputes its (seed, step)-pure
     reference locally — no reference data crosses hosts);
-  * ``DistributedFleetRouter.stats_global`` accounts for every host's
+  * ``DistributedMultiAppRouter.stats_global`` accounts for every host's
     requests/items/lanes, agrees across ranks, and matches the pure
     ``merge_stats`` roll-up of the per-host stats;
   * a dead worker takes the fleet down promptly (supervised shutdown)
@@ -24,8 +24,8 @@ import time
 
 import pytest
 
-from repro.fleet import RouterStats, merge_stats
 from repro.launch import simdev
+from repro.serving.stats import RouterStats, merge_stats
 
 pytestmark = pytest.mark.distributed
 

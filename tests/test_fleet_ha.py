@@ -13,6 +13,8 @@ Tier-1 layers (fast, no subprocess unless noted):
     dead host's exact feed, minus the journaled uids;
   * source/scheduler re-admission: front-of-queue ``requeue`` that
     bypasses admission limits without double-charging backpressure;
+  * :func:`degrade_to_local` on a router mid-serve: in-flight lanes
+    evicted and requeued at the front, accounting carried over;
   * TWO interleaved :class:`HAFleetServer`s in ONE process over toy
     fleets — one is starved of ticks to simulate its death
     deterministically; the survivor must absorb its feed with EXACT
@@ -39,15 +41,17 @@ import time
 import numpy as np
 import pytest
 
+from repro.deploy import MultiAppRouter
 from repro.fleet.ha import (FailureDetector, HAConfig, HAFleetServer,
                             HeartbeatBoard, MembershipChange, StepGuard,
-                            replay_requests, source_snapshot)
-from repro.fleet.router import FleetRouter
+                            degrade_to_local, replay_requests,
+                            source_snapshot)
 from repro.fleet.source import BoundedQueue, StreamSource
 from repro.launch import simdev
-from repro.serving.engine import ItemRequest
+from repro.serving.engine import ItemRequest, ItemRequestState
 
 D_IN = 3
+APP = "app"
 
 
 class ToyFleet:
@@ -60,6 +64,17 @@ class ToyFleet:
 
     def stream(self, x, use_kernel=False):
         return np.asarray(x, np.float32) * 2.0 + 1.0
+
+    stream_host = stream
+
+    def resize(self, n_chips=None, *, mesh=None):
+        self.n_chips = n_chips if mesh is None else mesh.devices.size
+
+
+def toy_router(*, lanes=2, queue_limit=None, **kw):
+    """The one-app router over a 1-chip toy fleet."""
+    return MultiAppRouter({APP: ToyFleet(1)}, lanes={APP: lanes},
+                          queue_limits={APP: queue_limit}, **kw)
 
 
 class ToyPipe:
@@ -309,10 +324,11 @@ def test_source_requeue_preserves_budget_and_order(tmp_path):
 
 
 def test_router_requeue_bypasses_admission_limit(tmp_path):
-    router = FleetRouter(ToyFleet(1), lanes_per_chip=2, queue_limit=1)
+    router = toy_router(queue_limit=1)
     rng = np.random.default_rng(0)
     mk = lambda uid, n: ItemRequest(
-        uid=uid, items=rng.uniform(-1, 1, (n, D_IN)).astype(np.float32))
+        uid=uid, items=rng.uniform(-1, 1, (n, D_IN)).astype(np.float32),
+        key=APP)
     assert router.submit(mk(0, 3))
     assert not router.submit(mk(1, 2))       # admission full
     router.requeue([mk(2, 2), mk(3, 1)])     # no-drop re-admission
@@ -322,6 +338,56 @@ def test_router_requeue_bypasses_admission_limit(tmp_path):
         router.step()
     assert sorted(st.request.uid for st in router.finished) == [0, 2, 3]
     assert router.submit(mk(5, 1))           # drained: admission back
+
+
+def test_degrade_to_local_requeues_in_flight_lanes(tmp_path):
+    """The degraded-mode switch on a router mid-serve: the member is
+    re-placed on the local mesh, in-flight lanes are evicted and
+    requeued at the FRONT with their progress, the counters carry
+    over, and every admitted request still finishes exactly once with
+    every item streamed once (y = 2x + 1)."""
+    router = toy_router(queue_limit=2, step_when_idle=True)
+    rng = np.random.default_rng(3)
+    lengths = {0: 1, 1: 6, 2: 5, 3: 4, 4: 2}
+    reqs = {uid: ItemRequest(
+        uid=uid, items=rng.uniform(-1, 1, (n, D_IN)).astype(np.float32),
+        key=APP) for uid, n in lengths.items()}
+    assert router.submit(reqs[0]) and router.submit(reqs[1])
+    assert not router.submit(reqs[4])        # admission full: rejected
+    router.step()                            # 0 done, 1 streaming
+    assert router.submit(reqs[2]) and router.submit(reqs[3])
+    router.step()
+    router.step()                            # 2 joined the free lane
+    before = (list(router.finished), router.items_emitted,
+              router.rejected, router.steps)
+    in_flight = [router.active[s] for s in sorted(router.active)]
+    progress = [(st.request.uid, st.pos) for st in in_flight]
+    assert sorted(uid for uid, _ in progress) == [1, 2]
+    assert all(pos > 0 for _, pos in progress)
+    assert [getattr(e, "uid", None) for e in router.queue] == [3]
+
+    degrade_to_local(router)
+
+    assert not router.active and router.slots == 2
+    assert list(router.queue)[:2] == in_flight   # front, slot order
+    assert all(isinstance(e, ItemRequestState)
+               for e in list(router.queue)[:2])
+    assert [(st.request.uid, st.pos) for st in in_flight] == progress
+    assert (list(router.finished), router.items_emitted,
+            router.rejected, router.steps) == before
+    assert not router._spmd_lockstep and not router._local_stream
+    assert not router.step_when_idle
+    assert router.members[APP].n_chips == 1
+
+    router.run_until_drained()
+    done = [st.request.uid for st in router.finished]
+    assert sorted(done) == [0, 1, 2, 3]          # 4 was rejected
+    assert len(set(done)) == len(done)
+    assert router.items_emitted == sum(lengths[u] for u in done)
+    for st in router.finished:
+        np.testing.assert_allclose(
+            st.result, np.asarray(st.request.items) * 2.0 + 1.0,
+            rtol=1e-6)
 
 
 # ---------------------------------------------------------------------- #
@@ -335,12 +401,13 @@ def _make_server(board, rank, *, takeover="replay",
                  pipeline=None):
     cfg = HAConfig(timeout_s=0.05, retries=2, backoff_s=0.01,
                    idle_sleep_s=0.001, takeover=takeover)
-    router = FleetRouter(ToyFleet(1), lanes_per_chip=2)
+    router = toy_router()
     pipe = pipeline or ToyPipe()
     src = StreamSource.for_host(pipe, host=rank, hosts=2,
                                 n_requests=N_REQ, capacity=3)
     return HAFleetServer(router, src, board=board, rank=rank,
-                         ranks=(0, 1), pipeline=pipe, config=cfg)
+                         ranks=(0, 1), pipeline=pipe, key=APP,
+                         config=cfg)
 
 
 def _run_death_scenario(tmp_path, *, takeover):
@@ -615,10 +682,10 @@ _LOCKSTEP_HA_WORKER = textwrap.dedent("""
         coordinator_address=f"127.0.0.1:{port}",
         num_processes=nprocs, process_id=rank)
     import numpy as np
-    from repro.chip import compile_chip
     from repro.core.crossbar_layer import MLPSpec, mlp_init
     from repro.data.pipeline import SensorPipeline
-    from repro.fleet import StreamSource, shard_chip
+    from repro.deploy import AppSpec, deploy
+    from repro.fleet import StreamSource
     from repro.fleet.ha import HAConfig, HAFleetServer, HeartbeatBoard
     from repro.launch.mesh import make_distributed_fleet_mesh
 
@@ -626,15 +693,16 @@ _LOCKSTEP_HA_WORKER = textwrap.dedent("""
     spec = MLPSpec(dims, activation="threshold",
                    out_activation="linear")
     params = mlp_init(jax.random.PRNGKey(0), spec)
-    chip = compile_chip(spec, params=params, system="memristor")
-    fleet = shard_chip(chip, mesh=make_distributed_fleet_mesh())
-    router = fleet.serve(lanes_per_chip=2, queue_limit=4)
-    assert type(router).__name__ == "DistributedFleetRouter"
+    d = deploy(AppSpec("app", spec, params=params, system="memristor",
+                       lanes_per_chip=2, queue_limit=4),
+               mesh=make_distributed_fleet_mesh())
+    router = d.router
+    assert type(router).__name__ == "DistributedMultiAppRouter"
     pipe = SensorPipeline(window=28, stride=18, frames_per_step=1)
     src = StreamSource.for_host(pipe, n_requests=6, capacity=3)
     server = HAFleetServer(
         router, src, board=HeartbeatBoard(ha_dir), rank=rank,
-        ranks=range(nprocs), pipeline=pipe,
+        ranks=range(nprocs), pipeline=pipe, key="app",
         config=HAConfig(timeout_s=1.0, retries=3, backoff_s=0.1,
                         step_sleep_s=0.05))
     done = server.serve()

@@ -1,4 +1,4 @@
-"""Scheduler/router invariants under random ragged traffic.
+"""Router invariants under random ragged traffic.
 
 The contracts the fleet's correctness rests on, stated once as checker
 functions and hammered from two directions:
@@ -14,28 +14,32 @@ Invariants (ISSUE 4): no item dropped or duplicated; backfill never
 exceeds ``lanes_per_chip × n_chips``; bounded-queue admission returns
 False exactly when the queue is full; per-request latencies monotone
 (submit ≤ admit ≤ first ≤ done, admit_step ≤ done_step). The payload
-is a row-pure toy fleet (``y = 2x + 1``) — the router is payload-
-agnostic, and a per-example chip compile would turn thousands of
-schedules into minutes.
+is a row-pure toy fleet (``y = 2x + 1``), served as the one app of a
+:class:`repro.deploy.MultiAppRouter` — the router is payload-agnostic,
+and a per-example chip compile would turn thousands of schedules into
+minutes.
 
 The elastic variants re-run the same invariants across MID-SERVE
-membership changes: ``router.resize`` between waves (grow and shrink,
-with lanes full of half-streamed requests) must preserve no-drop/
-no-dup, keep every step's emission within the CURRENT lane budget, and
-never re-stream an already-emitted item.
+membership changes: the toy fleet's chip count changes between waves
+and ``router.resize_lanes`` rebuilds the app's lane block (grow and
+shrink, with lanes full of half-streamed requests); that must preserve
+no-drop/no-dup, keep every step's emission within the CURRENT lane
+budget, and never re-stream an already-emitted item.
 """
 import dataclasses
 
 import numpy as np
 import pytest
 
-from repro.fleet import FleetRouter, merge_stats
+from repro.deploy import MultiAppRouter
 from repro.serving.engine import ItemRequest
+from repro.serving.stats import merge_stats
 
 # ---------------------------------------------------------------------- #
 # toy payload + schedule driver
 # ---------------------------------------------------------------------- #
 D_IN = 3
+APP = "toy"
 
 
 class ToyFleet:
@@ -49,6 +53,23 @@ class ToyFleet:
     def stream(self, x, use_kernel=False):
         return np.asarray(x, np.float32) * 2.0 + 1.0
 
+    stream_host = stream
+
+
+def make_router(fleet, *, lanes_per_chip, queue_limit=None):
+    """The one-app router over ``fleet``: ``lanes_per_chip × n_chips``
+    lanes, ``queue_limit`` bounding its admission queue."""
+    return MultiAppRouter({APP: fleet},
+                          lanes={APP: lanes_per_chip * fleet.n_chips},
+                          queue_limits={APP: queue_limit})
+
+
+def resize(router, n_chips, *, lanes_per_chip):
+    """The membership change: the fleet now has ``n_chips`` chips, and
+    the app's lane block is rebuilt to match."""
+    router.members[APP].n_chips = n_chips
+    router.resize_lanes({APP: lanes_per_chip * n_chips})
+
 
 @dataclasses.dataclass
 class DriveLog:
@@ -60,15 +81,15 @@ class DriveLog:
 
 def drive(schedule, *, lanes_per_chip=2, n_chips=2,
           queue_limit=None) -> tuple:
-    """Run one ragged schedule through a FleetRouter.
+    """Run one ragged schedule through the one-app router.
 
     ``schedule`` is a list of waves; each wave is
     ``(lengths, steps_after)``: submit one request per length, then run
     that many engine steps — arrivals land mid-flight, which is what
     exercises backfill. Returns (router, DriveLog) after a full drain.
     """
-    fleet = ToyFleet(n_chips)
-    router = FleetRouter(fleet, lanes_per_chip=lanes_per_chip,
+    router = make_router(ToyFleet(n_chips),
+                         lanes_per_chip=lanes_per_chip,
                          queue_limit=queue_limit)
     rng = np.random.default_rng(0)
     log = DriveLog([], [], [], [])
@@ -78,7 +99,8 @@ def drive(schedule, *, lanes_per_chip=2, n_chips=2,
             items = rng.uniform(-1, 1, (n, D_IN)).astype(np.float32)
             expected = queue_limit is None or \
                 len(router.queue) < queue_limit
-            got = router.submit(ItemRequest(uid=uid, items=items))
+            got = router.submit(ItemRequest(uid=uid, items=items,
+                                            key=APP))
             log.submit_expect.append((got, expected))
             (log.accepted if got else log.rejected).append(uid)
             uid += 1
@@ -108,14 +130,14 @@ def check_no_drop_no_dup(router, log):
     assert router.items_emitted == total_items == sum(log.step_emitted)
 
 
-def check_backfill_bound(router, log):
+def check_backfill_bound(router, log, lanes_per_chip, n_chips):
     """No engine step ever streams more than lanes_per_chip × n_chips
     items — lanes are the only concurrency there is."""
-    lanes = router.lanes_per_chip * router.n_chips
+    lanes = lanes_per_chip * n_chips
     assert router.slots == lanes
     assert all(0 <= e <= lanes for e in log.step_emitted)
     if router.steps:
-        assert 0 < router.stats().occupancy <= 1.0
+        assert 0 < router.stats().fleet.occupancy <= 1.0
 
 
 def check_admission_exact(router, log, queue_limit):
@@ -141,7 +163,7 @@ def check_all(schedule, *, lanes_per_chip, n_chips, queue_limit):
     router, log = drive(schedule, lanes_per_chip=lanes_per_chip,
                         n_chips=n_chips, queue_limit=queue_limit)
     check_no_drop_no_dup(router, log)
-    check_backfill_bound(router, log)
+    check_backfill_bound(router, log, lanes_per_chip, n_chips)
     check_admission_exact(router, log, queue_limit)
     check_latency_monotone(router)
     return router
@@ -155,8 +177,8 @@ def drive_with_resize(schedule, chip_counts, *, lanes_per_chip=2,
     evicted and front-requeued by the scheduler rebuild. Returns
     (router, log, lane_caps) where ``lane_caps[k]`` is the lane budget
     in force at engine step ``k``."""
-    fleet = ToyFleet(chip_counts[0])
-    router = FleetRouter(fleet, lanes_per_chip=lanes_per_chip,
+    router = make_router(ToyFleet(chip_counts[0]),
+                         lanes_per_chip=lanes_per_chip,
                          queue_limit=queue_limit)
     rng = np.random.default_rng(0)
     log = DriveLog([], [], [], [])
@@ -167,14 +189,15 @@ def drive_with_resize(schedule, chip_counts, *, lanes_per_chip=2,
             items = rng.uniform(-1, 1, (n, D_IN)).astype(np.float32)
             expected = queue_limit is None or \
                 len(router.queue) < queue_limit
-            got = router.submit(ItemRequest(uid=uid, items=items))
+            got = router.submit(ItemRequest(uid=uid, items=items,
+                                            key=APP))
             log.submit_expect.append((got, expected))
             (log.accepted if got else log.rejected).append(uid)
             uid += 1
         for _ in range(steps_after):
             lane_caps.append(router.slots)
             log.step_emitted.append(router.step())
-        router.resize(n_next)           # the membership change
+        resize(router, n_next, lanes_per_chip=lanes_per_chip)
     while router.queue or router.active:
         lane_caps.append(router.slots)
         log.step_emitted.append(router.step())
@@ -187,7 +210,7 @@ def check_backfill_bound_elastic(router, log, lane_caps,
     capped by the lane budget IN FORCE at that step, and the final
     slot count matches the last resize."""
     assert router.slots == lanes_per_chip * chip_counts[-1]
-    assert router.n_chips == chip_counts[-1]
+    assert router.members[APP].n_chips == chip_counts[-1]
     assert len(lane_caps) == len(log.step_emitted)
     assert all(0 <= e <= cap
                for e, cap in zip(log.step_emitted, lane_caps))
@@ -260,20 +283,19 @@ def test_shrink_grow_preserves_streamed_progress():
     shrink to one lane-block mid-flight, then grow back — every item
     must come out exactly once, never re-streamed (items_emitted ==
     total items == per-step sum), with outputs exact."""
-    fleet = ToyFleet(4)
-    router = FleetRouter(fleet, lanes_per_chip=2)
+    router = make_router(ToyFleet(4), lanes_per_chip=2)
     rng = np.random.default_rng(1)
     reqs = [ItemRequest(uid=i,
                         items=rng.uniform(-1, 1, (10, D_IN))
-                        .astype(np.float32))
+                        .astype(np.float32), key=APP)
             for i in range(8)]
     for r in reqs:
         assert router.submit(r)
     emitted = [router.step() for _ in range(3)]     # lanes mid-request
-    router.resize(1)                                # shrink 4 → 1 chip
+    resize(router, 1, lanes_per_chip=2)             # shrink 4 → 1 chip
     assert router.slots == 2
     emitted += [router.step() for _ in range(3)]
-    router.resize(4)                                # grow back
+    resize(router, 4, lanes_per_chip=2)             # grow back
     assert router.slots == 8
     while router.queue or router.active:
         emitted.append(router.step())
@@ -292,7 +314,7 @@ def test_merge_stats_is_consistent_with_parts():
     for seed in range(3):
         router = check_all(_random_schedule(rng), lanes_per_chip=2,
                            n_chips=1, queue_limit=None)
-        parts.append(router.stats())
+        parts.append(router.stats().fleet)
     m = merge_stats(parts)
     assert m.requests == sum(p.requests for p in parts)
     assert m.items == sum(p.items for p in parts)
@@ -358,7 +380,7 @@ if HAVE_HYPOTHESIS:
         for k in request_counts:
             router, _ = drive([(list(rng.integers(1, 5, size=k)), 1)],
                               lanes_per_chip=2, n_chips=1)
-            parts.append(router.stats())
+            parts.append(router.stats().fleet)
         m = merge_stats(parts)
         assert m.requests == sum(p.requests for p in parts)
         assert m.items == sum(p.items for p in parts)

@@ -28,11 +28,11 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.fleet.router import stats_from_states
 from repro.obs import (LANE_TID_BASE, MetricsRegistry, Reservoir,
                        Tracer, merge_snapshots)
 from repro.serving.engine import (ItemRequest, KeyedItemStreamScheduler,
                                   StreamSpec)
+from repro.serving.stats import stats_from_states
 
 
 # ------------------------------------------------------------------- #
