@@ -300,7 +300,10 @@ def _prefill(clm: "CompiledLM", tokens: jax.Array,
 @partial(jax.jit, static_argnames=("use_kernel",),
          donate_argnames=("cache",))
 def _decode(clm: "CompiledLM", cache, tokens: jax.Array,
-            pos: jax.Array, use_kernel: bool = False):
+            pos: jax.Array, prev=None, fresh=None,
+            use_kernel: bool = False):
+    if prev is not None:
+        tokens = jnp.where(fresh[:, None], tokens, prev[:, None])
     with jax.default_matmul_precision("highest"):
         h, new_cache, counts, read = _lm_forward(
             clm, {"tokens": tokens, "pos": pos}, "decode", cache,
@@ -371,12 +374,22 @@ class CompiledLM:
             cache, tokens, pos, use_kernel=use_kernel)
         return nxt, logits, counts, new_cache
 
-    def serve_step(self, cache, tokens, pos, *, use_kernel: bool = False):
+    def serve_step(self, cache, tokens, pos, *, prev=None, fresh=None,
+                   use_kernel: bool = False):
         """:meth:`step` with, before the cache, the latent attention's
         read: int32 (slots its branch read per lane, ring slots), summed
-        over the layers; (0, 0) without latent attention."""
+        over the layers; (0, 0) without latent attention.
+
+        With ``prev`` (B,) int, a previous step's next tokens left on
+        the device, and ``fresh`` (B,) bool, lane b decodes
+        ``tokens[b]`` where ``fresh[b]`` and ``prev[b]`` elsewhere,
+        merged inside the one jitted step."""
+        if prev is not None:
+            prev = jnp.asarray(prev, jnp.int32)
+            fresh = jnp.asarray(fresh, bool)
         return _decode(self, cache, jnp.asarray(tokens, jnp.int32),
-                       jnp.asarray(pos, jnp.int32), use_kernel)
+                       jnp.asarray(pos, jnp.int32), prev, fresh,
+                       use_kernel)
 
     def report(self):
         if self.chip is None:

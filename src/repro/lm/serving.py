@@ -11,11 +11,17 @@ KV cache:
 
   admit   → B=1 prefill of the prompt, ``kvcache.write_slot`` into the
             lane, first greedy token staged
-  step    → emit the staged token, then ONE batched ``CompiledLM.decode``
-            over every lane at its own position (inactive lanes decode
-            junk that ring-position masking ignores and the next admit
-            overwrites — same discipline as ``serving.Engine``)
-  release → ``kvcache.clear_slot``
+  step    → dispatch ONE batched ``CompiledLM.serve_step`` over every
+            lane at its own position, on the previous step's next
+            tokens still on the device (a lane admitted since decodes
+            its staged token, merged in the same jitted step); then
+            read the previous step's tokens back and emit them. One
+            step stays in flight, so the router's bookkeeping and the
+            next dispatch run while the device computes (inactive lanes
+            decode junk that ring-position masking ignores and the next
+            admit overwrites — same discipline as ``serving.Engine``)
+  release → ``kvcache.clear_slot``; the lane's token from the step in
+            flight is dropped
 
 Re-admission after an eviction (elastic resize / requeue) re-prefills
 prompt + already-emitted tokens: greedy decoding is deterministic, so
@@ -26,9 +32,14 @@ Token telemetry rides the ``repro.obs`` registry: ``lm.tokens``
 (counter, one per live lane per step), ``lm.prefill_tokens`` and a
 per-token ``lm.decode_latency_s`` histogram. Spans: ``lm.prefill`` (one
 admission: prefill, slot write, first token on the host) and
-``lm.decode`` (the batched decode, from dispatch to the tokens on the
-host). A MoE tenant's dispatch counts come back with the step's tokens
-in the same read-back, never by a sync of their own:
+``lm.decode`` (one call: the dispatch of this call's step and the wait
+for the previous step's tokens, which the call returns — the host's
+wait per step, not one step from dispatch to its own tokens). Counters
+``lm.decode_dispatched`` (every step dispatched) and
+``lm.decode_overlapped`` (the steps dispatched while the previous step
+was still running; below every step, the host sets the pace). A MoE
+tenant's dispatch counts come back with the step's tokens in the same
+read-back, never by a sync of their own:
 ``lm.expert_assignments`` (token-expert pairs on held experts),
 ``lm.expert_rows`` (rows the grouped expert layer computed) and
 ``lm.expert_dropped`` (pairs that found no slot; 0 by construction).
@@ -126,9 +137,15 @@ class LMMember:
         dtype = jnp.float32 if self.cfg.kv_cache_dtype == "float32" \
             else jnp.bfloat16
         self.cache = self.clm.init_cache(lanes, self.cache_len, dtype)
-        self._next_tok = np.zeros((lanes,), np.int32)
+        # a lane admitted since the last dispatch: its first token, on
+        # the host; every other live lane's is the step in flight's
+        self._staged = np.zeros((lanes,), np.int32)
+        self._fresh = np.zeros((lanes,), bool)
         self._pos = np.zeros((lanes,), np.int32)
         self._live: set = set()
+        # the step in flight: (next tokens, MoE counts, attention read)
+        # on the device, and the lanes it decoded for
+        self._flight = None
 
     # ---------------- lane lifecycle hooks -------------------------- #
     def on_admit(self, lane: int, st) -> None:
@@ -153,7 +170,8 @@ class LMMember:
                 jnp.asarray(context, jnp.int32)[None, :])
             self.cache = kvcache.write_slot(self.cache, one_cache,
                                             jnp.int32(lane))
-            self._next_tok[lane] = int(jnp.argmax(logits[0]))
+            self._staged[lane] = int(jnp.argmax(logits[0]))
+        self._fresh[lane] = True
         self._pos[lane] = len(context)
         self._live.add(lane)
         self.prefill_tokens += len(context)
@@ -167,32 +185,60 @@ class LMMember:
         # a free lane decodes junk at its position: at 0 it does not
         # hold the latent decode's prefix at the ring's length
         self._pos[lane] = 0
+        if not self._live and self._flight is not None:
+            self._collect()         # no lane waits on it: count it now
 
     # ---------------- one batched decode step ----------------------- #
     def stream_host(self, batch: np.ndarray, *,
                     use_kernel: bool = False) -> np.ndarray:
-        """(lanes, 1) placeholder in → (lanes, 1) token ids out: emit
-        each lane's staged token, then one batched decode (every lane
-        at its own position) stages the next."""
-        out = self._next_tok.astype(np.float32)[:, None]
+        """(lanes, 1) placeholder in → (lanes, 1) token ids out, with
+        one decode step kept in flight: dispatch this call's step (every
+        lane at its own position) on the previous step's next tokens,
+        still on the device, or a freshly admitted lane's staged token;
+        then read the previous step's tokens back and return them with
+        the staged ones — this step's inputs, which is what the lanes
+        emit. Host arrays the step reads are copies, so advancing the
+        positions after dispatch never reaches the step in flight."""
+        flight = self._flight
+        staged, fresh = self._staged.copy(), self._fresh.copy()
+        live = self.last_live = len(self._live)
         t0 = time.perf_counter()
         with _obs_span("lm.decode", cat="lm"):
+            # nothing in flight: every live lane is fresh
+            prev = staged if flight is None else flight[0]
+            overlapped = flight is not None and not prev.is_ready()
             nxt, self.last_logits, counts, read, self.cache = \
-                self.clm.serve_step(self.cache, self._next_tok[:, None],
-                                    self._pos, use_kernel=use_kernel)
-            nxt, counts, read = jax.device_get((nxt, counts, read))
+                self.clm.serve_step(self.cache, staged[:, None],
+                                    self._pos.copy(), prev=prev,
+                                    fresh=fresh, use_kernel=use_kernel)
+            self._fresh[:] = False
+            for lane in self._live:
+                self._pos[lane] += 1
+            out = staged if flight is None else \
+                np.where(fresh, staged, self._collect())
+            self._flight = (nxt, counts, read, live)
         dt = time.perf_counter() - t0
-        for lane in self._live:
-            self._next_tok[lane] = nxt[lane]
-            self._pos[lane] += 1
         self.decode_steps += 1
+        tel = _obs_current()
+        if tel.active:
+            m = tel.metrics
+            m.counter("lm.decode_dispatched").inc()
+            m.counter("lm.decode_overlapped").inc(int(overlapped))
+            if live:
+                m.counter("lm.tokens").inc(live)
+                m.histogram("lm.decode_latency_s").record(dt / live)
+        return out.astype(np.float32)[:, None]
+
+    def _collect(self) -> np.ndarray:
+        """Read the step in flight back in one transfer: count its MoE
+        dispatch and attention read, and return its next tokens."""
+        nxt, counts, read, live = self._flight
+        self._flight = None
+        nxt, counts, read = jax.device_get((nxt, counts, read))
         self.expert_counts += counts
-        live = self.last_live = len(self._live)
         tel = _obs_current()
         if tel.active and live:
             m = tel.metrics
-            m.counter("lm.tokens").inc(live)
-            m.histogram("lm.decode_latency_s").record(dt / live)
             if counts[1]:
                 m.counter("lm.expert_assignments").inc(int(counts[0]))
                 m.counter("lm.expert_rows").inc(int(counts[1]))
@@ -200,15 +246,19 @@ class LMMember:
             if read[1]:
                 m.counter("lm.attn_slots_read").inc(int(read[0]))
                 m.counter("lm.attn_slots_ring").inc(int(read[1]))
-        return out
+        return nxt
 
     # ---------------- elastic resize -------------------------------- #
     def resize(self, *, lanes: int, mesh=None) -> None:
         """Rebuild the lane-batched KV cache for a new lane budget.
         Call BEFORE the router requeues evicted lanes — their states
         re-admit through :meth:`on_admit`, which re-prefills into the
-        fresh cache (greedy determinism preserves the continuations)."""
+        fresh cache (greedy determinism preserves the continuations).
+        The step in flight is counted and its tokens dropped: the
+        re-prefill of prompt + emitted tokens stages the same ones."""
         if lanes < 1:
             raise ValueError("LMMember.resize: needs lanes >= 1")
+        if self._flight is not None:
+            self._collect()         # its tokens re-prefill, dropped here
         self.lanes = int(lanes)
         self._alloc(self.lanes)

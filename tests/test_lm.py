@@ -627,6 +627,190 @@ def test_moe_tenant_serves_through_deploy(moe_setup):
 
 
 
+# ------------------------------------------------------------------- #
+# one decode step in flight
+# ------------------------------------------------------------------- #
+def _sync_stream_host(self, batch, *, use_kernel=False):
+    """The member's decode as a plain synchronous loop over
+    ``CompiledLM.serve_step``: emit the staged tokens, decode them, wait
+    for the next ones and stage them."""
+    out = self._staged.copy()
+    nxt, self.last_logits, _, _, self.cache = self.clm.serve_step(
+        self.cache, self._staged[:, None], self._pos,
+        use_kernel=use_kernel)
+    nxt = np.asarray(nxt)
+    self.last_live = len(self._live)
+    for lane in self._live:
+        self._staged[lane] = nxt[lane]
+        self._pos[lane] += 1
+    return out.astype(np.float32)[:, None]
+
+
+def _recorded(dep, stream_host):
+    """Serve through ``stream_host`` and record, per call, the live
+    lanes with the tokens they emit and their logits rows."""
+    member = dep.router.members["lm"]
+    calls = []
+
+    def call(batch, *, use_kernel=False):
+        lanes = sorted(member._live)
+        out = stream_host(batch, use_kernel=use_kernel)
+        calls.append((lanes, out[lanes, 0].tolist(),
+                      np.asarray(member.last_logits)[lanes]))
+        return out
+    member.stream_host = call
+    return calls
+
+
+def _lm_tenant(request, name):
+    return request.getfixturevalue({"gqa": "setup", "moe": "moe_setup"}[name])
+
+
+@pytest.mark.parametrize("tenant", ["gqa", "moe"])
+def test_overlapped_decode_matches_a_synchronous_loop(request, tenant):
+    """Admissions mid-stream, releases at different lengths and an
+    elastic resize: the member that keeps one step in flight emits the
+    synchronous loop's tokens and binds ``last_logits`` to the same
+    step, row for row."""
+    from repro.deploy import AppSpec, deploy
+
+    cfg, params = _lm_tenant(request, tenant)
+    rng = np.random.default_rng(12)
+    prompts = [list(rng.integers(0, cfg.vocab_size, size=n))
+               for n in (5, 3, 6, 4, 7)]
+    new = (3, 6, 4, 5, 2)
+    runs = []
+    for overlapped in (True, False):
+        dep = deploy(AppSpec("lm", cfg, params=params, cache_len=32,
+                             lanes_per_chip=3))
+        member = dep.router.members["lm"]
+        calls = _recorded(dep, member.stream_host if overlapped else
+                          _sync_stream_host.__get__(member))
+        for p, n in zip(prompts[:3], new[:3]):
+            assert dep.submit_tokens("lm", p, max_new_tokens=n)
+        for _ in range(2):
+            dep.step()
+        for p, n in zip(prompts[3:], new[3:]):
+            assert dep.submit_tokens("lm", p, max_new_tokens=n)
+        for _ in range(3):
+            dep.step()
+        dep.resize(1)
+        dep.run_until_drained()
+        got = dep.generated_tokens("lm")
+        runs.append(([got[uid] for uid in sorted(got)], calls,
+                     member.decode_steps))
+        dep.close()
+    (tokens, calls, steps), (tokens_sync, calls_sync, _) = runs
+    assert tokens == tokens_sync
+    assert [len(t) for t in tokens] == list(new)
+    assert steps == len(calls) == len(calls_sync)
+    for (lanes, out, rows), (lanes_s, out_s, rows_s) in zip(calls,
+                                                            calls_sync):
+        assert lanes == lanes_s and out == out_s
+        np.testing.assert_array_equal(rows, rows_s)
+
+
+def _aligned(a):
+    """A copy of ``a`` on a 64-byte boundary, where the CPU client
+    hands a jitted call the host buffer itself instead of a copy."""
+    buf = np.zeros(a.size + 64 // a.itemsize, a.dtype)
+    off = (-buf.ctypes.data % 64) // a.itemsize
+    out = buf[off:off + a.size]
+    out[:] = a
+    return out
+
+
+class _BumpAfterDispatch:
+    """A CompiledLM whose ``serve_step`` moves the member's host
+    positions on as soon as the step is dispatched."""
+
+    def __init__(self, clm, member):
+        self._clm, self._member = clm, member
+
+    def __getattr__(self, name):
+        return getattr(self._clm, name)
+
+    def serve_step(self, *args, **kw):
+        res = self._clm.serve_step(*args, **kw)
+        self._member._pos += 1000
+        return res
+
+
+@pytest.mark.parametrize("tenant", ["gqa", "moe"])
+def test_positions_bumped_after_dispatch_miss_the_step_in_flight(
+        request, tenant):
+    from repro.deploy import AppSpec, deploy
+
+    cfg, params = _lm_tenant(request, tenant)
+    rng = np.random.default_rng(13)
+    prompts = [list(rng.integers(0, cfg.vocab_size, size=n))
+               for n in (5, 3)]
+    rows = []
+    for bump in (False, True):
+        dep = deploy(AppSpec("lm", cfg, params=params, cache_len=32,
+                             lanes_per_chip=2))
+        member = dep.router.members["lm"]
+        for p in prompts:
+            assert dep.submit_tokens("lm", p, max_new_tokens=4)
+        dep.step()
+        member._pos = _aligned(member._pos)
+        if bump:
+            member.clm = _BumpAfterDispatch(member.clm, member)
+        dep.step()
+        rows.append(np.asarray(member.last_logits))
+        dep.close()
+    np.testing.assert_array_equal(rows[1], rows[0])
+
+
+@pytest.mark.parametrize("tenant", ["gqa", "moe"])
+def test_decode_counters_count_every_step_and_the_overlapped(request,
+                                                             tenant):
+    """``lm.decode_dispatched`` counts every step; ``lm.decode_overlapped``
+    counts a step whose predecessor's tokens were still being computed
+    (here held back by a slow program) and not one whose predecessor
+    had finished."""
+    from repro import obs
+    from repro.deploy import AppSpec, deploy
+
+    cfg, params = _lm_tenant(request, tenant)
+
+    @jax.jit
+    def slow(tokens):
+        x = jax.lax.fori_loop(0, 3000, lambda i, a: jnp.sin(a) + 1.0,
+                              jnp.ones((1 << 16,), jnp.float32))
+        return tokens + (jnp.max(x) > 1e30).astype(jnp.int32)
+
+    dep = deploy(AppSpec("lm", cfg, params=params, cache_len=32,
+                         lanes_per_chip=2))
+    member = dep.router.members["lm"]
+    rng = np.random.default_rng(14)
+    for n in (5, 3):
+        assert dep.submit_tokens("lm", list(rng.integers(
+            0, cfg.vocab_size, size=n)), max_new_tokens=8)
+    dep.step()
+    jax.block_until_ready(slow(member._flight[0]))      # compiled
+    tel = obs.configure(metrics=True, trace=False)
+    try:
+        for held in (False, True, False, True, True):
+            nxt, *rest = member._flight
+            if held:
+                member._flight = (slow(nxt), *rest)
+            else:
+                jax.block_until_ready(nxt)
+            dep.step()
+        counters = tel.metrics.snapshot()["counters"]
+    finally:
+        obs.disable()
+    steps = member.decode_steps
+    dep.run_until_drained()
+    got = dep.generated_tokens("lm")
+    dep.close()
+    assert steps == 6
+    assert counters["lm.decode_dispatched"] == 5
+    assert counters["lm.decode_overlapped"] == 3
+    assert all(len(t) == 8 for t in got.values())
+
+
 def test_lm_above_the_routed_tile_limit_is_served_unpriced(setup,
                                                           monkeypatch):
     """Above ``ROUTED_TILE_LIMIT`` tiles compile_lm builds no cost chip:
